@@ -20,7 +20,9 @@ import os as _os
 if _os.environ.get("MXNET_TPU_FORCE_CPU", "") in ("1", "true"):
     # debugging/CI escape hatch (the reference's MXNET_ENGINE_TYPE=
     # NaiveEngine analogue): force the host platform before any backend
-    # init, overriding site-level accelerator selection
+    # init, from code that cannot set JAX_PLATFORMS=cpu in the
+    # environment (tools/diagnose.py keeps its own process off the chip
+    # this way)
     import jax as _jax
     _jax.config.update("jax_platforms", "cpu")
 

@@ -4,9 +4,8 @@ No reference counterpart — the reference recompiled its graph executors
 per process and called it cheap (CUDA kernels were prebuilt; only graph
 planning ran at bind). On XLA the per-process cost is an actual
 compiler invocation per program signature: serving warmup compiles one
-program per batch bucket, a bench round compiles the train step before
-it can measure anything, and BENCH_r03–r05 burned their entire on-chip
-budget in exactly this startup window. This module is the zero-cold-
+program per batch bucket, and a bench round compiles the train step
+before it can measure anything. This module is the zero-cold-
 start tier ROADMAP item 3 calls for — the tune-once-serve-forever loop
 of TVM (arXiv:1802.04799) native to our runtime:
 
@@ -55,6 +54,9 @@ import pickle
 import tempfile
 import threading
 import time
+
+import jax
+from jax.experimental import serialize_executable
 
 from . import telemetry
 from . import faults
@@ -130,32 +132,26 @@ def _trusted_dir():
 def enabled():
     """Whether executables persist to disk this process (requires a
     TRUSTED cache dir — see ``_trusted_dir``)."""
-    return _trusted_dir() is not None and _serialize_api() is not None
+    return _trusted_dir() is not None
 
 
 def persistable(donated=()):
     """Whether a program with this donation set may use the persisted
-    tier. Donated-buffer programs are EXCLUDED by default: executing a
-    deserialized input-donating executable intermittently corrupts the
-    process heap on jaxlib 0.4.36 (glibc ``corrupted double-linked
-    list`` aborts at a later free — reproduced through Module.fit's
-    fused train step; forward/serving programs are stable across
-    hundreds of warm starts). ``MXNET_COMPILE_CACHE_DONATED=1`` opts
-    donated programs back in on a jaxlib whose PJRT executable
-    deserialization handles input-output aliasing release correctly."""
+    tier. Donated-buffer programs are EXCLUDED by default: on jaxlib
+    0.4.36, executing a deserialized input-donating executable
+    intermittently corrupted the process heap (glibc ``corrupted
+    double-linked list`` at a later free — seen through Module.fit's
+    fused train step; forward/serving programs were stable). On the
+    installed jax/jaxlib 0.9.0 the opted-in round trip passes on the
+    CPU backend (tests/test_compile_cache.py) and 20 warm starts of
+    the fused step in a row showed no abort there; it has not been
+    tried on a TPU, so the default stays off and
+    ``MXNET_COMPILE_CACHE_DONATED=1`` is the opt-in. JAX's own
+    persistent cache (``jax_cache.place``) has no such exclusion and
+    is what the chip entry points use."""
     if not donated:
         return True
     return os.environ.get("MXNET_COMPILE_CACHE_DONATED", "") == "1"
-
-
-def _serialize_api():
-    """The jax AOT-serialization module, or None on jaxlibs without it
-    (the cache then degrades to disabled — never to an error)."""
-    try:
-        from jax.experimental import serialize_executable as se
-        return se
-    except Exception:
-        return None
 
 
 def env_meta():
@@ -163,7 +159,6 @@ def env_meta():
     a serialized executable is only valid under: jax/jaxlib versions,
     backend platform, and the local device topology (a cache written
     on an 8-device mesh must not load into a 1-device process)."""
-    import jax
     import jaxlib
     devs = jax.devices()
     return {
@@ -425,9 +420,8 @@ def load(key, kind=None):
     counter bump. The deserialize phase records as a
     ``jit_deserialize`` telemetry span, the disk-tier counterpart of
     ``jit_compile``."""
-    se = _serialize_api()
     path = entry_path(key)
-    if se is None or path is None or _trusted_dir() is None:
+    if path is None or _trusted_dir() is None:
         return None
     # chaos site: an injected raise behaves exactly like a mangled
     # entry — the reject path fires and the caller compiles fresh (a
@@ -461,10 +455,21 @@ def load(key, kind=None):
             % (meta.get("devices"), env["devices"]))
     if meta.get("blob_sha256") != hashlib.sha256(blob).hexdigest():
         return _reject(key, "corrupt", "blob checksum mismatch")
+    # the devices the program was compiled for: left to its default,
+    # deserialize_and_load loads for EVERY device of the backend, and a
+    # one-device program then wants one shard per device at dispatch
+    by_id = {int(d.id): d for d in jax.devices()}
+    try:
+        exec_devs = [by_id[i] for i in meta["execution_devices"]]
+    except (KeyError, TypeError):
+        return _reject(key, "mesh", "entry names execution devices %r "
+                       "this process does not have"
+                       % (meta.get("execution_devices"),))
     try:
         with telemetry.span("jit_deserialize"):
             payload, in_tree, out_tree = pickle.loads(blob)
-            compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+            compiled = serialize_executable.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=exec_devs)
     except Exception as e:
         return _reject(key, "deserialize",
                        "%s: %s" % (type(e).__name__, e))
@@ -479,13 +484,14 @@ def store(key, compiled, kind=None, entry=None, signature=None):
     trees, full disk) degrade to a warning-once no-op — persisting is
     an optimisation, never a requirement. Returns the stored byte
     count (0 when skipped)."""
-    se = _serialize_api()
     path = entry_path(key)
-    if se is None or path is None:
+    if path is None:
         return 0
     try:
-        payload, in_tree, out_tree = se.serialize(compiled)
+        payload, in_tree, out_tree = serialize_executable.serialize(compiled)
         blob = pickle.dumps((payload, in_tree, out_tree))
+        exec_devs = [int(d.id) for d in
+                     compiled.runtime_executable().local_devices()]
     except Exception as e:
         _warn_once(key, "serialize", "%s: %s" % (type(e).__name__, e))
         telemetry.counter_inc("compile_cache.store_fail")
@@ -499,6 +505,7 @@ def store(key, compiled, kind=None, entry=None, signature=None):
         "created": time.time(),
         "blob_sha256": hashlib.sha256(blob).hexdigest(),
         "blob_bytes": len(blob),
+        "execution_devices": exec_devs,
     })
     try:
         n = _write_entry(path, meta, blob)

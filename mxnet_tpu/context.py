@@ -51,12 +51,19 @@ class Context:
         if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
             devs = jax.local_devices(backend="cpu") if _has_platform("cpu") \
                 else jax.local_devices()
-        else:
-            # tpu and the gpu alias both mean "the accelerator"
-            devs = _accelerator_devices()
-        if not devs:
-            raise MXNetError("no devices for context %r" % (self,))
-        return devs[self.device_id % len(devs)]
+            # every cpu(i) is the host (reference semantics): ids wrap
+            return devs[self.device_id % len(devs)]
+        # tpu and the gpu alias both mean "the accelerator". An id beyond
+        # the chips present is an error, never chip (id mod n) or the
+        # host: a fallback here would hide a missing chip from every
+        # caller above
+        devs = _accelerator_devices()
+        if self.device_id >= len(devs):
+            raise MXNetError(
+                "%r: this process has %d accelerator device(s) (jax "
+                "platform %r); use mx.cpu() to run on the host"
+                % (self, len(devs), jax.default_backend()))
+        return devs[self.device_id]
 
     # -- dunder -------------------------------------------------------
     def __eq__(self, other):
@@ -92,11 +99,8 @@ def _has_platform(name):
 
 
 def _accelerator_devices():
-    """Local non-CPU devices if any; else all local devices (CPU-only
-    test runs)."""
-    devs = jax.local_devices()
-    accel = [d for d in devs if d.platform != "cpu"]
-    return accel or devs
+    """Local non-CPU devices (empty on a CPU-only host)."""
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def cpu(device_id=0):

@@ -62,16 +62,13 @@ def _force_cpu_collectives():
     """Select the gloo transport for cross-process CPU collectives when
     the job runs on the host platform (the tier-1 lane; the default CPU
     client has no multi-process collectives at all). A no-op when the
-    flag is unknown (older jax) or the platform is an accelerator."""
+    platform is an accelerator."""
     plats = os.environ.get("JAX_PLATFORMS", "")
     force_cpu = os.environ.get("MXNET_TPU_FORCE_CPU", "") in ("1", "true")
     if not (force_cpu or "cpu" in plats.split(",")):
         return
-    try:
-        import jax
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:   # flag unknown on this jax — stock behaviour
-        pass
+    import jax
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def init_from_env():
@@ -90,46 +87,15 @@ def init_from_env():
     _force_cpu_collectives()
     import jax
     with _lock:
-        if _state["initialized"] or _jax_initialized():
+        if _state["initialized"] or jax.distributed.is_initialized():
             _state["initialized"] = True
             return True
         nproc = int(os.environ.get(ENV_NUM_PROCESSES, "1"))
         pid = int(os.environ.get(ENV_PROCESS_ID, "0"))
-        try:
-            _survivable_initialize(addr, nproc, pid)
-            _state["owns_client"] = True
-        except (ImportError, AttributeError, TypeError):
-            # private client surface moved on this jax — fall back to
-            # the stock initialize (loses elastic survival, keeps
-            # multi-process training). If the SERVICE half already came
-            # up before the client constructor rejected a kwarg, tear
-            # it down first: the stock initialize refuses to run with
-            # a service already set, which would kill the coordinator
-            # process (and with it the whole job) at import
-            _teardown_partial_service()
-            jax.distributed.initialize(coordinator_address=addr,
-                                       num_processes=nproc,
-                                       process_id=pid)
+        _survivable_initialize(addr, nproc, pid)
+        _state["owns_client"] = True
         _state["initialized"] = True
     return True
-
-
-def _teardown_partial_service():
-    """Undo a half-finished :func:`_survivable_initialize`: shut down
-    and clear any coordination service it created so the stock
-    ``jax.distributed.initialize`` fallback starts from a clean
-    slate."""
-    try:
-        from jax._src import distributed as _jdist
-    except ImportError:
-        return
-    gs = _jdist.global_state
-    service, gs.service = gs.service, None
-    if service is not None:
-        try:
-            service.shutdown()
-        except Exception:
-            pass
 
 
 def _survivable_initialize(addr, nproc, pid):
@@ -140,22 +106,23 @@ def _survivable_initialize(addr, nproc, pid):
     ``jax.distributed.is_initialized()`` and every ``process_index``
     consumer see a normally-initialised runtime."""
     from jax._src import distributed as _jdist
-    from jax._src.lib import xla_extension as _xe
-    hb_s = int(os.environ.get(ENV_HEARTBEAT_S, "10"))
-    max_missed = int(os.environ.get(ENV_MAX_MISSED, "10"))
+    from jax._src.lib import _jax
+    # jaxlib 0.9.0's service and client take one timeout: interval x
+    # missed beats, the window the two env knobs describe
+    hb_timeout = (int(os.environ.get(ENV_HEARTBEAT_S, "10"))
+                  * int(os.environ.get(ENV_MAX_MISSED, "10")))
     gs = _jdist.global_state
     if gs.client is not None:
         raise RuntimeError("distributed client already initialised")
     if pid == 0 and gs.service is None:
         port = addr.rsplit(":", 1)[1]
-        gs.service = _xe.get_distributed_runtime_service(
-            "[::]:" + port, nproc, heartbeat_interval=hb_s,
-            max_missing_heartbeats=max_missed)
-    client = _xe.get_distributed_runtime_client(
+        gs.service = _jax.get_distributed_runtime_service(
+            "[::]:" + port, nproc, heartbeat_timeout=hb_timeout)
+    client = _jax.get_distributed_runtime_client(
         addr, pid,
         init_timeout=int(os.environ.get("MXNET_TPU_DIST_INIT_TIMEOUT",
                                         "300")),
-        heartbeat_interval=hb_s, max_missing_heartbeats=max_missed,
+        heartbeat_timeout=hb_timeout,
         shutdown_on_destruction=False, use_compression=True)
     client.connect()
     gs.client = client
@@ -166,19 +133,10 @@ def _survivable_initialize(addr, nproc, pid):
         gs.initialize_preemption_sync_manager()
 
 
-def _jax_initialized():
-    """Whether the jax distributed client exists (jax's own
-    ``is_initialized`` only appeared in later releases)."""
-    try:
-        from jax._src import distributed as _jdist
-        return _jdist.global_state.client is not None
-    except Exception:
-        return False
-
-
 def initialized():
     """Whether a multi-process runtime is live."""
-    return _jax_initialized()
+    import jax
+    return jax.distributed.is_initialized()
 
 
 def rank():

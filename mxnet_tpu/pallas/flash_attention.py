@@ -17,6 +17,17 @@ Design (TPU-first):
   same kernel serves both the single-chip and the sequence-sharded case.
 - ``interpret=True`` off-TPU so the unit suite runs on the CPU mesh.
 
+Limit (TPU v5e, compiled for a described chip, jax 0.9.0): each program
+holds the whole local K and V block plus a (block_q, S_kv) f32 score
+tile in VMEM. (B4,H16,S2048,D128) and D64 bf16 compile, forward and
+gradient. At (B1,H16,S8192,D128) bf16 the non-causal forward still
+compiles, but the causal forward and the gradient are refused
+(``RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem``). Longer
+sequences are what ``parallel.ring_attention``/``ulysses_attention``
+are for: they keep the per-device S_kv short; a local block of 8192
+reached through them on a TPU hits the same refusal
+(tests/test_tpu_compile.py pins it).
+
 Backward for the plain entry is a custom VJP: recompute probabilities
 from the saved log-sum-exp one Q block at a time (lax.map), so peak
 memory stays O(block_q * S) instead of O(S^2) — the flash backward
@@ -44,6 +55,14 @@ NEG_INF = -1e30
 
 def _use_interpret():
     return jax.default_backend() != "tpu"
+
+
+def _dot_precision(dtype):
+    """Mosaic refuses a bf16 x bf16 matmul at any contract precision but
+    the default ("Bad lhs type"), so a process-wide
+    ``jax_default_matmul_precision=highest`` (a parity-check habit) must
+    not reach the kernel's bf16 dots. f32 operands keep the ambient one."""
+    return None if dtype == jnp.float32 else lax.Precision.DEFAULT
 
 
 def _resolve_block_q(q, k, causal, interpret):
@@ -95,6 +114,7 @@ def _attn_kernel(scalars_ref, q_ref, k_ref, v_ref, o_in_ref, m_in_ref,
 
     scores = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_dot_precision(q.dtype),
         preferred_element_type=jnp.float32) * scale     # (block_q, S_kv)
 
     qi = pl.program_id(1)
@@ -118,6 +138,7 @@ def _attn_kernel(scalars_ref, q_ref, k_ref, v_ref, o_in_ref, m_in_ref,
     l_new = l_in * corr + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
         p.astype(v.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=_dot_precision(v.dtype),
         preferred_element_type=jnp.float32)
     o_new = o_in * corr + pv
 

@@ -1,32 +1,10 @@
-"""JAX version compatibility shims for the parallel package.
-
-``shard_map`` has moved twice upstream: it started life as
-``jax.experimental.shard_map.shard_map`` (with a ``check_rep`` kwarg),
-and newer JAX releases promote it to ``jax.shard_map`` (renaming the
-kwarg to ``check_vma``). Every per-device collective program in this
-package (ring/ulysses attention, MoE dispatch, the GPipe schedule) uses
-the ONE wrapper below so the call sites are written against the modern
-``jax.shard_map`` surface and keep working on the older installed
-jaxlib without per-module try/except drift.
-"""
+"""The one ``shard_map`` every per-device collective program in this
+package (ring/ulysses attention, MoE dispatch, the GPipe schedule)
+imports: ``jax.shard_map`` of the installed JAX."""
 from __future__ import annotations
 
 import jax
 
 __all__ = ["shard_map"]
 
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=True, **kwargs):
-        """Modern ``jax.shard_map`` signature on the experimental
-        implementation: ``check_vma`` maps onto the old ``check_rep``
-        (same meaning — verify the per-device values claimed replicated
-        really are)."""
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma, **kwargs)
+shard_map = jax.shard_map

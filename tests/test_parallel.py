@@ -184,7 +184,7 @@ def test_ulysses_differentiable():
 
 def test_spmd_trainer_adam_matches_eager():
     """dp/tp Adam in the sharded step must match the eager mx.optimizer
-    Adam applied to the same grads (VERDICT r1 #9 done-criterion)."""
+    Adam applied to the same grads."""
     import mxnet_tpu as mx
     from mxnet_tpu import nd
 

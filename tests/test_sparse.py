@@ -423,7 +423,7 @@ def test_csr_elemwise_add_native_no_densify():
 # sum(csr, axis) — the remaining reference FComputeEx table
 # (elemwise_binary_op_basic.cc, elemwise_binary_scalar_op_basic.cc,
 # elemwise_unary_op_basic.cc square, square_sum-inl.h,
-# broadcast_reduce_op_value.cc) — VERDICT r4 next #5.
+# broadcast_reduce_op_value.cc).
 # ---------------------------------------------------------------------------
 
 def _rand_sparse_pair(rs, shape, density=0.4):

@@ -388,7 +388,13 @@ def test_rec2idx_roundtrip(tmp_path):
 def test_diagnose_runs():
     p = _run([os.path.join(TOOLS, "diagnose.py"), "--accelerator", "0"])
     assert p.returncode == 0, p.stderr
-    assert "Framework" in p.stdout and "native C ABI : built" in p.stdout
+    # the line reports the state the tree is in: nothing requires `make`
+    # (every consumer of mxnet_tpu/_lib has a pure-Python path)
+    lib = os.path.join(os.path.dirname(TOOLS), "mxnet_tpu", "_lib",
+                       "libmxtpu_c_api.so")
+    want = "built" if os.path.exists(lib) else "NOT BUILT (run `make`)"
+    assert "Framework" in p.stdout
+    assert "native C ABI : " + want in p.stdout
 
 
 def test_rec2idx_duplicate_ids_key_sequentially(tmp_path):

@@ -1,0 +1,101 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e at
+real widths. Nothing runs: the TPU's compiler is installed here and
+compiles for a chip that is described, not attached, so what it refuses
+(a misaligned slice, too much VMEM) is caught at no chip time.
+
+This is the ONE file that describes the chip. The topology is described
+inside a module-scoped fixture, after a test of this file has started:
+only one process at a time may load the TPU's library, and every xdist
+worker imports every test file.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.pallas.flash_attention import flash_attention
+from mxnet_tpu.pallas.fused_bn import scale_bias_add_relu
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip can be written to JAX's persistent
+    cache but not read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _sum32(fn):
+    return lambda *a: jnp.sum(fn(*a).astype(jnp.float32))
+
+
+# the four ResNet-50 NHWC stage outputs at batch 128
+@pytest.mark.parametrize("shape", [(128, 56, 56, 256), (128, 28, 28, 512),
+                                   (128, 14, 14, 1024), (128, 7, 7, 2048)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_bn_compiles_at_resnet50_stage(one_chip, shape):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct(shape[-1:], jnp.float32, sharding=one_chip)
+
+    def fused(x, s, b, r):
+        return scale_bias_add_relu(x, s, b, r, interpret=False)
+
+    fwd = _compile(fused, x, v, v, x)
+    assert "tpu_custom_call" in fwd.as_text()
+    grad = _compile(jax.grad(_sum32(fused), argnums=(0, 1, 2, 3)),
+                    x, v, v, x)
+    assert "tpu_custom_call" in grad.as_text()
+
+
+def _attn(causal):
+    return lambda q, k, v: flash_attention(q, k, v, causal, None, 128, False)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_attention_forward_compiles(one_chip, causal):
+    q = jax.ShapeDtypeStruct((4, 16, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    assert "tpu_custom_call" in _compile(_attn(causal), q, q, q).as_text()
+
+
+def test_flash_attention_gradient_compiles(one_chip):
+    q = jax.ShapeDtypeStruct((4, 16, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    grad = _compile(jax.grad(_sum32(_attn(True)), argnums=(0, 1, 2)),
+                    q, q, q)
+    assert "tpu_custom_call" in grad.as_text()
+
+
+def test_flash_attention_vmem_limit_at_8k(one_chip):
+    """Today's limit, written down (flash_attention.py docstring): the
+    whole local K/V block and a (block_q, S_kv) f32 score tile live in
+    VMEM, so at S=8192, D=128 the causal forward is refused. Sequence
+    parallelism (ring/ulysses) is how longer sequences are meant to run."""
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
+        _compile(_attn(True), q, q, q)

@@ -4,11 +4,13 @@ The reference validates its second backend by consistency against the
 first (tests/python/gpu/test_operator_gpu.py + test_utils.check_consistency
 at python/mxnet/test_utils.py:1267); this lane is the TPU analogue.
 
-Run with:
-    MXTPU_TEST_PLATFORM=tpu python -m pytest tests/tpu -q
+Run with (one process: a chip belongs to one process at a time):
+    MXTPU_TEST_PLATFORM=tpu python -m pytest tests/tpu -q -p no:xdist
 
 Under the default test run (`pytest tests/`) the root conftest pins the
-cpu platform and everything here skips.
+cpu platform and everything here skips. Asked for by name on a machine
+where JAX finds no accelerator, the lane is an error, not a skip: it
+never falls back to the CPU.
 """
 import os
 
@@ -32,9 +34,15 @@ def pytest_collection_modifyitems(config, items):
     # skip the entire suite (round-2 regression).
     if os.environ.get("MXTPU_SWEEP_SELF") == "1":
         return  # cpu-vs-cpu case-spec debugging (test_op_sweep.SELF_MODE)
-    if os.environ.get("MXTPU_TEST_PLATFORM") != "tpu" or not _on_accelerator():
-        skip = pytest.mark.skip(
-            reason="TPU lane: set MXTPU_TEST_PLATFORM=tpu with a chip attached")
-        for item in items:
-            if str(item.fspath).startswith(_TPU_LANE_DIR + os.sep):
-                item.add_marker(skip)
+    lane = [item for item in items
+            if str(item.fspath).startswith(_TPU_LANE_DIR + os.sep)]
+    if os.environ.get("MXTPU_TEST_PLATFORM") == "tpu":
+        if lane and not _on_accelerator():
+            raise pytest.UsageError(
+                "MXTPU_TEST_PLATFORM=tpu, but JAX finds no accelerator: "
+                "the on-chip lane does not fall back to the CPU")
+        return
+    skip = pytest.mark.skip(
+        reason="TPU lane: set MXTPU_TEST_PLATFORM=tpu with a chip attached")
+    for item in lane:
+        item.add_marker(skip)

@@ -7,6 +7,10 @@ round-3's trace):
     python tools/mfu_capture.py              # real chip (or CPU smoke:
     MXTPU_BENCH_SMOKE=1 python tools/mfu_capture.py)
 
+This process imports no JAX and so never holds the chip: the one child
+(``bench.py --child``) does, and keeps JAX's persistent compile cache
+where ``mxnet_tpu.jax_cache.place()`` says.
+
 Runs ``bench.py --child`` with MXTPU_BENCH_TRACE set, finds the
 resulting ``.xplane.pb``, aggregates per-op self time into the same
 categories PERF.md uses (convolution fusions / elementwise loop
@@ -46,7 +50,8 @@ def hbm_bw_for(kind):
     for sub, val in HBM_BW:
         if sub in k:
             return val
-    return None
+    raise ValueError("mfu_capture: no HBM bandwidth known for device kind "
+                     "%r — add it to HBM_BW with its source" % (kind,))
 
 
 def run_traced_child(trace_dir, timeout):
@@ -207,9 +212,11 @@ def main():
     # roofline ceiling re-derivation (PERF.md arithmetic, fresh inputs):
     # FLOP/byte of the step vs the chip's break-even ratio. Byte source
     # priority: program card (exact, compile-time) > xplane hlo_stats.
-    from bench import peak_flops_for, ITERS  # noqa: E402
-    peak = peak_flops_for(bench_line.get("device", ""))
-    bw = hbm_bw_for(bench_line.get("device", ""))
+    # The roofline is a device metric: the CPU harness check
+    # (MXTPU_BENCH_SMOKE) derives none, and an unknown device kind raises.
+    from bench import peak_flops_for, ITERS, SMOKE  # noqa: E402
+    peak = None if SMOKE else peak_flops_for(bench_line["device"])
+    bw = None if SMOKE else hbm_bw_for(bench_line["device"])
     if card_bytes:
         bytes_per_step = float(card_bytes)
         out["bytes_source"] = "program_card"
