@@ -21,6 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+import functools
 import itertools
 import time
 
@@ -242,6 +243,18 @@ def _path_str(path, argnames):
     return head + keystr(tuple(rest))
 
 
+def _named(fn, name):
+    """``fn`` under the name ``name``: jax names the XLA module after
+    the function it jits, so the device trace's "XLA Modules" line reads
+    ``jit_<kind>(...)`` — a stable name a trace reader can look the step
+    program up by — and not ``jit_step`` / ``jit_fn`` / ``jit__lambda_``."""
+    @functools.wraps(fn)
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
 class _InstrumentedProgram:
     """One jitted entry point, compiled through explicit
     ``lower().compile()`` with full introspection:
@@ -273,7 +286,7 @@ class _InstrumentedProgram:
         self.argnames = argnames or ()
         kw = dict(jit_kwargs or {})
         self._donate = tuple(kw.get("donate_argnums", ()) or ())
-        self._jitted = jax.jit(fn, **kw)   # the ONE instrumented jit site
+        self._jitted = jax.jit(_named(fn, kind), **kw)   # the ONE instrumented jit site
         self._cache = {}    # dispatch sig -> [callable, card, aot_bool]
         self._card = None   # last-compiled card: the recompile-diff base
         self._meta = dict(meta or {})
@@ -756,8 +769,12 @@ class _GraphProgram:
                         node, arg_dict, aux_dict),)
                     continue
                 raw_in = [env[id(c)][idx] for c, idx in node.inputs]
-                env[id(node)] = self._apply_node(node, raw_in, train,
-                                                 aux_dict, aux_updates)
+                # every op of the node carries the node's name in its
+                # metadata (trace time only): the profiler's trace
+                # then says which layer a device op belongs to
+                with jax.named_scope(node.name):
+                    env[id(node)] = self._apply_node(
+                        node, raw_in, train, aux_dict, aux_updates)
         outputs = [env[id(n)][idx] for n, idx in self.output_entries]
         return outputs, aux_updates
 
@@ -835,8 +852,9 @@ class _GraphProgram:
                         raw_in = []
                         for c, idx in n.inputs:
                             raw_in.append(local[(id(c), idx)])
-                        outs = self._apply_node(n, raw_in, train,
-                                                aux_dict, ups)
+                        with jax.named_scope(n.name):
+                            outs = self._apply_node(n, raw_in, train,
+                                                    aux_dict, ups)
                         for i, v in enumerate(outs):
                             local[(id(n), i)] = v
                     return [local[key] for key in _needed], ups
@@ -856,7 +874,8 @@ class _GraphProgram:
         telemetry.record_jit("forward", hit)
         if not hit:
             def fn(args, aux, rng):
-                return self.eval_graph(args, aux, rng, train)
+                with jax.named_scope("forward"):
+                    return self.eval_graph(args, aux, rng, train)
             # grouped programs pin ops to concrete devices — eager
             # execution (per-op dispatch), not one jitted program
             self._jit_cache[key] = fn if self.node_devices else \
@@ -899,8 +918,9 @@ class _GraphProgram:
             def fn(args, aux, rng, head_grads):
                 grad_args = {k: args[k] for k in grad_names}
                 rest = {k: v for k, v in args.items() if k not in grad_names}
-                outs, vjp, aux_up = self._vjp_over_graph(
-                    grad_args, rest, aux, rng, train)
+                with jax.named_scope("forward"):
+                    outs, vjp, aux_up = self._vjp_over_graph(
+                        grad_args, rest, aux, rng, train)
                 hg = tuple(
                     head_grads[i] if head_grads[i] is not None
                     else jnp.ones(outs[i].shape, outs[i].dtype)
@@ -912,7 +932,8 @@ class _GraphProgram:
                         jax.device_put(g, self.node_devices.get(
                             id(n), self.default_device))
                         for g, (n, _) in zip(hg, self.output_entries))
-                grads = vjp(hg)[0]
+                with jax.named_scope("backward"):
+                    grads = vjp(hg)[0]
                 return outs, grads, aux_up
             self._jit_cache[key] = fn if self.node_devices else \
                 _InstrumentedProgram(
@@ -993,10 +1014,14 @@ class _GraphProgram:
             grad_args = {k: params[k] for k in update_names}
             rest = {k: v for k, v in params.items() if k not in grad_set}
             rest.update(ins)
-            outs, vjp, aux_up = self._vjp_over_graph(
-                grad_args, rest, aux, rng, True)
+            # the four phases carry names (``jax.named_scope``, metadata
+            # only): a device op's path in the profiler's trace reads
+            # forward/jvp(<node>)/..., backward/transpose(jvp(<node>))/
+            # ..., optimizer/... or metric/...
+            with jax.named_scope("forward"):
+                outs, vjp, aux_up = self._vjp_over_graph(
+                    grad_args, rest, aux, rng, True)
             hg = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
-            grads = vjp(hg)[0]
             # gradients pass through the bound grad-array dtype (the
             # phase-split path stores them there before the optimizer
             # reads them — bit-parity demands the same rounding). Only
@@ -1005,20 +1030,26 @@ class _GraphProgram:
             # die inside the program — emitting them would be pure
             # output-buffer overhead nothing consumes
             gs, grads_out = [], {}
-            for k in update_names:
-                g = grads[k].astype(params[k].dtype)
-                if k in add_names:
-                    g = add_grads[k] + g
-                    grads_out[k] = g
-                gs.append(g)
+            with jax.named_scope("backward"):
+                grads = vjp(hg)[0]
+                for k in update_names:
+                    g = grads[k].astype(params[k].dtype)
+                    if k in add_names:
+                        g = add_grads[k] + g
+                        grads_out[k] = g
+                    gs.append(g)
             ws = [params[k] for k in update_names]
-            new_ws, new_states = update_fn(ws, opt_states, gs, lrs, wds, ts)
+            with jax.named_scope("optimizer"):
+                new_ws, new_states = update_fn(ws, opt_states, gs, lrs,
+                                               wds, ts)
             new_params = dict(params)
             new_params.update(zip(update_names, new_ws))
             new_aux = dict(aux)
             new_aux.update({k: v for k, v in aux_up.items() if k in aux})
-            new_acc = metric_fn(outs, ins, metric_acc) if metric_fn \
-                else metric_acc
+            new_acc = metric_acc
+            if metric_fn:
+                with jax.named_scope("metric"):
+                    new_acc = metric_fn(outs, ins, metric_acc)
             return new_params, new_states, new_acc, new_aux, outs, grads_out
 
         step_argnames = ("params", "opt_states", "metric_acc", "aux",

@@ -351,6 +351,7 @@ class BaseModule:
         from ..checkpoint import TrainingPreempted
         from ..heartbeat import DeadWorkerError
         train_data.reset()
+        fit_step = 0        # batches since fit began: the profiler's step
         for epoch in range(begin_epoch, num_epoch):
             tic = time.time()
             eval_metric.reset()
@@ -387,12 +388,15 @@ class BaseModule:
                 # callbacks that read the metric).
                 # The causal() scope stamps (epoch, nbatch) step ids on
                 # every span this batch records (fit_batch, feed, step,
-                # opt_update, ...) so the merged chrome trace links one
-                # step's spans with flow arrows and a postmortem's ring
-                # says which step each interval served.
+                # opt_update, ...): they ride as stats of each span's
+                # annotation in a profiler trace, and a postmortem's
+                # ring says which step each interval served. fit_batch
+                # is the profiler's STEP annotation (its step view
+                # groups the device's work by it).
+                step_ids = telemetry.causal(epoch=epoch, nbatch=nbatch)
                 try:
-                    with telemetry.causal(epoch=epoch, nbatch=nbatch), \
-                            telemetry.span("fit_batch"):
+                    with step_ids, \
+                            telemetry.span("fit_batch", step_num=fit_step):
                         fused = self._fused_batch_step(data_batch,
                                                        eval_metric)
                         if not fused:
@@ -421,8 +425,9 @@ class BaseModule:
                         and not self.finite_check():   # mxlint: disable=host-sync -- opt-in divergence sentinel: the user asked for a blocking verdict once per divergence_check_every batches
                     self._handle_divergence(divergence_policy, ckpt,
                                             epoch, nbatch)
+                fit_step += 1
                 if batch_end_callback is not None:
-                    with telemetry.span("callbacks"):
+                    with step_ids, telemetry.span("callbacks"):
                         param = BatchEndParam(epoch=epoch, nbatch=nbatch,
                                               eval_metric=eval_metric,
                                               locals=locals())

@@ -142,62 +142,63 @@ class Module(BaseModule):
         if self.binded:
             self.logger.warning("Already bound, ignoring bind()")
             return
-        self.for_training = for_training
-        self.inputs_need_grad = inputs_need_grad
+        with telemetry.span("bind"):
+            self.for_training = for_training
+            self.inputs_need_grad = inputs_need_grad
 
-        self._data_shapes = [_as_desc(d) for d in data_shapes]
-        self._label_shapes = [_as_desc(l) for l in label_shapes] \
-            if label_shapes else []
+            self._data_shapes = [_as_desc(d) for d in data_shapes]
+            self._label_shapes = [_as_desc(l) for l in label_shapes] \
+                if label_shapes else []
 
-        shape_kwargs = {d.name: d.shape for d in self._data_shapes}
-        shape_kwargs.update({l.name: l.shape for l in self._label_shapes})
-        # input dtypes flow from DataDesc into the joint InferShape/Type
-        # pass, so a bf16 data desc binds a bf16 executor end to end.
-        # Labels included: without an explicit entry the inference pass
-        # would anchor the label var to the data dtype (bf16 truncates
-        # class indices > 256).
-        type_dict = {d.name: d.dtype
-                     for d in self._data_shapes + self._label_shapes
-                     if getattr(d, "dtype", None) is not None}
+            shape_kwargs = {d.name: d.shape for d in self._data_shapes}
+            shape_kwargs.update({l.name: l.shape for l in self._label_shapes})
+            # input dtypes flow from DataDesc into the joint InferShape/Type
+            # pass, so a bf16 data desc binds a bf16 executor end to end.
+            # Labels included: without an explicit entry the inference pass
+            # would anchor the label var to the data dtype (bf16 truncates
+            # class indices > 256).
+            type_dict = {d.name: d.dtype
+                         for d in self._data_shapes + self._label_shapes
+                         if getattr(d, "dtype", None) is not None}
 
-        reqs = {}
-        for name in self._symbol.list_arguments():
-            if name in self._data_names:
-                reqs[name] = "write" if inputs_need_grad else "null"
-            elif name in self._label_names or name in self._state_names:
-                reqs[name] = "null"
-            elif name in self._fixed_param_names:
-                reqs[name] = "null"
-            else:
-                reqs[name] = grad_req if for_training else "null"
-        self._grad_req = reqs
-        ctx = self._context[0]
-        g2c = self._group2ctxs
-        if isinstance(g2c, (list, tuple)):
-            # reference group2ctxs is a per-context list; the single-exec
-            # module uses the first entry
-            g2c = g2c[0] if g2c else None
-        if g2c and len(self._context) > 1:
-            # grouped programs pin ops to concrete devices (eager
-            # per-segment execution); the dp mesh shards ONE jitted
-            # program — the two placements are mutually exclusive
-            raise MXNetError(
-                "group2ctxs cannot be combined with a multi-device "
-                "context list; use a single context for model "
-                "parallelism or drop group2ctxs for data parallelism")
-        self._exec = self._symbol.simple_bind(ctx=ctx, grad_req=reqs,
-                                              type_dict=type_dict,
-                                              group2ctx=g2c,
-                                              **shape_kwargs)
-        if len(self._context) > 1:
-            self._init_mesh()
-        self.binded = True
-        if shared_module is not None and shared_module.params_initialized:
-            arg_p, aux_p = shared_module.get_params()
-            self.set_params(arg_p, aux_p)
-        elif self.params_initialized and self._arg_params is not None:
-            # Module.load path: checkpointed params install at bind time
-            self.set_params(self._arg_params, self._aux_params or {})
+            reqs = {}
+            for name in self._symbol.list_arguments():
+                if name in self._data_names:
+                    reqs[name] = "write" if inputs_need_grad else "null"
+                elif name in self._label_names or name in self._state_names:
+                    reqs[name] = "null"
+                elif name in self._fixed_param_names:
+                    reqs[name] = "null"
+                else:
+                    reqs[name] = grad_req if for_training else "null"
+            self._grad_req = reqs
+            ctx = self._context[0]
+            g2c = self._group2ctxs
+            if isinstance(g2c, (list, tuple)):
+                # reference group2ctxs is a per-context list; the single-exec
+                # module uses the first entry
+                g2c = g2c[0] if g2c else None
+            if g2c and len(self._context) > 1:
+                # grouped programs pin ops to concrete devices (eager
+                # per-segment execution); the dp mesh shards ONE jitted
+                # program — the two placements are mutually exclusive
+                raise MXNetError(
+                    "group2ctxs cannot be combined with a multi-device "
+                    "context list; use a single context for model "
+                    "parallelism or drop group2ctxs for data parallelism")
+            self._exec = self._symbol.simple_bind(ctx=ctx, grad_req=reqs,
+                                                  type_dict=type_dict,
+                                                  group2ctx=g2c,
+                                                  **shape_kwargs)
+            if len(self._context) > 1:
+                self._init_mesh()
+            self.binded = True
+            if shared_module is not None and shared_module.params_initialized:
+                arg_p, aux_p = shared_module.get_params()
+                self.set_params(arg_p, aux_p)
+            elif self.params_initialized and self._arg_params is not None:
+                # Module.load path: checkpointed params install at bind time
+                self.set_params(self._arg_params, self._aux_params or {})
 
     # -- multi-device mesh (TPU-native DataParallelExecutorGroup) ----------
     def _init_mesh(self):
@@ -456,41 +457,42 @@ class Module(BaseModule):
         """(parity: module.py init_params)"""
         if self.params_initialized and not force_init:
             return
-        assert self.binded, "call bind before init_params"
-        if arg_params is None and self._arg_params is not None:
-            arg_params = self._arg_params
-        if aux_params is None and self._aux_params is not None:
-            aux_params = self._aux_params
-        attrs = self._symbol.attr_dict()
+        with telemetry.span("init_params"):
+            assert self.binded, "call bind before init_params"
+            if arg_params is None and self._arg_params is not None:
+                arg_params = self._arg_params
+            if aux_params is None and self._aux_params is not None:
+                aux_params = self._aux_params
+            attrs = self._symbol.attr_dict()
 
-        for name, arr in self._exec.arg_dict.items():
-            if name in self._data_names or name in self._label_names \
-                    or name in self._state_names:
-                continue
-            given = (arg_params or {}).get(name)
-            if given is not None:
-                given.copyto(arr) if isinstance(given, NDArray) \
-                    else arr.__setitem__(slice(None), given)
-            elif not allow_missing or initializer is not None:
-                if initializer is None:
-                    if not allow_missing:
-                        raise MXNetError("no initializer and no value for %r"
-                                         % name)
+            for name, arr in self._exec.arg_dict.items():
+                if name in self._data_names or name in self._label_names \
+                        or name in self._state_names:
                     continue
-                desc = InitDesc(name, attrs.get(name))
-                initializer(desc, arr)
-        for name, arr in self._exec.aux_dict.items():
-            given = (aux_params or {}).get(name)
-            if given is not None:
-                given.copyto(arr)
-            elif initializer is not None:
-                desc = InitDesc(name, attrs.get(name))
-                initializer(desc, arr)
-        self.params_initialized = True
-        self._params_dirty = False
-        if self._mesh is not None:
-            # re-commit: initializer writes land on the default device
-            self._shard_exec_arrays()
+                given = (arg_params or {}).get(name)
+                if given is not None:
+                    given.copyto(arr) if isinstance(given, NDArray) \
+                        else arr.__setitem__(slice(None), given)
+                elif not allow_missing or initializer is not None:
+                    if initializer is None:
+                        if not allow_missing:
+                            raise MXNetError(
+                                "no initializer and no value for %r" % name)
+                        continue
+                    desc = InitDesc(name, attrs.get(name))
+                    initializer(desc, arr)
+            for name, arr in self._exec.aux_dict.items():
+                given = (aux_params or {}).get(name)
+                if given is not None:
+                    given.copyto(arr)
+                elif initializer is not None:
+                    desc = InitDesc(name, attrs.get(name))
+                    initializer(desc, arr)
+            self.params_initialized = True
+            self._params_dirty = False
+            if self._mesh is not None:
+                # re-commit: initializer writes land on the default device
+                self._shard_exec_arrays()
 
     def get_params(self):
         """(parity: module.get_params) returns host copies."""
@@ -508,42 +510,43 @@ class Module(BaseModule):
         assert self.binded and self.params_initialized
         if self.optimizer_initialized and not force_init:
             return
-        arg_dict = self._exec.arg_dict
-        kv, update_on_kvstore = _create_kvstore(
-            kvstore, len(self._context),
-            {n: arg_dict[n] for n in self._param_names})
+        with telemetry.span("init_optimizer"):
+            arg_dict = self._exec.arg_dict
+            kv, update_on_kvstore = _create_kvstore(
+                kvstore, len(self._context),
+                {n: arg_dict[n] for n in self._param_names})
 
-        if isinstance(optimizer, str):
-            idx2name = {i: n for i, n in enumerate(self._param_names)}
-            optimizer_params = dict(optimizer_params)
-            optimizer_params.setdefault("rescale_grad", 1.0)
-            optimizer = opt.create(optimizer, sym=self._symbol,
-                                   param_idx2name=idx2name,
-                                   **optimizer_params)
-        self._optimizer = optimizer
-        if kv is not None:
-            if kv.type.startswith("dist"):
-                # EVERY dist_* type runs the optimizer kvstore-side
-                # (reference semantics: the server applies updates for
-                # dist_sync, dist_sync_device, dist_device_sync AND
-                # dist_async alike). The old predicate named only
-                # "dist_sync" and let the other dist types ride
-                # whatever _create_kvstore defaulted to — the same
-                # outcome today, silently, and one heuristic change
-                # away from divergent update paths across workers.
-                update_on_kvstore = True
-            for i, name in enumerate(self._param_names):
-                kv.init(i, arg_dict[name])
-            if update_on_kvstore:
-                kv.set_optimizer(self._optimizer)
-        self._kvstore = kv
-        self._update_on_kvstore = update_on_kvstore
-        self._updater = None
-        if not update_on_kvstore:
-            self._updater = opt.get_updater(optimizer)
-        if kv is not None and kv.fused_dist_step:
-            self._init_dist_spec()
-        self.optimizer_initialized = True
+            if isinstance(optimizer, str):
+                idx2name = {i: n for i, n in enumerate(self._param_names)}
+                optimizer_params = dict(optimizer_params)
+                optimizer_params.setdefault("rescale_grad", 1.0)
+                optimizer = opt.create(optimizer, sym=self._symbol,
+                                       param_idx2name=idx2name,
+                                       **optimizer_params)
+            self._optimizer = optimizer
+            if kv is not None:
+                if kv.type.startswith("dist"):
+                    # EVERY dist_* type runs the optimizer kvstore-side
+                    # (reference semantics: the server applies updates for
+                    # dist_sync, dist_sync_device, dist_device_sync AND
+                    # dist_async alike). The old predicate named only
+                    # "dist_sync" and let the other dist types ride
+                    # whatever _create_kvstore defaulted to — the same
+                    # outcome today, silently, and one heuristic change
+                    # away from divergent update paths across workers.
+                    update_on_kvstore = True
+                for i, name in enumerate(self._param_names):
+                    kv.init(i, arg_dict[name])
+                if update_on_kvstore:
+                    kv.set_optimizer(self._optimizer)
+            self._kvstore = kv
+            self._update_on_kvstore = update_on_kvstore
+            self._updater = None
+            if not update_on_kvstore:
+                self._updater = opt.get_updater(optimizer)
+            if kv is not None and kv.fused_dist_step:
+                self._init_dist_spec()
+            self.optimizer_initialized = True
 
     # -- compute -----------------------------------------------------------
     def forward(self, data_batch, is_train=None):
@@ -1078,91 +1081,98 @@ class Module(BaseModule):
 
         # host-side bookkeeping exactly as the phase-split update() does
         # it — same Updater states, same count/lr/wd schedule, so a
-        # fallback mid-training continues seamlessly
-        indices = plan["indices"]
-        for i in indices:
-            optimizer._update_count(i)
-        counts = optimizer._index_update_count
-        ts = np.asarray([counts[i] for i in indices], np.float32)
-        lrs = np.asarray([optimizer._get_lr(i) for i in indices], np.float32)
-        wds = np.asarray([optimizer._get_wd(i) for i in indices], np.float32)
+        # fallback mid-training continues seamlessly. ``step_prep``
+        # runs from the end of the feed to the dispatch: these loops
+        # over every parameter are host time a span has to name
+        with telemetry.span("step_prep"):
+            indices = plan["indices"]
+            for i in indices:
+                optimizer._update_count(i)
+            counts = optimizer._index_update_count
+            ts = np.asarray([counts[i] for i in indices], np.float32)
+            lrs = np.asarray([optimizer._get_lr(i) for i in indices],
+                             np.float32)
+            wds = np.asarray([optimizer._get_wd(i) for i in indices],
+                             np.float32)
 
-        params_raw = {n: arg_dict[n]._data for n in self._param_names}
-        states_raw = [tuple(x._data for x in tup) for tup in packed]
-        aux_raw = {n: a._data for n, a in zip(ex._aux_names, ex.aux_arrays)}
-        grad_dict = ex.grad_dict
-        add_names = plan["add_names"]
-        add_grads = {n: grad_dict[n]._data for n in add_names}
-        acc = None
-        if kernel is not None:
-            acc = getattr(eval_metric, "_dev_sum", None)
-            if acc is None:
-                import jax.numpy as jnp
-                # a fresh accumulator commits to the module's placement
-                # (the mesh program reshards via in_shardings; a single-
-                # device module must not introduce a default-device
-                # operand)
-                acc = jnp.zeros((), jnp.float32)
-                if spanning:
-                    acc = _spmd.put_replicated_local(acc, spec)
-                elif dev is not None:
-                    acc = jax.device_put(acc, dev)
-        rng = ex._step_key()
-        if spanning:
-            # per-step scalars install as replicated WITHOUT a
-            # collective (every worker computes identical values —
-            # the SPMD discipline put_replicated_local documents);
-            # letting jit auto-commit them would pay a cross-host
-            # equality collective per array per step
-            rng = _spmd.put_replicated_local(rng, spec)
-            lrs = _spmd.put_replicated_local(lrs, spec)
-            wds = _spmd.put_replicated_local(wds, spec)
-            ts = _spmd.put_replicated_local(ts, spec)
+            params_raw = {n: arg_dict[n]._data for n in self._param_names}
+            states_raw = [tuple(x._data for x in tup) for tup in packed]
+            aux_raw = {n: a._data
+                       for n, a in zip(ex._aux_names, ex.aux_arrays)}
+            grad_dict = ex.grad_dict
+            add_names = plan["add_names"]
+            add_grads = {n: grad_dict[n]._data for n in add_names}
+            acc = None
+            if kernel is not None:
+                acc = getattr(eval_metric, "_dev_sum", None)
+                if acc is None:
+                    import jax.numpy as jnp
+                    # a fresh accumulator commits to the module's placement
+                    # (the mesh program reshards via in_shardings; a single-
+                    # device module must not introduce a default-device
+                    # operand)
+                    acc = jnp.zeros((), jnp.float32)
+                    if spanning:
+                        acc = _spmd.put_replicated_local(acc, spec)
+                    elif dev is not None:
+                        acc = jax.device_put(acc, dev)
+            rng = ex._step_key()
+            if spanning:
+                # per-step scalars install as replicated WITHOUT a
+                # collective (every worker computes identical values —
+                # the SPMD discipline put_replicated_local documents);
+                # letting jit auto-commit them would pay a cross-host
+                # equality collective per array per step
+                rng = _spmd.put_replicated_local(rng, spec)
+                lrs = _spmd.put_replicated_local(lrs, spec)
+                wds = _spmd.put_replicated_local(wds, spec)
+                ts = _spmd.put_replicated_local(ts, spec)
 
         record_dispatch("train_step")
         with telemetry.span("step"):
             new_params, new_states, new_acc, new_aux, outs, grads_out = \
                 plan["fn"](params_raw, states_raw, acc, aux_raw, inputs, rng,   # mxlint: donates 0-3
                            lrs, wds, ts, add_grads)
-        if spanning:
-            # the in-program cross-host psum IS the dist wire now:
-            # account it next to the explicit push path's counters, and
-            # keep a handle for the pre-gate sync of the NEXT step
-            self._dist_sync_handle = \
-                new_params[plan["update_names"][0]] \
-                if plan["update_names"] else None
-            telemetry.counter_inc("kvstore.dist.fused_steps")
-            telemetry.counter_inc("kvstore.dist.collectives")
-            telemetry.counter_inc("kvstore.dist.wire_bytes",
-                                  plan["dist_wire_bytes"])
-            telemetry.counter_inc("kvstore.dist.wire_bytes_raw",
-                                  plan["dist_wire_bytes"])
+        with telemetry.span("step_install"):
+            if spanning:
+                # the in-program cross-host psum IS the dist wire now:
+                # account it next to the explicit push path's counters, and
+                # keep a handle for the pre-gate sync of the NEXT step
+                self._dist_sync_handle = \
+                    new_params[plan["update_names"][0]] \
+                    if plan["update_names"] else None
+                telemetry.counter_inc("kvstore.dist.fused_steps")
+                telemetry.counter_inc("kvstore.dist.collectives")
+                telemetry.counter_inc("kvstore.dist.wire_bytes",
+                                      plan["dist_wire_bytes"])
+                telemetry.counter_inc("kvstore.dist.wire_bytes_raw",
+                                      plan["dist_wire_bytes"])
 
-        # donation invalidated the old buffers — reinstall everything
-        for n in self._param_names:
-            arg_dict[n]._set_data(new_params[n])
-        for tup, ntup in zip(packed, new_states):
-            for x, nx in zip(tup, ntup):
-                x._set_data(nx)
-        for n, a in zip(ex._aux_names, ex.aux_arrays):
-            a._set_data(new_aux[n])
-        # only 'add' accumulators come back (next step's input); 'write'
-        # grads are consumed inside the program and never materialized
-        # (add_grads above already established every 'add' grad exists)
-        for n in add_names:
-            grad_dict[n]._set_data(grads_out[n])
-        # subsumed update_on_kvstore: refresh the store's canonical
-        # weight copies (pointer swaps — no device work)
-        for n, store_arr in plan["store_sync"]:
-            store_arr._set_data(new_params[n])
-        ex.outputs = [_wrap(o, ex._out_ctx(i)) for i, o in enumerate(outs)]
-        if kernel is not None:
-            n_inst = sum(int(r.size) for r in label_raws)
-            eval_metric._install_fused(new_acc, n_inst)
-        elif eval_metric is not None:
-            self.update_metric(eval_metric, data_batch.label)
-        self._params_dirty = True
-        self._fused_fallback_reason = None
+            # donation invalidated the old buffers — reinstall everything
+            for n in self._param_names:
+                arg_dict[n]._set_data(new_params[n])
+            for tup, ntup in zip(packed, new_states):
+                for x, nx in zip(tup, ntup):
+                    x._set_data(nx)
+            for n, a in zip(ex._aux_names, ex.aux_arrays):
+                a._set_data(new_aux[n])
+            # only 'add' accumulators come back (next step's input); 'write'
+            # grads are consumed inside the program and never materialized
+            # (add_grads above already established every 'add' grad exists)
+            for n in add_names:
+                grad_dict[n]._set_data(grads_out[n])
+            # subsumed update_on_kvstore: refresh the store's canonical
+            # weight copies (pointer swaps — no device work)
+            for n, store_arr in plan["store_sync"]:
+                store_arr._set_data(new_params[n])
+            ex.outputs = [_wrap(o, ex._out_ctx(i)) for i, o in enumerate(outs)]
+            if kernel is not None:
+                n_inst = sum(int(r.size) for r in label_raws)
+                eval_metric._install_fused(new_acc, n_inst)
+            elif eval_metric is not None:
+                self.update_metric(eval_metric, data_batch.label)
+            self._params_dirty = True
+            self._fused_fallback_reason = None
         return True
 
     def get_outputs(self, merge_multi_context=True):

@@ -3,13 +3,20 @@
 Parity: reference ``src/engine/profiler.{h,cc}`` + ``python/mxnet/
 profiler.py`` (SURVEY.md §5.1; chrome://tracing JSON output). TPU-native
 design: wraps the JAX/XLA profiler, which records real device op spans
-(the reference stamped engine-op spans), and MERGES the telemetry host
-spans (feed/shard_put/step/metric_fetch/io_next/...) into the same
-chrome-trace JSON, so ``dump()`` yields ONE perfetto-loadable file where
-the host timeline (what Python dispatched when) lines up against the
-device timeline (what XLA executed when) — the view that found the 14x
-``Module.fit`` gap (PERF.md). TensorBoard-compatible artifacts stay in
-the output directory.
+(the reference stamped engine-op spans). ONE CLOCK: importing this
+module installs ``jax.profiler.TraceAnnotation`` as telemetry's
+annotation factory, so every same-thread telemetry span (fit_batch/
+feed/step_prep/step/step_install/io_next/bind/...) is written by the
+profiler itself, on ``/host:CPU`` beside the device planes and on
+their clock, with its causal ids (``epoch``, ``nbatch``) as stats —
+whoever started the trace (``set_state('run')``, a bare
+``jax.profiler.start_trace``, a benchmark). ``fit_batch`` is a
+``StepTraceAnnotation``, which the profiler's step view groups by.
+Only spans that cross threads (the serving request chain) and
+retroactive ones are MERGED from telemetry's ring into the chrome
+JSON, with their request flow arrows; their alignment to the device
+clock is a guess by magnitude (``_aligned_host_events``).
+TensorBoard-compatible artifacts stay in the output directory.
 """
 from __future__ import annotations
 
@@ -20,12 +27,26 @@ import time
 
 import jax
 
-from .base import MXNetError, get_env
+from . import telemetry
+from .base import MXNetError
 
 __all__ = ["profiler_set_config", "profiler_set_state", "set_config",
            "set_state", "dump", "pause", "resume"]
 
 _state = {"running": False, "filename": "profile.json", "dir": None}
+
+
+def _annotation(name, ids, step_num):
+    """Telemetry's annotation factory: the profiler-side half of a
+    same-thread span, its causal ids riding as the event's stats."""
+    ids = ids or {}
+    if step_num is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=step_num,
+                                                **ids)
+    return jax.profiler.TraceAnnotation(name, **ids)
+
+
+telemetry._annotation = _annotation
 
 
 def set_config(profile_all=None, profile_symbolic=None,
@@ -47,7 +68,6 @@ def set_state(state="stop", profile_process="worker"):
             jax.profiler.start_trace(out_dir)
             # stamp the host-span window: the merged dump keeps only
             # spans recorded while the device trace ran
-            from . import telemetry
             telemetry.mark_trace_start()
             _state["dir"] = out_dir
             _state["running"] = True
@@ -63,31 +83,23 @@ def set_state(state="stop", profile_process="worker"):
 profiler_set_state = set_state
 
 
-def _host_events():
-    """Telemetry host spans as chrome events — including the causal
-    FLOW events (``ph: s/t/f``) linking one serving request's or one
-    fit step's spans across threads; the alignment shift below applies
-    to those too (they carry ``ts`` like every slice)."""
-    from . import telemetry
-    return telemetry.chrome_events()
-
-
 # any epoch-microsecond stamp after ~1973 exceeds this; a trace-relative
 # stamp would need a ~3-year-long trace to reach it
 _EPOCH_TS_FLOOR_US = 1e14
 
 
 def _aligned_host_events(device_events, host):
-    """Host span events on the device trace's timebase. Telemetry stamps
-    spans in epoch microseconds; XLA's trace converter may emit epoch-
-    based OR trace-relative timestamps depending on version. The two
-    cases are separated by MAGNITUDE (epoch stamps are ~1.7e15 us;
-    trace-relative ones start near zero — a first-device-op gap, e.g. a
-    minutes-long in-window compile, cannot cross that line): epoch-based
-    device stamps need no adjustment; trace-relative ones get the host
-    events shifted so the trace-start instant maps onto the earliest
-    device timestamp."""
-    from . import telemetry
+    """Ring-only span events (cross-thread request spans, retroactive
+    ``record_span`` ones, their flows) on the device trace's timebase,
+    by a GUESS: same-thread spans need none, the profiler stamps them.
+    Telemetry stamps spans in epoch microseconds; XLA's trace converter
+    may emit epoch-based OR trace-relative timestamps depending on
+    version. The two cases are separated by MAGNITUDE (epoch stamps are
+    ~1.7e15 us; trace-relative ones start near zero — a first-device-op
+    gap, e.g. a minutes-long in-window compile, cannot cross that
+    line): epoch-based device stamps need no adjustment; trace-relative
+    ones get the host events shifted so the trace-start instant maps
+    onto the earliest device timestamp."""
     t0_us = telemetry.trace_start_epoch_us()
     dts = [e["ts"] for e in device_events
            if e.get("ph") in ("X", "B") and "ts" in e]
@@ -106,21 +118,34 @@ def _aligned_host_events(device_events, host):
 def _link_chrome_trace():
     """Surface the chrome trace at the configured filename as plain JSON
     — the reference emits an uncompressed chrome://tracing file
-    (profiler.cc:161) — with the telemetry HOST spans merged into the
-    device event list (one perfetto view, host track above the device
-    tracks). When the backend produced no ``.trace.json.gz`` (some
-    platforms/versions skip the converter), a host-span-only trace is
-    still written so the configured filename always materialises."""
+    (profiler.cc:161) — with the telemetry spans the profiler did NOT
+    write itself (those that cross threads, with their request flows)
+    merged into the device event list; the same-thread spans are in the
+    device dump already, as annotations. When the backend produced no
+    ``.trace.json.gz`` (some platforms/versions skip the converter), a
+    trace of every ring span is still written so the configured
+    filename always materialises."""
     out_dir = _state["dir"]
     if not out_dir:
         return
     matches = glob.glob(os.path.join(out_dir, "**", "*.trace.json.gz"),
                         recursive=True)
-    host = _host_events()
-    if matches and not any(e.get("ph") == "X" for e in host):
-        # nothing to merge (telemetry disabled / empty span window):
-        # stream the device dump through verbatim instead of paying a
-        # full parse+re-serialize of a potentially huge trace
+    host = telemetry.chrome_events(skip_annotated=bool(matches))
+    # what rides in the file's otherData (a chrome-trace field perfetto
+    # preserves): the program cards (cost/memory/compile figures of
+    # every program dispatched) and the flight recorder's recent
+    # time-series window, so one file carries timeline, cost model AND
+    # the metrics trajectory around the captured window
+    from . import flight
+    other = {"mxnet_tpu_programs": telemetry.programs(),
+             "mxnet_tpu_series": flight.series(240)}
+    other = {k: v for k, v in other.items() if v}
+    if not any(e.get("ph") == "X" for e in host):
+        host = []           # no slice to merge: no empty track either
+    if matches and not other and not host:
+        # nothing to add (telemetry disabled): stream the device dump
+        # through verbatim instead of paying a full parse+re-serialize
+        # of a potentially huge trace
         import gzip
         import shutil
         with gzip.open(sorted(matches)[-1], "rb") as src, \
@@ -146,25 +171,8 @@ def _link_chrome_trace():
         trace = {"traceEvents": events, "displayTimeUnit": "ms"}
     trace["traceEvents"].extend(
         _aligned_host_events(trace["traceEvents"], host))
-    # program cards ride in the trace file's otherData (a chrome-trace
-    # field perfetto preserves): the cost/memory/compile figures of
-    # every program whose spans appear on the host track, so one file
-    # carries timeline AND cost model
-    from . import telemetry
-    cards = telemetry.programs()
-    if cards:
-        other = trace.setdefault("otherData", {})
-        if isinstance(other, dict):
-            other["mxnet_tpu_programs"] = cards
-    # the flight recorder's recent time-series window rides too (when
-    # the sampler ran): the trace then carries timeline, cost model AND
-    # the metrics trajectory around the captured window
-    from . import flight
-    samples = flight.series(240)
-    if samples:
-        other = trace.setdefault("otherData", {})
-        if isinstance(other, dict):
-            other["mxnet_tpu_series"] = samples
+    if other and isinstance(trace.setdefault("otherData", {}), dict):
+        trace["otherData"].update(other)
     with open(_state["filename"], "w") as dst:
         json.dump(trace, dst)
 
@@ -183,32 +191,11 @@ def resume(profile_process="worker"):
     pass
 
 
-class Scope:
-    """Annotate a region so it shows up in the device trace
-    (jax.profiler.TraceAnnotation under the hood) AND as a telemetry
-    host span (so the region also lands in the merged chrome dump and
-    the snapshot percentiles)."""
-
-    def __init__(self, name):
-        self._ann = jax.profiler.TraceAnnotation(name)
-        from . import telemetry
-        self._span = telemetry.span(name)
-
-    def __enter__(self):
-        self._span.__enter__()
-        try:
-            self._ann.__enter__()
-        except BaseException:
-            # the device annotation failing to arm (profiler state,
-            # backend teardown) must not leave the host span entered
-            # forever — every entered span exits (mxlife)
-            self._span.__exit__(None, None, None)
-            raise
-        return self
-
-    def __exit__(self, *exc):
-        self._ann.__exit__(*exc)
-        self._span.__exit__(*exc)
+def Scope(name):
+    """Annotate a region: a telemetry span, hence an annotation in the
+    profiler's trace AND an entry in the ring and the snapshot
+    percentiles (parity: the reference's ``profiler.Scope``)."""
+    return telemetry.span(name)
 
 
 def dump_profile():

@@ -19,18 +19,24 @@ is the standing instrument every perf PR reads from:
   ``executor.dispatch_hook`` global (which probe, tests and telemetry
   silently clobbered off each other; the legacy name still works as a
   back-compat shim read by ``executor.record_dispatch``);
-* **chrome-trace export** — ``chrome_events()`` renders the span ring
-  as chrome://tracing ``X`` events; ``profiler.py`` merges them into
-  the XLA device dump so host and device timelines land in ONE
-  perfetto-loadable JSON;
+* **one clock with the device trace** — a span entered and left on
+  one thread also enters a profiler annotation (``profiler.py``
+  installs the factory, ``jax.profiler.TraceAnnotation``; this module
+  never imports jax), so it is written into the profiler's own trace,
+  on the device trace's clock, with its causal ids as stats. Spans
+  that cross threads (an explicit ``ctx=``) and retroactive
+  ``record_span`` ones stay ring-only; ``chrome_events()`` renders
+  those as chrome://tracing ``X`` events and ``profiler.py`` merges
+  them into the device dump;
 * a **program-card registry** — every XLA program the executor
   compiles deposits a card (``record_program``) carrying its abstract
   input signature, trace/compile wall-time, ``cost_analysis`` FLOPs/
   bytes and ``memory_analysis`` footprint; ``program_dispatch`` bumps
   the card's dispatch count per launch, and ``snapshot()`` derives an
   ONLINE sustained-FLOP/s (and MFU, once ``set_peak_flops`` is told
-  the chip's ceiling) from card FLOPs x dispatches / step-span time —
-  the live counterpart of PERF.md's offline roofline table. Cards are
+  the chip's ceiling) from card FLOPs x dispatches over the wall time
+  between the first and the newest dispatch — the live counterpart of
+  PERF.md's offline roofline table. Cards are
   plain JSON-safe dicts built by executor.py (this module stays
   stdlib-only and never imports jax);
 * a **live device-buffer ledger** — ``ledger_track(obj, ...)`` charges
@@ -45,10 +51,10 @@ is the standing instrument every perf PR reads from:
   serves. ``serving.submit()`` stamps a ``req_id`` that rides the
   request through coalesce → batch dispatch → d2h → resolve (batch
   spans carry the member ``req_ids``), ``Module.fit`` stamps
-  ``(epoch, nbatch)`` onto feed/step/opt spans, and
-  ``chrome_events()`` renders the shared ids as chrome-trace FLOW
-  events (``ph: s/t/f``) so perfetto draws arrows linking one
-  request's or step's spans across threads;
+  ``(epoch, nbatch)`` onto feed/step/opt spans (they ride as stats of
+  each span's annotation), and ``chrome_events()`` renders shared
+  request ids as chrome-trace FLOW events (``ph: s/t/f``) so perfetto
+  draws arrows linking one request's spans across threads;
 * an **event ring** — ``record_event(kind, **data)`` appends one
   discrete runtime event (a fault firing, a shed, a breaker trip, a
   checkpoint save) into a bounded ring; together with
@@ -84,6 +90,7 @@ __all__ = [
     "card_annotate",
     "set_peak_flops", "ledger_track", "ledger", "ledger_top",
     "SPAN_RING_SIZE", "EVENT_RING_SIZE", "FIT_PHASE_SPANS",
+    "SETUP_SPANS",
     "SERVE_SPANS", "DECODE_SPANS", "COMPILE_SPANS",
     "MAX_PROGRAM_CARDS", "COUNTERS",
 ]
@@ -103,10 +110,15 @@ EVENT_RING_SIZE = 2048
 # the fit-loop phase span names — the ONE list the bench/probe artifact
 # summaries filter on, kept next to the code that records them so the
 # BENCH/MULTICHIP accountings can't silently diverge
-FIT_PHASE_SPANS = ("fit_batch", "feed", "step", "shard_put",
+FIT_PHASE_SPANS = ("fit_batch", "feed", "step_prep", "step",
+                   "step_install", "shard_put",
                    "metric_update", "metric_fetch", "opt_update",
                    "io_next", "callbacks", "epoch_sync",
                    "kv_push", "kv_pull")
+
+# Module set-up, once per bind: what a process spends before its first
+# step beside compilation
+SETUP_SPANS = ("bind", "init_params", "init_optimizer")
 
 # the serving-path span names (mxnet_tpu/serving.py): request time in
 # queue, program dispatch per coalesced batch, the blocking d2h fetch,
@@ -192,10 +204,12 @@ class _State:
 _state = _State()
 _lock = threading.Lock()
 _counters = {}           # guarded by: _lock
-# span ring: (name, start_ns, end_ns, thread_id, causal_ctx_or_None)
-# in perf_counter_ns time. Appends are deliberately LOCK-FREE
-# (GIL-atomic deque ops on the per-batch hot path); see the
-# _record_span disables.
+# span ring: (name, start_ns, end_ns, thread_id, causal_ctx_or_None,
+# annotated) in perf_counter_ns time; ``annotated`` marks a span that
+# was also written as a profiler annotation (its one path into a
+# trace: the merged chrome dump leaves it to the device dump). Appends
+# are deliberately LOCK-FREE (GIL-atomic deque ops on the per-batch hot
+# path); see the _record_span disables.
 _spans = collections.deque(maxlen=SPAN_RING_SIZE)   # guarded by: _lock
 # event ring: (perf_ns, kind, data_dict_or_None, thread_id). Appends
 # are lock-free for the same hot-path reason (some events fire under
@@ -205,12 +219,15 @@ _events = collections.deque(maxlen=EVENT_RING_SIZE)  # guarded by: _lock
 # per-thread causal ids (req_id / epoch+nbatch) stamped onto spans
 # recorded while a causal() scope is active on that thread
 _tls = threading.local()
+# ``factory(name, ids_or_None, step_num_or_None)`` -> a context manager
+# writing into the profiler's trace. Set ONCE, by ``profiler.py`` at
+# import (this module stays off jax); None = ring only.
+_annotation = None
 _durations = {}          # name -> deque of durations  # guarded by: _lock
 _span_total = {}         # name -> cumulative count    # guarded by: _lock
 _span_seconds = {}       # guarded by: _lock
-                         # name -> cumulative span seconds (uncapped) —
-                         # the online-MFU denominator must cover EVERY
-                         # step, not just the histogram ring's tail
+                         # name -> cumulative span seconds (uncapped by
+                         # the histogram ring)
 _dispatch_subs = []      # guarded by: _lock
 _gen = 0                 # guarded by: _lock
                          # bumped by reset(): spans straddling a reset
@@ -225,6 +242,12 @@ _gen = 0                 # guarded by: _lock
 _programs = {}                  # guarded by: _lock
 _programs_dropped_flops = 0.0   # guarded by: _lock
 _peak_flops = None              # guarded by: _lock
+# the online estimate's clock: perf_counter_ns of the window's first
+# and newest carded dispatch, and the first one's FLOPs (its interval
+# lies before the clock starts)
+_dispatch_t0_ns = None          # guarded by: _lock
+_dispatch_t1_ns = None          # guarded by: _lock
+_dispatch_flops0 = 0.0          # guarded by: _lock
 
 # live device-buffer ledger: per-context alive/peak counters plus the
 # individual live-buffer map that backs ledger_top() / OOM enrichment
@@ -287,9 +310,10 @@ def reset():
     buffers are still alive and their finalizers will still fire);
     its cumulative totals zero and peak rebases to the current alive
     level, so a windowed reader sees this window's high-water mark."""
-    global _gen, _programs_dropped_flops
+    global _gen, _programs_dropped_flops, _dispatch_t0_ns, _dispatch_t1_ns
     with _lock:
         _gen += 1
+        _dispatch_t0_ns = _dispatch_t1_ns = None
         _counters.clear()
         _spans.clear()
         _events.clear()
@@ -469,7 +493,7 @@ class _Causal:
 def causal(**ids):
     """``with telemetry.causal(epoch=2, nbatch=17): ...`` — every span
     recorded on THIS thread inside the scope carries the given ids
-    (``chrome_events()`` renders shared ids as flow arrows; postmortems
+    (they ride as stats of each span's profiler annotation; postmortems
     and ``tools/flight_view.py`` group the ring by them). Spans that
     cross threads pass ``span(name, ctx=...)`` explicitly instead."""
     return _Causal(ids)
@@ -519,7 +543,7 @@ def recent_spans(n=None):
     return [{"name": name, "ts": round(_epoch_us(s_ns) / 1e6, 6),
              "dur_ms": round((e_ns - s_ns) / 1e6, 4), "tid": tid,
              "ctx": None if ctx is None else dict(ctx)}
-            for name, s_ns, e_ns, tid, ctx in spans]
+            for name, s_ns, e_ns, tid, ctx, _ann in spans]
 
 
 # ---------------------------------------------------------------------------
@@ -527,49 +551,78 @@ def recent_spans(n=None):
 # ---------------------------------------------------------------------------
 
 class _Span:
-    """Reentrant-per-instance-free timing scope; ~two perf_counter_ns
-    calls + two deque appends when enabled, two attribute reads when
-    disabled. ``ctx`` pins explicit causal ids (for spans that are
-    entered on one thread and exited on another, e.g. the serving
-    request spans); without it the recording thread's ambient
-    ``causal()`` ids are captured at ENTER."""
-    __slots__ = ("name", "_t0", "_gen", "_ctx")
+    """Timing scope: two ``perf_counter_ns`` stamps + a ring append
+    when enabled, two attribute reads when disabled. ``ctx`` pins
+    explicit causal ids (for spans that are entered on one thread and
+    exited on another, e.g. the serving request spans); without it the
+    recording thread's ambient ``causal()`` ids are captured at ENTER.
 
-    def __init__(self, name, ctx=None):
+    A span without ``ctx=`` lives on one thread, so it also enters a
+    profiler annotation (``_annotation``, installed by ``profiler.py``)
+    carrying the ids as stats: it lands in the profiler's own trace on
+    the device trace's clock. With no profiler session the annotation
+    is one flag test in C++. ``step_num`` makes it a step annotation
+    (the profiler's step view groups device work by it)."""
+    __slots__ = ("name", "_t0", "_gen", "_ctx", "_pinned", "_step",
+                 "_ann")
+
+    def __init__(self, name, ctx=None, step_num=None):
         self.name = name
         self._t0 = 0
         self._ctx = ctx
+        self._pinned = ctx is not None
+        self._step = step_num
+        self._ann = None
 
     def __enter__(self):
         if _state.enabled:
             self._t0 = time.perf_counter_ns()
             self._gen = _gen   # mxlint: disable=lock-discipline -- single GIL-atomic int read; a torn window only drops this one span
-            if self._ctx is None:
+            if not self._pinned:
                 self._ctx = getattr(_tls, "ids", None)
+                if _annotation is not None:
+                    try:
+                        ann = _annotation(self.name, self._ctx, self._step)
+                        ann.__enter__()
+                        self._ann = ann
+                    except Exception:
+                        # an annotation that fails to arm (profiler
+                        # teardown) costs the trace one slice, never
+                        # the program its step: the ring still records
+                        self._ann = None
         return self
 
     def cancel(self):
         """Drop this span: nothing is recorded at scope exit (e.g. an
-        epoch-end StopIteration is not io time)."""
+        epoch-end StopIteration is not io time). Its annotation, if a
+        profiler session took it, cannot be recalled."""
         self._t0 = 0
 
     def __exit__(self, *exc):
+        t0 = self._t0
+        t1 = time.perf_counter_ns() if t0 else 0
+        ann = self._ann
+        if ann is not None:     # a cancelled span still leaves its scope
+            self._ann = None
+            ann.__exit__(*exc)
         # record only if telemetry is STILL enabled (a disable() mid-
         # span pins the disabled leg clean) and no reset() started a
         # new accounting window while this span was open
-        if self._t0 and _state.enabled and self._gen == _gen:   # mxlint: disable=lock-discipline -- single GIL-atomic int compare; worst case one pre-reset span drops
-            _record_span(self.name, self._t0, time.perf_counter_ns(),
-                         self._ctx)
+        if t0 and _state.enabled and self._gen == _gen:   # mxlint: disable=lock-discipline -- single GIL-atomic int compare; worst case one pre-reset span drops
+            _record_span(self.name, t0, t1, self._ctx, ann is not None)
         self._t0 = 0
         return False
 
 
-def span(name, ctx=None):
+def span(name, ctx=None, step_num=None):
     """``with telemetry.span("feed"): ...`` — record one host wall-time
-    interval into the ring buffer and the per-name histogram. ``ctx``
-    attaches explicit causal ids (defaults to the recording thread's
-    ambient ``causal()`` scope)."""
-    return _Span(name, ctx)
+    interval into the ring buffer and the per-name histogram, and (for
+    a same-thread span, i.e. without ``ctx=``) into the profiler's
+    trace as an annotation. ``ctx`` attaches explicit causal ids
+    (defaults to the recording thread's ambient ``causal()`` scope) and
+    keeps the span ring-only: it may be left on another thread.
+    ``step_num`` marks a training step for the profiler's step view."""
+    return _Span(name, ctx, step_num)
 
 
 def record_span(name, t0_ns, t1_ns, ctx=None):
@@ -577,17 +630,19 @@ def record_span(name, t0_ns, t1_ns, ctx=None):
     endpoints) retroactively — for callers that only learn a span's
     identity AFTER it ended: the collective gate knows which rank it
     waited on (and by how much) only once the wait resolves, yet the
-    ``gate_wait`` span must carry that attribution in its ctx."""
+    ``gate_wait`` span must carry that attribution in its ctx. Ring
+    only: an annotation cannot be written after the fact."""
     if not _state.enabled:
         return
     _record_span(name, int(t0_ns), int(t1_ns), dict(ctx) if ctx else None)
 
 
-def _record_span(name, t0_ns, t1_ns, ctx=None):
+def _record_span(name, t0_ns, t1_ns, ctx=None, annotated=False):
     # deque.append and dict reads are GIL-atomic so the ring/histogram
     # writes stay lock-free; the cumulative counter is a read-modify-
     # write and takes the lock like every other counter
-    _spans.append((name, t0_ns, t1_ns, threading.get_ident(), ctx))   # mxlint: disable=lock-discipline -- GIL-atomic bounded-deque append on the per-batch hot path
+    _spans.append((name, t0_ns, t1_ns, threading.get_ident(), ctx,   # mxlint: disable=lock-discipline -- GIL-atomic bounded-deque append on the per-batch hot path
+                   annotated))
     d = _durations.get(name)   # mxlint: disable=lock-discipline -- GIL-atomic dict probe; the insert below re-checks under the lock
     if d is None:
         with _lock:
@@ -603,7 +658,7 @@ def _record_span(name, t0_ns, t1_ns, ctx=None):
 def span_seconds(name):
     """CUMULATIVE wall-seconds recorded under ``name`` since the last
     reset() — unlike the histogram total, not capped by the duration
-    ring. The online-MFU denominator."""
+    ring."""
     with _lock:
         return _span_seconds.get(name, 0.0)
 
@@ -699,14 +754,20 @@ def program_dispatch(card):
     reset() opened a new accounting window since the card was
     installed, the count restarts and the card re-registers — so a
     windowed snapshot reads only this window's dispatches."""
+    global _dispatch_t0_ns, _dispatch_t1_ns, _dispatch_flops0
     if not _state.enabled or card is None:
         return
+    now = time.perf_counter_ns()
     with _lock:
         if card.get("_gen") != _gen:
             card["dispatches"] = 0
             card["_gen"] = _gen
             _programs[card["id"]] = card
         card["dispatches"] = card.get("dispatches", 0) + 1
+        if _dispatch_t0_ns is None:
+            _dispatch_t0_ns = now
+            _dispatch_flops0 = card.get("flops") or 0.0
+        _dispatch_t1_ns = now
 
 
 def card_update(card, **fields):
@@ -744,9 +805,15 @@ def programs():
 
 def _online_stats():
     """The live roofline estimate: FLOPs dispatched (card FLOPs x
-    dispatch count, plus evicted cards' share) over cumulative
-    step-span wall-time. ``mfu`` needs ``set_peak_flops`` — the chip
-    ceiling is not knowable from stdlib."""
+    dispatch count, plus evicted cards' share) after the window's first
+    carded dispatch, over the wall time from that dispatch to the
+    newest. NOT over the ``step`` spans: a step span is the
+    asynchronous enqueue, which returns in a millisecond while the
+    runtime's queue has room, so their sum says nothing of how long the
+    device took; the dispatch stream does, since the runtime lets the
+    host only a bounded number of steps ahead. ``mfu`` needs
+    ``set_peak_flops`` — the chip ceiling is not knowable from
+    stdlib."""
     with _lock:
         flops = _programs_dropped_flops + sum(
             (c.get("flops") or 0.0) * c.get("dispatches", 0)
@@ -754,22 +821,30 @@ def _online_stats():
         step_s = _span_seconds.get("step", 0.0)
         compile_s = _span_seconds.get("jit_compile", 0.0)
         deser_s = _span_seconds.get("jit_deserialize", 0.0)
+        wall_s = 0.0 if _dispatch_t0_ns is None \
+            else (_dispatch_t1_ns - _dispatch_t0_ns) / 1e9
+        timed = max(flops - _dispatch_flops0, 0.0)
         # read the ceiling INSIDE the lock: the mfu and peak_flops
         # fields below must come from the same value (a set_peak_flops
         # racing the two bare reads used to be able to split them)
         peak = _peak_flops
+    rate = timed / wall_s if wall_s else None
     out = {
         "flops_dispatched": flops,
+        # first carded dispatch to the newest: the rate's denominator
+        "dispatch_wall_s": round(wall_s, 6),
+        # cumulative enqueue time (the ``step`` spans): how long the
+        # host spent handing steps over, not how long they ran
         "step_time_s": round(step_s, 6),
-        # first-launch compiles happen INSIDE the step span; reported so
-        # readers can judge how much of the window was warmup
+        # first-launch compiles happen INSIDE the dispatch stream;
+        # reported so readers can judge how much of it was warmup
         "compile_time_s": round(compile_s, 6),
         # disk-cache loads (compile_cache) — the warm-start counterpart
         "deserialize_time_s": round(deser_s, 6),
-        "model_flops_per_s": round(flops / step_s, 3) if step_s else None,
+        "model_flops_per_s": None if rate is None else round(rate, 3),
         "peak_flops": peak,
         # unrounded: a CPU-smoke MFU is ~1e-6 and must not read as 0.0
-        "mfu": flops / step_s / peak if step_s and peak else None,
+        "mfu": rate / peak if rate is not None and peak else None,
     }
     return out
 
@@ -970,19 +1045,18 @@ def trace_start_epoch_us():
 
 
 def _flow_ids(ctx):
-    """The flow identities one span's causal ctx binds it to: a request
-    id (``req_id`` on request spans, each member of ``req_ids`` on
-    batch-level spans) maps to ``req:<n>``; fit-step ids map to
-    ``step:<epoch>:<nbatch>``."""
+    """The request flows one span's causal ctx binds it to:
+    ``req:<n>`` for ``req_id`` on request spans and for each member of
+    ``req_ids`` on batch-level spans. (A fit step's spans need no
+    flow: they are annotations of one thread, nested in their step
+    annotation, with ``epoch``/``nbatch`` on each.)"""
     if not ctx:
         return ()
     out = []
     if ctx.get("req_id") is not None:
-        out.append(("req", "req:%s" % ctx["req_id"]))
+        out.append("req:%s" % ctx["req_id"])
     for rid in ctx.get("req_ids") or ():
-        out.append(("req", "req:%s" % rid))
-    if ctx.get("epoch") is not None and ctx.get("nbatch") is not None:
-        out.append(("step", "step:%s:%s" % (ctx["epoch"], ctx["nbatch"])))
+        out.append("req:%s" % rid)
     return out
 
 
@@ -997,18 +1071,19 @@ _SERVE_FLOW_RANK = {"serve_wait": 0,
                     "serve_request": 4}
 
 
-def chrome_events(pid=None, since_trace_start=True):
+def chrome_events(pid=None, since_trace_start=True, skip_annotated=False):
     """Render retained host spans as chrome://tracing complete events
     (``ph: "X"``, ``ts``/``dur`` in microseconds, epoch timebase) plus
     the process/thread metadata rows that label the track "mxnet_tpu
     host" in perfetto, plus FLOW events (``ph: "s"/"t"/"f"``) linking
-    the spans that share one causal id — one request's serve_wait →
-    serve_batch → serve_d2h → serve_request across the submit/coalesce/
-    resolve threads, one fit step's feed → step → opt spans — so
-    perfetto draws the request's/step's path as arrows.
+    the spans that share one request id — serve_wait → serve_batch →
+    serve_d2h → serve_request across the submit/coalesce/resolve
+    threads — so perfetto draws the request's path as arrows.
     ``since_trace_start=True`` keeps only spans that began after the
     last ``mark_trace_start()`` (everything, if no trace was
-    started)."""
+    started). ``skip_annotated=True`` leaves out the spans that were
+    written as profiler annotations: the profiler's own dump has them
+    already, on its own clock (``profiler._link_chrome_trace``)."""
     if pid is None:
         pid = os.getpid()
     with _lock:
@@ -1026,9 +1101,11 @@ def chrome_events(pid=None, since_trace_start=True):
         "args": {"sort_index": -1},
     }]
     tids = set()
-    flows = {}            # flow id -> (label, [(s_ns, tid), ...])
-    for name, s_ns, e_ns, tid, ctx in spans:
+    flows = {}            # flow id -> [(rank, bind_ns, tid), ...]
+    for name, s_ns, e_ns, tid, ctx, annotated in spans:
         if t0 is not None and s_ns < t0:
+            continue
+        if annotated and skip_annotated:
             continue
         tids.add(tid)
         ev = {
@@ -1040,20 +1117,17 @@ def chrome_events(pid=None, since_trace_start=True):
         if ctx:
             ev["args"] = dict(ctx)
         events.append(ev)
-        for label, fid in _flow_ids(ctx):
+        for fid in _flow_ids(ctx):
             # request flows chain in PIPELINE order (wait -> batch ->
             # d2h -> request), not start order — serve_request opens at
             # submit, so its start sorts next to serve_wait; its node
             # binds near the span END (the resolution instant), which
-            # also keeps the drawn arrows chronologically forward.
-            # Other flows (fit steps) chain by start time.
-            rank = _SERVE_FLOW_RANK.get(name, -1) if label == "req" \
-                else -1
+            # also keeps the drawn arrows chronologically forward
             bind_ns = s_ns if name != "serve_request" \
                 else max(s_ns, e_ns - 1000)
-            flows.setdefault(fid, (label, []))[1].append(
-                (rank, bind_ns, tid))
-    for fid, (label, members) in flows.items():
+            flows.setdefault(fid, []).append(
+                (_SERVE_FLOW_RANK.get(name, -1), bind_ns, tid))
+    for fid, members in flows.items():
         if len(members) < 2:
             continue          # an arrow needs two ends
         members.sort()       # (rank, bind_ns, tid): pipeline order,
@@ -1065,7 +1139,7 @@ def chrome_events(pid=None, since_trace_start=True):
             # the serve_request terminus) is inside by definition
             ev = {
                 "ph": "s" if i == 0 else ("f" if i == last else "t"),
-                "cat": "flow", "name": label, "id": fid,
+                "cat": "flow", "name": "req", "id": fid,
                 "pid": pid, "tid": tid,
                 "ts": round(_epoch_us(bind_ns), 3),
             }

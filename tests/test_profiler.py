@@ -50,10 +50,13 @@ def test_profiler_autostart_env(tmp_path):
 
 
 def test_profiler_scope_region_in_trace(tmp_path):
-    """profiler.Scope annotates a region: the TraceAnnotation enters the
-    device trace and the telemetry span lands in the merged dump."""
+    """profiler.Scope is a telemetry span: the region is written ONCE,
+    by the profiler itself (an annotation on the device dump's clock),
+    not merged a second time from the ring; the ring keeps it too."""
+    from mxnet_tpu import telemetry
     fname = str(tmp_path / "scope_profile.json")
     mx.profiler.set_config(filename=fname)
+    before = telemetry.span_count("my_hot_region")
     mx.profiler.set_state("run")
     with mx.profiler.Scope("my_hot_region"):
         a = mx.nd.uniform(shape=(32, 32))
@@ -63,9 +66,11 @@ def test_profiler_scope_region_in_trace(tmp_path):
         trace = json.load(f)
     events = trace.get("traceEvents", trace)
     assert isinstance(events, list) and events
-    host = [e for e in events if e.get("cat") == "host"]
-    assert any(e["name"] == "my_hot_region" for e in host), \
-        "Scope region missing from the merged host track"
+    region = [e for e in events
+              if e.get("ph") == "X" and e["name"] == "my_hot_region"]
+    assert len(region) == 1, "Scope region must be in the dump exactly once"
+    assert region[0].get("cat") != "host"     # the profiler's own slice
+    assert telemetry.span_count("my_hot_region") == before + 1
 
 
 def test_link_chrome_trace_fallback_no_gz(tmp_path):
@@ -128,28 +133,22 @@ def test_plot_network_graphviz_or_skip():
     assert dot is not None
 
 
-def test_scope_releases_span_when_annotation_fails():
-    """mxlife resource-release fix: if the device TraceAnnotation
-    fails to arm, the already-entered host span must close instead of
-    staying open forever (every entered span exits)."""
+def test_scope_releases_span_when_annotation_fails(monkeypatch):
+    """If the profiler annotation fails to arm, the host span still
+    opens, closes and records (every entered span exits — mxlife), and
+    the region's own code runs: tracing never costs the program."""
     from mxnet_tpu import telemetry
 
-    class _BoomAnn:
-        def __enter__(self):
-            raise RuntimeError("annotation failed to arm")
-
-        def __exit__(self, *exc):
-            return False
+    def _boom(name, ids, step_num):
+        raise RuntimeError("annotation failed to arm")
 
     telemetry.enable()
-    scope = mx.profiler.Scope("failing_region")
-    scope._ann = _BoomAnn()
     before = telemetry.span_count("failing_region")
-    try:
-        scope.__enter__()
-    except RuntimeError:
-        pass
-    else:
-        raise AssertionError("the arm failure must propagate")
+    ran = []
+    monkeypatch.setattr(telemetry, "_annotation", _boom)
+    with mx.profiler.Scope("failing_region"):
+        ran.append(True)
+    assert ran == [True]
     # the host span closed (one recorded sample), not leaked open
     assert telemetry.span_count("failing_region") == before + 1
+    telemetry.reset()

