@@ -345,12 +345,29 @@ def _bn_stats(data, moving_mean, moving_var, red, _train,
     """Shared BN statistics: batch mean/var in training mode (fp32
     accumulation for half dtypes — the reference's cudnn BN behaviour),
     stop-gradiented moving stats otherwise. One source of truth for
-    BatchNorm and the fused _contrib_BatchNormAddReLU."""
+    BatchNorm and the fused _contrib_BatchNormAddReLU.
+
+    Half-precision data gives both moments from ONE read (sum(d), sum(d*d)
+    with d = data - c), so they ride in the epilogue of the op that
+    produces ``data`` and the derivative is elementwise: a two-pass
+    ``jnp.var`` costs a second read forward and, transposed, a reduction
+    of a term that is zero backward. E[d^2] - E[d]^2 loses the digits of
+    z*z, z = (mean - c) / std: the fp32 sums hold 16 bits more than the
+    data, so up to z = 256 that is below the data's own rounding, and
+    ``c``, the moving mean, is known before the pass and puts z near 0
+    once training runs. Data as wide as its accumulation has no digits to
+    spare and keeps the two passes."""
     if _train and not use_global_stats:
-        stat_in = data.astype(jnp.float32) \
-            if data.dtype in (jnp.bfloat16, jnp.float16) else data
-        mean = jnp.mean(stat_in, axis=red).astype(moving_mean.dtype)
-        var = jnp.var(stat_in, axis=red).astype(moving_var.dtype)
+        if data.dtype not in (jnp.bfloat16, jnp.float16):
+            return (jnp.mean(data, axis=red).astype(moving_mean.dtype),
+                    jnp.var(data, axis=red).astype(moving_var.dtype))
+        shape = [1 if i in red else n for i, n in enumerate(data.shape)]
+        c = jax.lax.stop_gradient(moving_mean).astype(jnp.float32)
+        d = data.astype(jnp.float32) - c.reshape(shape)
+        m1 = jnp.mean(d, axis=red)
+        m2 = jnp.mean(d * d, axis=red)
+        mean = (c + m1).astype(moving_mean.dtype)
+        var = jnp.maximum(m2 - m1 * m1, 0).astype(moving_var.dtype)
         return mean, var
     return (jax.lax.stop_gradient(moving_mean),
             jax.lax.stop_gradient(moving_var))
