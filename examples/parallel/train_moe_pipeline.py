@@ -1,6 +1,7 @@
 """Demonstrates the full parallelism menu on a virtual device mesh:
-data (dp), sequence (sp via ring attention), tensor (tp), expert (ep via
-all_to_all MoE), and pipeline (pp via the GPipe schedule).
+data (dp), sequence (sp via ring attention), tensor (tp), the expert layer
+an ``ep`` axis wraps (one device's held experts), and pipeline (pp via the
+GPipe schedule).
 
 These are the new-framework extensions beyond the 2017 reference
 (SURVEY.md §2.3 last row); run on a real pod the same code spans chips
@@ -32,17 +33,21 @@ def main():
     rs = np.random.RandomState(0)
     E, F = 16, 32
 
-    # --- expert parallelism: MoE FFN over 4 experts -----------------------
+    # --- the expert layer: top-2 of 4 sigmoid-routed gated experts, of
+    # which this device holds experts 1 and 2 (its share under an ``ep``
+    # axis of two devices); dropless, no capacity
     n_exp = 4
-    mesh = parallel.make_mesh({"ep": n_exp})
     x = rs.randn(n_exp, 8, E).astype(np.float32)
-    out = parallel.moe_ffn(
+    out, counts = parallel.moe_layer(
         jnp.asarray(x),
         jnp.asarray(rs.randn(n_exp, E).astype(np.float32)),
-        jnp.asarray(rs.randn(n_exp, F, E).astype(np.float32) * 0.1),
-        jnp.asarray(rs.randn(n_exp, E, F).astype(np.float32) * 0.1),
-        mesh)
-    print("moe_ffn out", out.shape)
+        jnp.zeros((n_exp,), jnp.float32),
+        jnp.asarray(rs.randn(2, E, F).astype(np.float32) * 0.1),
+        jnp.asarray(rs.randn(2, E, F).astype(np.float32) * 0.1),
+        jnp.asarray(rs.randn(2, F, E).astype(np.float32) * 0.1),
+        top_k=2, experts_held=(1, 2))
+    print("positions per expert", np.asarray(counts))
+    print("moe_layer out", out.shape)
 
     # --- pipeline parallelism: 4 stages, 6 microbatches -------------------
     n_pp = 4

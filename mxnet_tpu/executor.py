@@ -32,6 +32,10 @@ from . import random as _random
 from . import telemetry
 from . import faults
 
+#: the attribute with which a symbol's op node starts a checkpoint segment
+#: (``_GraphProgram.mirror_stages``)
+MIRROR_STAGE = "__mirror_stage__"
+
 __all__ = ["Executor", "infer_graph_shapes", "record_dispatch",
            "card_from_compiled", "DeviceMemoryError"]
 
@@ -778,6 +782,21 @@ class _GraphProgram:
         outputs = [env[id(n)][idx] for n, idx in self.output_entries]
         return outputs, aux_updates
 
+    @property
+    def mirror_stages(self):
+        """Whether the symbol marks segments itself: op nodes that carry
+        the ``__mirror_stage__`` attribute each start a checkpoint
+        segment, and the graph is then evaluated mirrored whatever
+        ``MXNET_BACKWARD_DO_MIRROR`` says (a model that cannot hold its
+        activations says so in its symbol, not in its user's
+        environment)."""
+        cached = self.__dict__.get("_mirror_stages")
+        if cached is None:
+            cached = any(n.op is not None and MIRROR_STAGE in n._extra_attrs
+                         for n in self.nodes)
+            self.__dict__["_mirror_stages"] = cached
+        return cached
+
     def can_segment(self):
         """Whether mirrored evaluation can split this graph into
         checkpoint segments: needs a jitted single-device program with
@@ -805,9 +824,17 @@ class _GraphProgram:
             # jitted single-device program
             return self.eval_graph(arg_dict, aux_dict, rng_key, train)
         ops = [n for n in self.nodes if n.op is not None]
-        k = max(2, int(round(math.sqrt(len(ops)))))
-        step = (len(ops) + k - 1) // k
-        chunks = [ops[i:i + step] for i in range(0, len(ops), step)]
+        if self.mirror_stages:
+            # the symbol says where its segments start (one a layer)
+            chunks = []
+            for n in ops:
+                if not chunks or MIRROR_STAGE in n._extra_attrs:
+                    chunks.append([])
+                chunks[-1].append(n)
+        else:
+            k = max(2, int(round(math.sqrt(len(ops)))))
+            step = (len(ops) + k - 1) // k
+            chunks = [ops[i:i + step] for i in range(0, len(ops), step)]
 
         # val_env: (id(node), out_index) -> traced value
         val_env = {}
@@ -894,7 +921,7 @@ class _GraphProgram:
         the checkpointing choice and gradient partitioning stay
         identical by construction."""
         from .config import do_mirror
-        mirror = do_mirror()
+        mirror = do_mirror() or self.mirror_stages
         segmented = mirror and self.can_segment()
 
         def f(ga):
@@ -1641,6 +1668,23 @@ class Executor:
 
     def output_entries_len(self):
         return len(self._prog.output_entries)
+
+    def publish_aux_counters(self):
+        """Add to the telemetry counters what the ops' auxiliary states
+        have summed since the last call (``OpDef.aux_counters``: the
+        routed-expert layer's rows). The sums grow on the device inside
+        the step; this fetch is the only host sync, so it is called where
+        a sync is due anyway (the end of an epoch), never per batch."""
+        seen = self.__dict__.setdefault("_aux_published", {})
+        for node in self._prog.nodes:
+            table = node.op.aux_counters if node.op is not None else None
+            for idx, publish in (table or {}).items():
+                aux = node.inputs[idx][0].name
+                if aux not in self.aux_dict:
+                    continue
+                now = np.asarray(self.aux_dict[aux]._data, np.float64)   # mxlint: disable=host-sync -- the one fetch of the summed counters, at an epoch's end
+                publish(now - seen.get(aux, 0.0))
+                seen[aux] = now
 
     def _write_aux(self, aux_up):
         if not aux_up:

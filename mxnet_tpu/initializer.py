@@ -74,7 +74,7 @@ class Initializer:
             self._init_zero(desc, arr)
         elif desc.endswith("moving_var") or desc.endswith("running_var"):
             self._init_one(desc, arr)
-        elif desc.endswith("moving_inv_var") or desc.endswith("moving_avg"):
+        elif desc.endswith(("moving_inv_var", "moving_avg", "running_sum")):
             self._init_zero(desc, arr)
         else:
             self._init_default(desc, arr)

@@ -516,6 +516,22 @@ class Loss(EvalMetric):
             self.sum_metric += float(pred.sum())
             self.num_inst += pred.size
 
+    def device_kernel(self):
+        """Fused-step accumulate: the sum of the outputs in float32. The
+        step counts ``num_inst`` as the labels' sizes, which for a loss
+        head (one output a label) is ``update``'s ``pred.size``."""
+        def kernel(labels, preds, acc):
+            import jax.numpy as jnp
+            for l, p in zip(labels, preds):
+                if p.size != l.size:
+                    raise MXNetError(
+                        "Loss in the fused step counts one output a label: "
+                        "got outputs %s for labels %s" % (p.shape, l.shape))
+                acc = acc + jnp.sum(p.astype(jnp.float32))
+            return acc
+
+        return kernel
+
 
 @register
 class Torch(Loss):

@@ -461,6 +461,7 @@ class BaseModule:
                 self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
             self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
                              time.time() - tic)
+            self._publish_aux_counters()
 
             # epoch-end host param sync ONLY at a callback boundary: the
             # executor already holds the canonical values, so the
@@ -486,6 +487,11 @@ class BaseModule:
                     self.logger.info("Epoch[%d] Validation-%s=%f", epoch,
                                      name, val)
             train_data.reset()
+
+    def _publish_aux_counters(self):
+        """Telemetry counters that ops sum in auxiliary states on the
+        device (``Executor.publish_aux_counters``); fetched here, after
+        the metric's own fetch, once an epoch."""
 
     def _elastic_remesh(self, dead_ranks):
         """Adopt the surviving membership after a member loss. The base

@@ -1179,6 +1179,9 @@ class Module(BaseModule):
         assert self.binded
         return list(self._exec.outputs)
 
+    def _publish_aux_counters(self):
+        self._exec.publish_aux_counters()
+
     def get_input_grads(self, merge_multi_context=True):
         assert self.binded and self.inputs_need_grad
         gd = self._exec.grad_dict

@@ -18,6 +18,7 @@ from . import contrib     # noqa: F401
 from . import contrib_extra  # noqa: F401
 from . import contrib_extra3  # noqa: F401
 from . import spatial     # noqa: F401
+from . import lm          # noqa: F401
 
 from . import shape_infer as _shape_infer  # noqa: E402
 _shape_infer.install()

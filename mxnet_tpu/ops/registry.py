@@ -86,6 +86,10 @@ class OpDef:
         # FInferType; e.g. BatchNorm pins scale/shift/moving stats to fp32
         # under low-precision data, the cudnn_batch_norm behaviour).
         self.param_dtype_infer = None
+        # Optional {aux input index: fn(growth)}: the auxiliary state holds
+        # running sums, and ``Executor.publish_aux_counters`` hands ``fn``
+        # what they grew by since its last call, for telemetry counters.
+        self.aux_counters = None
 
     def __repr__(self):
         return "OpDef(%s)" % self.name

@@ -1,43 +1,51 @@
-"""Blockwise (flash) attention as a Pallas TPU kernel.
+"""Blockwise (flash) attention as Pallas TPU kernels.
 
 New-framework extension beyond the 2017 reference (which predates
-attention, SURVEY.md §5.7); this is the single-chip building block that
-``parallel.ring_attention`` composes over the 'sp' mesh axis.
+attention, SURVEY.md §5.7). Two entries:
 
-Design (TPU-first):
-- grid over (batch*heads, q-blocks); each program owns a ``block_q``-row
-  Q tile in VMEM and the device's whole local K/V block (VMEM-resident —
-  ring attention keeps per-device K/V small, so one MXU matmul per tile
-  beats a DMA'd kv-chunk loop).
-- online softmax: running max ``m`` and denominator ``l`` per Q row, so
-  the kernel can be chained across ring steps: ``flash_attention_carry``
-  takes and returns the (o, m, l) accumulator, exactly the carry that
-  rotates with ``ppermute``.
-- causal masking by *global* positions (``q_offset``/``kv_offset``): the
-  same kernel serves both the single-chip and the sequence-sharded case.
-- ``interpret=True`` off-TPU so the unit suite runs on the CPU mesh.
+``flash_attention`` (single chip, differentiable): tiles over Q *and*
+K/V. The grid is (batch, query head, Q block, K/V step); a program holds
+one ``(block_q, D)`` Q tile, one ``(block_k, D)`` K and V tile and a
+``(block_q, block_k)`` float32 score tile, with the online-softmax state
+(running max, denominator, numerator) in VMEM scratch across the K/V
+steps. Nothing in VMEM grows with the sequence.
+- causal and windowed masks are a *band* of K/V blocks per Q block: the
+  K/V axis of the grid is as long as the widest band (static), the block
+  index is clamped into the band (a repeated index is not fetched again)
+  and steps beyond it are skipped, so time follows the unmasked blocks.
+  Blocks inside the band that no mask edge crosses take a path without
+  the mask's compare and select.
+- grouped-query heads: ``q`` is (B, Hq, S, D), ``k``/``v`` (B, Hkv, S, D)
+  with Hq a multiple of Hkv; query head h reads K/V head h // (Hq/Hkv),
+  no repeated copy of K/V in HBM.
+- the backward is two kernels from the saved log-sum-exp (the flash
+  backward): dQ over the same band, and dK/dV over the transposed band
+  with the query heads of a group accumulated in scratch.
+- operands stay in their own type (bf16 on the chip) with float32
+  accumulation; softmax statistics are float32.
 
-Limit (TPU v5e, compiled for a described chip, jax 0.9.0): each program
-holds the whole local K and V block plus a (block_q, S_kv) f32 score
-tile in VMEM. (B4,H16,S2048,D128) and D64 bf16 compile, forward and
-gradient. At (B1,H16,S8192,D128) bf16 the non-causal forward still
-compiles, but the causal forward and the gradient are refused
-(``RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem``). Longer
-sequences are what ``parallel.ring_attention``/``ulysses_attention``
-are for: they keep the per-device S_kv short; a local block of 8192
-reached through them on a TPU hits the same refusal
-(tests/test_tpu_compile.py pins it).
+``flash_attention_carry`` (the building block ``parallel.ring_attention``
+composes over the 'sp' mesh axis): grid over (batch*heads, Q blocks);
+each program owns a ``block_q``-row Q tile and the device's whole local
+K/V block, and takes and returns the online-softmax accumulator (o, m, l)
+that rotates with ``ppermute``. Causal masking is by *global* positions
+(``q_offset``/``kv_offset``).
 
-Backward for the plain entry is a custom VJP: recompute probabilities
-from the saved log-sum-exp one Q block at a time (lax.map), so peak
-memory stays O(block_q * S) instead of O(S^2) — the flash backward
-formulation, expressed in XLA.
+``interpret=True`` off-TPU so the unit suite runs on the CPU mesh.
+
+Limits (TPU v5e, compiled for a described chip, jax 0.9.0,
+tests/test_tpu_compile.py): the plain entry compiles forward and gradient
+at (B1, Hq32/Hkv4, S8192, D128) bf16, causal, with and without a 2,048
+window, and at (B4, H16, S2048, D128). The carry entry still holds the
+whole local K/V block and a (block_q, S_kv) f32 score tile in VMEM, so
+a local block of 8,192 reached through the ring is refused
+(``RESOURCE_EXHAUSTED ... vmem``): sequence parallelism keeps its local
+block short.
 """
 from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -47,9 +55,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_carry"]
 
-DEFAULT_BLOCK_Q = 128
-# candidate Q-block sizes offered to the operator tuner (default first)
-TUNE_BLOCKS_Q = (128, 256, 512)
+DEFAULT_BLOCK_Q = 128          # the carry entry's Q tile
+#: the plain entry's Q and K/V tiles: (512, 512) float32 scores are 1 MB of
+#: VMEM, and at S = 8,192 a tile is 1/16 of the sequence, so the causal
+#: band wastes little on its diagonal
+DEFAULT_BLOCK = 512
 NEG_INF = -1e30
 
 
@@ -63,40 +73,6 @@ def _dot_precision(dtype):
     ``jax_default_matmul_precision=highest`` (a parity-check habit) must
     not reach the kernel's bf16 dots. f32 operands keep the ambient one."""
     return None if dtype == jnp.float32 else lax.Precision.DEFAULT
-
-
-def _resolve_block_q(q, k, causal, interpret):
-    """``block_q=None`` -> measured choice per (shape, dtype, causal)
-    signature via the operator tuner (mxnet_tpu.tuner ≙ reference
-    operator_tune.h:37-202). Interpret mode (off-TPU) skips measurement —
-    timings there say nothing about the MXU."""
-    if interpret:
-        return DEFAULT_BLOCK_Q
-    b, h, s_q, d = q.shape
-    s_kv = k.shape[2]
-    effective = []
-    for blk in TUNE_BLOCKS_Q:
-        e = min(blk, max(s_q, 1))
-        if e not in effective:
-            effective.append(e)
-    if len(effective) == 1:
-        return effective[0]
-    from ..tuner import tuned_choice
-
-    def mk(blk):
-        def thunk():
-            qz = jnp.zeros((b, h, s_q, d), q.dtype)
-            kz = jnp.zeros((b, h, s_kv, d), k.dtype)
-            return _forward(qz, kz, kz, causal, 1.0 / math.sqrt(d), blk,
-                            interpret)[0]
-        return thunk
-
-    key = "bh%d_sq%d_skv%d_d%d_%s_c%d" % (b * h, s_q, s_kv, d,
-                                          jnp.dtype(q.dtype).name,
-                                          int(causal))
-    label = tuned_choice("flash_attention.block_q", key,
-                         [(str(e), mk(e)) for e in effective], args=(q, k))
-    return int(label)
 
 
 def _attn_kernel(scalars_ref, q_ref, k_ref, v_ref, o_in_ref, m_in_ref,
@@ -233,221 +209,336 @@ def flash_attention_carry(q, k, v, o, m, l, q_offset=0, kv_offset=0,
     return o2, m2, l2
 
 
-def _forward(q, k, v, causal, scale, block_q, interpret):
-    """(B, H, S, D) -> (out, lse). Single chip, whole sequence."""
-    b, h, s_q, d = q.shape
-    s_kv = k.shape[2]
-    qf = q.reshape(b * h, s_q, d)
-    kf = k.reshape(b * h, s_kv, d)
-    vf = v.reshape(b * h, s_kv, d)
-    o0 = jnp.zeros((b * h, s_q, d), jnp.float32)
-    m0 = jnp.full((b * h, s_q), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b * h, s_q), jnp.float32)
-    o, m, l = flash_attention_carry(qf, kf, vf, o0, m0, l0, 0, 0, causal,
-                                    scale, block_q, interpret)
-    out = o / jnp.maximum(l, 1e-30)[..., None]
-    lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    return out.astype(q.dtype).reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
+# ---------------------------------------------------------------------------
+# the plain entry: tiles over Q and K/V, banded, grouped-query
+# ---------------------------------------------------------------------------
+
+def _round_up(n, m):
+    return -(-n // m) * m
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=None, interpret=None):
-    """Exact attention, (B, H, S, D) layout, O(block_q * S) memory.
+class _Band:
+    """Which K/V blocks a Q block attends, and the transposed question.
+    Written with plain integer arithmetic so that the same methods serve
+    Python ints (the static grid length) and traced program ids."""
 
-    Differentiable; the forward runs as a Pallas kernel on TPU (interpret
-    mode elsewhere), the backward recomputes probabilities blockwise from
-    the saved log-sum-exp. ``block_q=None`` (default) lets the operator
-    tuner measure-and-cache the Q-block size per signature.
+    def __init__(self, n_q, n_k, bq, bk, causal, window, kv_len):
+        self.n_q, self.n_k, self.bq, self.bk = n_q, n_k, bq, bk
+        self.causal, self.window, self.kv_len = causal, window, kv_len
+        self.k_steps = max(self.k_hi(i) - self.k_lo(i) + 1
+                           for i in range(n_q))
+        self.q_steps = max(self.q_hi(j) - self.q_lo(j) + 1
+                           for j in range(n_k))
+
+    @staticmethod
+    def _min(a, b):
+        return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+            else jnp.minimum(a, b)
+
+    @staticmethod
+    def _max(a, b):
+        return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+            else jnp.maximum(a, b)
+
+    def k_lo(self, i):
+        if not self.window:
+            return i * 0
+        return self._min(self._max(i * self.bq - (self.window - 1), 0)
+                         // self.bk, self.n_k - 1)
+
+    def k_hi(self, i):
+        if not self.causal:
+            return i * 0 + self.n_k - 1
+        return self._min(((i + 1) * self.bq - 1) // self.bk, self.n_k - 1)
+
+    def q_lo(self, j):
+        if not self.causal:
+            return j * 0
+        return self._min((j * self.bk) // self.bq, self.n_q - 1)
+
+    def q_hi(self, j):
+        if not self.window:
+            return j * 0 + self.n_q - 1
+        return self._min(((j + 1) * self.bk - 1 + self.window - 1)
+                         // self.bq, self.n_q - 1)
+
+    def crossed(self, i, j):
+        """Whether an edge of the mask (the diagonal, the window's far
+        side, the end of the real K/V rows) passes through block (i, j)."""
+        q0, k0 = i * self.bq, j * self.bk
+        hit = (k0 + self.bk) > self.kv_len
+        if self.causal:
+            hit = hit | ((k0 + self.bk - 1) > q0)
+        if self.window:
+            hit = hit | ((q0 + self.bq - 1 - k0) >= self.window)
+        return hit
+
+    def mask(self, i, j):
+        q_pos = i * self.bq + lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 0)
+        k_pos = j * self.bk + lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 1)
+        m = k_pos < self.kv_len
+        if self.causal:
+            m = m & (q_pos >= k_pos)
+        if self.window:
+            m = m & (q_pos - k_pos < self.window)
+        return m
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           precision=_dot_precision(a.dtype),
+                           preferred_element_type=jnp.float32)
+
+
+def _on_band(inside, crossed, body):
+    """Run ``body(masked)`` if the step lies in the band: the masked form
+    where a mask edge crosses the block, the bare one elsewhere."""
+    pl.when(inside & crossed)(lambda: body(True))
+    pl.when(inside & jnp.logical_not(crossed))(lambda: body(False))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
+                band, scale):
+    i, j = pl.program_id(2), pl.program_id(3)
+    kb = band.k_lo(i) + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def body(masked):
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+        s = _dot(q, k, ((1,), (1,))) * scale             # (bq, bk)
+        if masked:
+            mask = band.mask(i, kb)
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked:      # a row with nothing visible yet: exp(0) is not 0
+            p = jnp.where(mask, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + _dot(p.astype(v.dtype), v,
+                                              ((1,), (0,)))
+        m_s[...] = m_new
+
+    _on_band(kb <= band.k_hi(i), band.crossed(i, kb), body)
+
+    @pl.when(j == band.k_steps - 1)
+    def _done():
+        l = jnp.maximum(l_s[...], 1e-30)
+        o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
+               acc_s, *, band, scale):
+    i, j = pl.program_id(2), pl.program_id(3)
+    kb = band.k_lo(i) + j
+
+    @pl.when(j == 0)
+    def _init():
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def body(masked):
+        q, k, v, g = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], g_ref[0, 0]
+        s = _dot(q, k, ((1,), (1,))) * scale
+        if masked:
+            s = jnp.where(band.mask(i, kb), s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0, 0])
+        dp = _dot(g, v, ((1,), (1,)))
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        acc_s[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,)))
+
+    _on_band(kb <= band.k_hi(i), band.crossed(i, kb), body)
+
+    @pl.when(j == band.k_steps - 1)
+    def _done():
+        dq_ref[0, 0] = acc_s[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref,
+                dv_ref, dk_s, dv_s, *, band, scale, group):
+    jb, t = pl.program_id(2), pl.program_id(3)
+    ib = band.q_lo(jb) + t % band.q_steps
+
+    @pl.when(t == 0)
+    def _init():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    def body(masked):
+        q, k, v, g = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], g_ref[0, 0]
+        s = _dot(q, k, ((1,), (1,))) * scale
+        if masked:
+            s = jnp.where(band.mask(ib, jb), s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0, 0])                    # (bq, bk)
+        dv_s[...] += _dot(p.astype(g.dtype), g, ((0,), (0,)))
+        dp = _dot(g, v, ((1,), (1,)))
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        dk_s[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,)))
+
+    _on_band(ib <= band.q_hi(jb), band.crossed(ib, jb), body)
+
+    @pl.when(t == group * band.q_steps - 1)
+    def _done():
+        dk_ref[0, 0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+def _pad_seq(x, block):
+    pad = (-x.shape[2]) % block
+    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+
+def _plan(q, k, causal, window, block_q, block_k):
+    """Tile sizes and the band for ``q`` (B, Hq, Sq, D), ``k`` (B, Hkv,
+    Skv, D)."""
+    if window and not causal:
+        raise ValueError("a window needs causal=True")
+    if q.shape[1] % k.shape[1]:
+        raise ValueError("%d query heads are no multiple of %d K/V heads"
+                         % (q.shape[1], k.shape[1]))
+    s_q, s_kv = q.shape[2], k.shape[2]
+    bq = min(block_q or DEFAULT_BLOCK, _round_up(s_q, 8))
+    bk = min(block_k or DEFAULT_BLOCK, _round_up(s_kv, 8))
+    return _Band(_round_up(s_q, bq) // bq, _round_up(s_kv, bk) // bk, bq, bk,
+                 bool(causal), int(window or 0), s_kv)
+
+
+def _q_major_specs(band, group, d):
+    """Block specs of the grid (batch, query head, Q block, K/V step): a Q
+    block's rows, its per-row statistics, and the step's K/V block (the
+    group's K/V head, the index clamped into the band)."""
+    def kv_map(b_, h, i, j):
+        return (b_, h // group,
+                jnp.minimum(band.k_lo(i) + j, band.k_hi(i)), 0)
+
+    def q_map(b_, h, i, j):
+        return (b_, h, i, 0)
+
+    return (pl.BlockSpec((1, 1, band.bq, d), q_map),
+            pl.BlockSpec((1, 1, band.bq, 1), q_map),
+            pl.BlockSpec((1, 1, band.bk, d), kv_map))
+
+
+def _forward(q, k, v, band, scale, interpret):
+    """(out, lse) on padded inputs: ``lse`` is (B, Hq, Sq', 1) float32."""
+    b, hq, s_q, d = q.shape
+    bq, bk = band.bq, band.bk
+    row, stat, kv = _q_major_specs(band, hq // k.shape[1], d)
+    steps = band.n_q * band.k_steps
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, band=band, scale=scale),
+        grid=(b, hq, band.n_q, band.k_steps),
+        in_specs=[row, kv, kv], out_specs=[row, stat],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, hq, s_q, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * hq * steps * bq * bk * d,
+            bytes_accessed=q.dtype.itemsize * (2 * q.size + k.size + v.size),
+            transcendentals=b * hq * steps * bq * bk),
+        name="flash_attention_fwd", interpret=interpret,
+    )(q, k, v)
+
+
+def _backward(q, k, v, g, lse, delta, band, scale, interpret):
+    b, hq, s_q, d = q.shape
+    hkv = k.shape[1]
+    group = hq // hkv
+    bq, bk = band.bq, band.bk
+    row, stat, kv = _q_major_specs(band, group, d)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, band=band, scale=scale),
+        grid=(b, hq, band.n_q, band.k_steps),
+        in_specs=[row, kv, kv, row, stat, stat], out_specs=row,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        name="flash_attention_dq", interpret=interpret,
+    )(q, k, v, g, lse, delta)
+
+    def q_map(b_, h, j, t):
+        return (b_, h * group + t // band.q_steps,
+                jnp.minimum(band.q_lo(j) + t % band.q_steps, band.q_hi(j)),
+                0)
+
+    qrow = pl.BlockSpec((1, 1, bq, d), q_map)
+    qstat = pl.BlockSpec((1, 1, bq, 1), q_map)
+    kvrow = pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, t: (b_, h, j, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, band=band, scale=scale, group=group),
+        grid=(b, hkv, band.n_k, group * band.q_steps),
+        in_specs=[qrow, kvrow, kvrow, qrow, qstat, qstat],
+        out_specs=[kvrow, kvrow],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        name="flash_attention_dkv", interpret=interpret,
+    )(q, k, v, g, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    interpret=None, window=None, block_k=None):
+    """Exact attention. ``q``: (B, Hq, Sq, D); ``k``, ``v``: (B, Hkv, Skv,
+    D) with Hq a multiple of Hkv (query head h reads K/V head
+    h // (Hq / Hkv)). ``causal``: position i sees j <= i; ``window`` (with
+    ``causal``): and only i - j < window, the position itself counted.
+
+    Differentiable; forward and backward are Pallas kernels on the TPU
+    (interpret mode elsewhere) whose VMEM use does not grow with S.
     """
-    if interpret is None:
-        interpret = _use_interpret()
-    if block_q is None:
-        block_q = _resolve_block_q(q, k, causal, interpret)
-    out, _ = _forward(q, k, v, causal, scale if scale is not None
-                      else 1.0 / math.sqrt(q.shape[-1]), block_q, interpret)
-    return out
+    return _fwd(q, k, v, causal, scale, block_q, interpret, window,
+                block_k)[0]
 
 
-def _fwd(q, k, v, causal, scale, block_q, interpret):
+def _fwd(q, k, v, causal, scale, block_q, interpret, window, block_k):
     if interpret is None:
         interpret = _use_interpret()
-    if block_q is None:
-        block_q = _resolve_block_q(q, k, causal, interpret)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    out, lse = _forward(q, k, v, causal, scale, block_q, interpret)
+    band = _plan(q, k, causal, window, block_q, block_k)
+    out, lse = _forward(_pad_seq(q, band.bq), _pad_seq(k, band.bk),
+                        _pad_seq(v, band.bk), band, scale, interpret)
+    out = out[:, :, :q.shape[2]]
     return out, (q, k, v, out, lse)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, *, causal, scale, block_q):
-    """One (bh, q-block) program of the flash backward: recompute p from
-    the saved lse, then dv += p^T dO, ds = p*(dp - delta), dq = ds k,
-    dk += ds^T q. dk/dv accumulate across the (sequential) q-block grid
-    axis into constant-index output blocks — the TPU Pallas revisiting
-    pattern."""
-    i = pl.program_id(1)
-    f32 = jnp.float32
-    q = q_ref[0].astype(f32)           # (bq, D)
-    k = k_ref[0].astype(f32)           # (S, D)
-    v = v_ref[0].astype(f32)
-    g = g_ref[0].astype(f32)           # (bq, D)
-    lse = lse_ref[0]                   # (bq, 1) f32
-    delta = delta_ref[0]               # (bq, 1) f32
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=f32) * scale
-    if causal:
-        s_kv = k.shape[0]
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, s_kv), 0)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, s_kv), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    p = jnp.exp(s - lse)                                   # (bq, S)
-
-    dv_c = jax.lax.dot_general(p, g, (((0,), (0,)), ((), ())),
-                               preferred_element_type=f32)  # (S, D)
-    dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)    # (bq, S)
-    ds = p * (dp - delta) * scale
-    dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                             preferred_element_type=f32)    # (bq, D)
-    dk_c = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                               preferred_element_type=f32)  # (S, D)
-
-    dq_ref[0] = dq
-
-    @pl.when(i == 0)
-    def _init():
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
-
-    dk_ref[0] += dk_c
-    dv_ref[0] += dv_c
-
-
-def _bwd(causal, scale, block_q, interpret, res, g):
+def _bwd(causal, scale, block_q, interpret, window, block_k, res, g):
+    q, k, v, out, lse = res
     if interpret is None:
         interpret = _use_interpret()
-    use_xla = os.environ.get("MXTPU_FLASH_BWD", "") == "xla"
-    if not use_xla:
-        return _bwd_flash(causal, scale, block_q, interpret, res, g)
-    return _bwd_xla(causal, scale, block_q, interpret, res, g)
-
-
-def _bwd_flash(causal, scale, block_q, interpret, res, g):
-    q, k, v, out, lse = res
-    if block_q is None:
-        # same tuner decision as the forward: the cache is keyed by the
-        # identical signature, so the cached winner (or default) applies
-        block_q = _resolve_block_q(q, k, causal, interpret)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    b, h, s_q, d = q.shape
-    s_kv = k.shape[2]
-    f32 = jnp.float32
-    bh = b * h
-    qf = q.reshape(bh, s_q, d)
-    kf = k.reshape(bh, s_kv, d)
-    vf = v.reshape(bh, s_kv, d)
-    gf = g.reshape(bh, s_q, d)
-    of = out.reshape(bh, s_q, d)
-    lf = lse.reshape(bh, s_q)
-
-    block = min(block_q, max(s_q, 1))
-    pad = (-s_q) % block
-    qp, _ = _pad_q(qf, block)
-    gp, _ = _pad_q(gf, block)
-    op, _ = _pad_q(of, block)
-    lsep = jnp.pad(lf, ((0, 0), (0, pad)), constant_values=-NEG_INF)
-    delta = jnp.sum(gp.astype(f32) * op.astype(f32), -1)   # (BH, Sq')
-    n_q = qp.shape[1] // block
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(bh, n_q),
-        in_specs=[
-            pl.BlockSpec((1, block, d), lambda b, i: (b, i, 0)),      # q
-            pl.BlockSpec((1, s_kv, d), lambda b, i: (b, 0, 0)),       # k
-            pl.BlockSpec((1, s_kv, d), lambda b, i: (b, 0, 0)),       # v
-            pl.BlockSpec((1, block, d), lambda b, i: (b, i, 0)),      # g
-            pl.BlockSpec((1, block, 1), lambda b, i: (b, i, 0)),      # lse
-            pl.BlockSpec((1, block, 1), lambda b, i: (b, i, 0)),      # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block, d), lambda b, i: (b, i, 0)),      # dq
-            pl.BlockSpec((1, s_kv, d), lambda b, i: (b, 0, 0)),       # dk
-            pl.BlockSpec((1, s_kv, d), lambda b, i: (b, 0, 0)),       # dv
-        ],
-    )
-    kernel = functools.partial(_bwd_kernel, causal=causal, scale=scale,
-                               block_q=block)
-    dq, dk, dv = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, qp.shape[1], d), f32),
-            jax.ShapeDtypeStruct((bh, s_kv, d), f32),
-            jax.ShapeDtypeStruct((bh, s_kv, d), f32),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=5 * bh * qp.shape[1] * s_kv * d,
-            bytes_accessed=4 * (qp.size + kf.size + vf.size + gp.size),
-            transcendentals=bh * qp.shape[1] * s_kv),
-        interpret=interpret,
-    )(qp, kf, vf, gp, lsep[..., None], delta[..., None])
-    dq = dq[:, :s_q].reshape(b, h, s_q, d)
-    return (dq.astype(q.dtype), dk.reshape(b, h, s_kv, d).astype(k.dtype),
-            dv.reshape(b, h, s_kv, d).astype(v.dtype))
-
-
-def _bwd_xla(causal, scale, block_q, interpret, res, g):
-    q, k, v, out, lse = res
-    if block_q is None:
-        block_q = _resolve_block_q(q, k, causal, interpret)
-    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    b, h, s_q, d = q.shape
-    s_kv = k.shape[2]
-    block = min(block_q, s_q)
-    pad = (-s_q) % block
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    gp = jnp.pad(g, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    op = jnp.pad(out, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    # padded q rows get a large POSITIVE lse so p = exp(s - lse) -> 0
-    # (NEG_INF here would give exp(+inf) -> NaN folded into dk/dv)
-    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, pad)), constant_values=-NEG_INF)
-    n_blk = qp.shape[2] // block
-
-    # delta_i = rowsum(dO * O)
-    delta = jnp.sum(gp.astype(jnp.float32) * op.astype(jnp.float32), -1)
-
-    k_pos = jnp.arange(s_kv)
-
-    def blk(i):
-        def sl(x, ax=2):
-            return lax.dynamic_slice_in_dim(x, i * block, block, axis=ax)
-        qb, gb = sl(qp), sl(gp)
-        lb = sl(lsep)
-        db = sl(delta)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qb, k,
-                       preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = i * block + jnp.arange(block)
-            mask = q_pos[:, None] >= k_pos[None, :]
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lb[..., None])                  # (b,h,block,S)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", gb, v,
-                        preferred_element_type=jnp.float32)
-        ds = p * (dp - db[..., None]) * scale
-        dq = jnp.einsum("bhqk,bhkd->bhqd", ds, k)
-        dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qb)
-        dv = jnp.einsum("bhqk,bhqd->bhkd", p, gb)
-        return dq, dk, dv
-
-    dqs, dks, dvs = lax.map(blk, jnp.arange(n_blk))
-    dq = jnp.moveaxis(dqs, 0, 2).reshape(b, h, n_blk * block, d)[:, :, :s_q]
-    dk = jnp.sum(dks, axis=0)
-    dv = jnp.sum(dvs, axis=0)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    band = _plan(q, k, causal, window, block_q, block_k)
+    # delta_i = rowsum(dO * O); padded rows have dO = 0, so every term
+    # they would add to dK and dV is 0
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1,
+                    keepdims=True)
+    dq, dk, dv = _backward(
+        _pad_seq(q, band.bq), _pad_seq(k, band.bk), _pad_seq(v, band.bk),
+        _pad_seq(g.astype(q.dtype), band.bq), lse, _pad_seq(delta, band.bq),
+        band, scale, interpret)
+    return (dq[:, :, :q.shape[2]], dk[:, :, :k.shape[2]],
+            dv[:, :, :v.shape[2]])
 
 
 flash_attention.defvjp(_fwd, _bwd)

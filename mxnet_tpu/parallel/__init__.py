@@ -23,5 +23,5 @@ from .partition import (PartitionRules, UNMATCHED_REPLICATE,
                         UNMATCHED_ERROR, partition_summary)
 from .ring_attention import ring_attention, attention
 from .ulysses import ulysses_attention
-from .moe import moe_ffn
+from .moe import moe_layer
 from .pipeline import pipeline_apply

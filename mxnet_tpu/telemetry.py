@@ -190,6 +190,10 @@ COUNTERS = (
     # and the structured straggler verdicts the gate emits
     "heartbeat.gate_wait_ms.*", "heartbeat.gate_crossings.*",
     "dist.straggler",
+    # the routed-expert layer (ops/lm.py ``_contrib_MoE``), summed over
+    # training steps and layers: steps, rows the held experts took, rows
+    # of the fullest held expert
+    "moe.steps", "moe.rows_held", "moe.rows_max",
 )
 
 

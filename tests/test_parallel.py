@@ -245,27 +245,9 @@ def test_spmd_trainer_rmsprop_and_adagrad_run():
             (name, l0, l1, l2)
 
 
-def test_moe_ffn_matches_dense_oracle():
-    """Expert-parallel MoE (all_to_all dispatch) must equal the dense
-    per-token oracle wherever capacity is not exceeded."""
-    import jax
-    import jax.numpy as jnp
-    from mxnet_tpu import parallel
-
-    n = 4
-    mesh = parallel.make_mesh({"ep": n})
-    rs = np.random.RandomState(0)
-    B, T, E, F = 4, 8, 16, 32
-    x = rs.randn(B, T, E).astype(np.float32) * 0.5
-    wr = rs.randn(n, E).astype(np.float32)
-    w1 = rs.randn(n, F, E).astype(np.float32) * 0.1
-    w2 = rs.randn(n, E, F).astype(np.float32) * 0.1
-
-    got = np.asarray(parallel.moe_ffn(jnp.asarray(x), jnp.asarray(wr),
-                                      jnp.asarray(w1), jnp.asarray(w2),
-                                      mesh, capacity_factor=8.0))
-
-    flat = x.reshape(-1, E)
+def _switch_oracle(flat, wr, w1, w2):
+    """Dense per-token oracle of a top-1 softmax-gated ReLU expert layer;
+    w1 (n, E, F), w2 (n, F, E)."""
     logits = flat @ wr.T
     probs = np.exp(logits - logits.max(1, keepdims=True))
     probs /= probs.sum(1, keepdims=True)
@@ -273,35 +255,67 @@ def test_moe_ffn_matches_dense_oracle():
     gate = probs[np.arange(len(flat)), exp]
     want = np.zeros_like(flat)
     for i, (tok, e) in enumerate(zip(flat, exp)):
-        h = np.maximum(tok @ w1[e].T, 0)
-        want[i] = (h @ w2[e].T) * gate[i]
-    np.testing.assert_allclose(got.reshape(-1, E), want, rtol=1e-4,
-                               atol=1e-4)
+        want[i] = (np.maximum(tok @ w1[e], 0) @ w2[e]) * gate[i]
+    return want, exp
+
+
+def test_moe_ffn_matches_dense_oracle():
+    """The held-experts layer as a top-1 softmax switch layer (no gate,
+    ReLU, weights not normalised) must equal the dense per-token oracle,
+    and the shares of an ``ep`` axis (here 4 devices' worth, one expert
+    each) must add up to it."""
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel import moe
+
+    n = 4
+    rs = np.random.RandomState(0)
+    B, T, E, F = 4, 8, 16, 32
+    x = rs.randn(B, T, E).astype(np.float32) * 0.5
+    wr = rs.randn(n, E).astype(np.float32)
+    w1 = rs.randn(n, E, F).astype(np.float32) * 0.1
+    w2 = rs.randn(n, F, E).astype(np.float32) * 0.1
+    want, _ = _switch_oracle(x.reshape(-1, E), wr, w1, w2)
+
+    def layer(first, count):
+        out, counts = moe.moe_layer(
+            jnp.asarray(x), jnp.asarray(wr), None,
+            jnp.asarray(w1[first:first + count]), None,
+            jnp.asarray(w2[first:first + count]), top_k=1,
+            experts_held=(first, count), score_func="softmax",
+            route_norm=False, act="relu")
+        assert int(counts.sum()) == B * T
+        return np.asarray(out).reshape(-1, E)
+
+    np.testing.assert_allclose(layer(0, n), want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(sum(layer(e, 1) for e in range(n)), want,
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_moe_capacity_drops_tokens():
-    """Overflow tokens contribute exactly zero (switch convention)."""
+    """The layer is dropless: with a router that sends EVERY token to
+    one held expert (the case in which the old switch layer's capacity
+    zeroed the overflow), every token still gets its expert's output."""
     import jax.numpy as jnp
-    from mxnet_tpu import parallel
+    from mxnet_tpu.parallel import moe
 
     n = 2
-    mesh = parallel.make_mesh({"ep": n})
     rs = np.random.RandomState(1)
     B, T, E, F = 2, 8, 8, 8
-    x = rs.randn(B, T, E).astype(np.float32)
-    # router that sends EVERY token to expert 0
+    x = np.abs(rs.randn(B, T, E)).astype(np.float32)
     wr = np.zeros((n, E), np.float32)
-    wr[0] = 1e3 * np.ones(E) @ np.eye(E)
-    wr[0, 0] = 1e3
-    w1 = np.ones((n, F, E), np.float32) * 0.01
-    w2 = np.ones((n, E, F), np.float32) * 0.01
-    out = np.asarray(parallel.moe_ffn(
-        jnp.asarray(np.abs(x)), jnp.asarray(wr), jnp.asarray(w1),
-        jnp.asarray(w2), mesh, capacity_factor=0.3))
-    # some tokens must be zeroed (capacity < tokens routed to expert 0)
-    flat = out.reshape(-1, E)
-    assert (np.abs(flat).sum(1) == 0).any()
-    assert (np.abs(flat).sum(1) > 0).any()
+    wr[0] = 1e3
+    w1 = np.ones((n, E, F), np.float32) * 0.01
+    w2 = np.ones((n, F, E), np.float32) * 0.01
+    out, counts = moe.moe_layer(
+        jnp.asarray(x), jnp.asarray(wr), None, jnp.asarray(w1), None,
+        jnp.asarray(w2), top_k=1, score_func="softmax", route_norm=False,
+        act="relu")
+    assert list(np.asarray(counts)) == [B * T, 0]
+    want, exp = _switch_oracle(x.reshape(-1, E), wr, w1, w2)
+    assert (exp == 0).all()
+    flat = np.asarray(out).reshape(-1, E)
+    assert (np.abs(flat).sum(1) > 0).all()
+    np.testing.assert_allclose(flat, want, rtol=1e-5, atol=1e-6)
 
 
 def test_pipeline_matches_sequential():

@@ -90,12 +90,40 @@ def test_flash_attention_gradient_compiles(one_chip):
     assert "tpu_custom_call" in grad.as_text()
 
 
-def test_flash_attention_vmem_limit_at_8k(one_chip):
-    """Today's limit, written down (flash_attention.py docstring): the
-    whole local K/V block and a (block_q, S_kv) f32 score tile live in
-    VMEM, so at S=8192, D=128 the causal forward is refused. Sequence
-    parallelism (ring/ulysses) is how longer sequences are meant to run."""
-    q = jax.ShapeDtypeStruct((1, 16, 8192, 128), jnp.bfloat16,
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window"])
+def test_flash_attention_compiles_at_8k(one_chip, window):
+    """The plain entry tiles over K/V: at S=8192, D=128 with 32 query
+    heads on 4 K/V heads (the language-model cell's attention) the causal
+    forward and the gradient compile, with and without the window. (Until
+    the PR that tiled it, the whole local K/V block and a (block_q, S_kv)
+    score tile sat in VMEM and this shape was refused.)"""
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
                              sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, None, None, False, window)
+
+    assert "tpu_custom_call" in _compile(attn, q, k, k).as_text()
+    grad = _compile(jax.grad(_sum32(attn), argnums=(0, 1, 2)), q, k, k)
+    assert grad.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_attention_carry_vmem_limit_at_8k(one_chip):
+    """The carry entry (ring attention's building block) still holds the
+    whole local K/V block and a (block_q, S_kv) f32 score tile in VMEM
+    (flash_attention.py docstring), so a local block of 8192 is refused:
+    sequence parallelism is meant to keep it short."""
+    from mxnet_tpu.pallas.flash_attention import flash_attention_carry
+    q = jax.ShapeDtypeStruct((16, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    o = jax.ShapeDtypeStruct((16, 8192, 128), jnp.float32, sharding=one_chip)
+    m = jax.ShapeDtypeStruct((16, 8192), jnp.float32, sharding=one_chip)
+
+    def carry(q, k, v, o, m, l):
+        return flash_attention_carry(q, k, v, o, m, l, causal=True,
+                                     interpret=False)
+
     with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
-        _compile(_attn(True), q, q, q)
+        _compile(carry, q, q, q, o, m, m)
