@@ -118,10 +118,11 @@ def moe(data, router_weight, expert_w1_weight, expert_w3_weight,
     in).
 
     ``bias`` (num_experts,) is the router's selection bias and
-    ``load_running_sum`` (3,) = (training steps, rows the held experts
-    took, rows of the fullest held expert, both summed over the steps):
-    auxiliary states that a training step's forward pass writes, as
-    batch-norm's moving statistics are. Returns ``(out, counts)``;
+    ``load_running_sum`` (5,) = (training steps, rows the held experts
+    took, rows of the fullest held expert, chunks of the sorted order the
+    layer ran, held rows past the first chunk; each summed over the
+    steps): auxiliary states that a training step's forward pass writes,
+    as batch-norm's moving statistics are. Returns ``(out, counts)``;
     ``counts`` is hidden."""
     from ..parallel.moe import moe_layer
     held = _moe_held(dict(experts_held=experts_held,
@@ -141,18 +142,23 @@ def _moe_shapes(shapes, params):
     d, f = shapes[0][-1], int(params["hidden"])
     n, (_, count) = int(params["num_experts"]), _moe_held(params)
     return {1: (n, d), 2: (count, d, f), 3: (count, d, f),
-            4: (count, f, d), 5: (n,), 6: (3,)}
+            4: (count, f, d), 5: (n,), 6: (5,)}
 
 
 def _moe_stateful_update(raw_inputs, raw_outputs, params):
     if not params.get("_train"):
         return {}
-    from ..parallel.moe import bias_update
+    from ..parallel.moe import bias_update, chunk_load
     counts = raw_outputs[1]
     first, count = _moe_held(params)
-    rows = counts[first:first + count].astype(_F32)
-    load = raw_inputs[6] + jnp.stack([jnp.ones((), _F32), jnp.sum(rows),
-                                      jnp.max(rows)])
+    rows = counts[first:first + count]
+    # what the layer's loops ran: their own trip count, from the same counts
+    tokens = raw_inputs[0].size // raw_inputs[0].shape[-1]
+    chunks, overflow = chunk_load(rows, tokens * int(params["top_k"]),
+                                  counts.shape[0])
+    load = raw_inputs[6] + jnp.stack([
+        jnp.ones((), rows.dtype), jnp.sum(rows), jnp.max(rows), chunks,
+        overflow]).astype(_F32)
     return {5: bias_update(raw_inputs[5], counts,
                            float(params.get("load_balance_coeff", 0.0))),
             6: load}
@@ -162,11 +168,13 @@ def _publish_load(delta):
     """What ``load_running_sum`` grew by since it was last read, into the
     telemetry counters (``Executor.publish_aux_counters``)."""
     from .. import telemetry
-    steps, held, fullest = (int(round(v)) for v in delta)
+    steps, held, fullest, chunks, overflow = (int(round(v)) for v in delta)
     if steps:
         telemetry.counter_inc("moe.steps", steps)
         telemetry.counter_inc("moe.rows_held", held)
         telemetry.counter_inc("moe.rows_max", fullest)
+        telemetry.counter_inc("moe.chunks_run", chunks)
+        telemetry.counter_inc("moe.rows_overflow", overflow)
 
 
 @register("_contrib_TokenCrossEntropy", nin=3,

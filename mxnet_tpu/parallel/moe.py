@@ -15,6 +15,20 @@ the ``T x k`` selections by expert (selections of experts held elsewhere
 sort to the end) and one grouped matrix product over the sorted rows
 (``lax.ragged_dot``: a row costs one expert's product, whichever expert),
 not from a capacity: a held expert takes as many rows as are routed to it.
+
+The buffers of sorted rows have ``T k`` rows, the most a step can route
+here, and hold real rows only as far as the step routes: the grouped
+products skip the rest by themselves (and accumulate and hand back
+float32), and every other pass over the sorted rows (dispatch, rounding a
+product to the data's type, gate, weights, and their transposes) is a
+loop over *chunks* of ``chunk_rows`` rows, the held experts' even share
+of the selections, that runs as many chunks as the held rows fill
+(``chunk_load``). A step that routes its even share here pays for one
+chunk and one of noughts after it (the next product's tile may read
+there), one that routes every selection here for all ``T k /
+chunk_rows``; what lies past is never written and never read, and the
+combine takes nothing from it. A layer that holds every expert has one chunk of
+``T k`` rows.
 """
 from __future__ import annotations
 
@@ -25,8 +39,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["route", "held_experts_ffn", "moe_layer", "bias_update",
-           "PARTITION_RULES"]
+__all__ = ["route", "chunk_rows", "chunk_load", "held_experts_ffn",
+           "moe_layer", "bias_update", "PARTITION_RULES"]
 
 # The layer's layout as a partition-rule set the engine can apply
 # (``PartitionRules(PARTITION_RULES)``): the router is tiny and
@@ -63,43 +77,222 @@ def route(x, router_w, bias, top_k, score_func="sigmoid", route_norm=True,
     return sel.astype(jnp.int32), w * route_scale
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _spread(x, order, inv, k):
-    """``(T, d) -> (T k, d)``: row r is token ``order[r] // k``'s. Its
+#: rows of the grouped product's row tile where the chunks matter (the
+#: compiler's kernel at the benchmark's shapes, (65,536 x 2,048) by
+#: (16, 2,048, 1,024): its metadata lists ``T k / 512 + count - 1``
+#: tiles, which ``tests/test_tpu_compile.py`` holds it to). A chunk is a
+#: multiple of it, so no tile lies across more than two chunks.
+_TILE = 512
+
+
+def chunk_rows(selections, count, num_experts):
+    """Rows of one chunk of the sorted order: the even share of
+    ``selections`` (= T k) that ``count`` of ``num_experts`` experts take,
+    rounded up to the grouped product's tile and never more than all of
+    them (``count == num_experts``: the one chunk is the whole order)."""
+    even = -(-selections * count // num_experts)
+    return min(selections, -(-even // _TILE) * _TILE)
+
+
+def chunk_load(rows, selections, num_experts):
+    """``(chunks, overflow)`` of a layer whose held experts take ``rows``
+    (count,) of the step's ``selections``: the chunks of the sorted order
+    each row pass runs (the loops' trip count: those the held rows fill
+    and, where there is one, the chunk after them, which is written as
+    nought) and the held rows past the first chunk. Both int32 scalars."""
+    size = chunk_rows(selections, rows.shape[0], num_experts)
+    total = jnp.sum(rows.astype(jnp.int32))
+    filled = (total + size - 1) // size
+    return (jnp.minimum(filled + 1, -(-selections // size)),
+            jnp.maximum(total - size, 0))
+
+
+def _put(buf, rows, chunk):
+    return lax.dynamic_update_slice_in_dim(buf, rows, chunk * rows.shape[0],
+                                           axis=0)
+
+
+def _rows(fn, sort, bufs):
+    """``fn`` over the sorted rows, a chunk at a time and only as far as
+    the held rows reach: ``bufs`` are (rows, features) buffers of one row
+    a sorted selection, ``fn`` maps a chunk of each to a tuple of such
+    chunks, row by row. What a grouped product leaves past the held rows
+    is undefined, and the next product's tile may read past them: so the
+    rows past the held ones are written as nought, in the last chunk they
+    fill and in the one after it (a tile is no longer than a chunk). The
+    chunks after that are never written, nor read."""
+    order, _, total, trips = sort
+    size = order.shape[1]
+
+    def chunk(c, outs):
+        real = (c * size + jnp.arange(size) < total)[:, None]
+        got = fn(*(lax.dynamic_slice_in_dim(b, c * size, size) for b in bufs))
+        return tuple(_put(o, jnp.where(real, g, 0), c)
+                     for o, g in zip(outs, got))
+
+    like = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        (size,) + b.shape[1:], b.dtype) for b in bufs))
+    return lax.fori_loop(
+        0, trips, chunk,
+        tuple(lax.empty((order.size,) + o.shape[1:], o.dtype) for o in like))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _spread(x, sort, k):
+    """``(T, d) -> (chunks x size, d)``: sorted row r is token
+    ``order[r] // k``'s, written for the chunks the held rows fill. Its
     transpose is ``_collect``: both directions are row gathers (a
-    scatter-add of 65,536 rows costs the chip several times a gather)."""
-    return jnp.take(x, order // k, axis=0)
+    scatter-add of as many rows costs the chip several times a gather)."""
+    return _rows(lambda at: (jnp.take(x, at[:, 0] // k, axis=0),), sort,
+                 (sort[0].reshape(-1, 1),))[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _collect(y, order, inv, k):
-    """``(T k, d) -> (T, d)``: token t's row is the sum of the rows its k
-    selections were sorted to, ``inv[t k .. t k + k - 1]``, in float32."""
-    rows = jnp.take(y, inv, axis=0).reshape(-1, k, y.shape[1])
-    return jnp.sum(rows.astype(jnp.float32), axis=1).astype(y.dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _collect(y, sort, k):
+    """``(chunks x size, d) -> (T, d)``: token t's row is the sum, in
+    float32, of the rows its k selections were sorted to, ``inv[t k ..
+    t k + k - 1]``; a selection sorted past the held rows (its expert is
+    held elsewhere) adds nought, whatever its row holds."""
+    _, inv, total, _ = sort
+    rows = jnp.where((inv < total)[:, None], jnp.take(y, inv, axis=0), 0)
+    return jnp.sum(rows.reshape((-1, k) + y.shape[1:]).astype(jnp.float32),
+                   axis=1).astype(y.dtype)
 
 
-_spread.defvjp(lambda x, o, i, k: (_spread(x, o, i, k), (o, i)),
-               lambda k, res, g: (_collect(g, *res, k), None, None))
-_collect.defvjp(lambda y, o, i, k: (_collect(y, o, i, k), (o, i)),
-                lambda k, res, g: (_spread(g, *res, k), None, None))
+_spread.defvjp(lambda x, sort, k: (_spread(x, sort, k), sort),
+               lambda k, sort, g: (_collect(g, sort, k), None))
+_collect.defvjp(lambda y, sort, k: (_collect(y, sort, k), sort),
+                lambda k, sort, g: (_spread(g, sort, k), None))
 
 
-def held_experts_ffn(x, sel, w, w1, w3, w2, first=0, act="silu"):
+def _grouped(lhs, rhs, group_sizes):
+    """``lhs`` (m, k) rows sorted by group, ``rhs`` (g, k, n): row r of
+    group e times ``rhs[e]``, accumulated and handed back in float32. Rows
+    past the last group are undefined (on the chip they are not nought)."""
+    return lax.ragged_dot(lhs, rhs, group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+#: ``_grouped``'s transpose for ``rhs``: a group's rows of ``lhs`` (m, k)
+#: against the same rows of a cotangent (m, n)
+_BY_ROWS = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+
+def _grouped_back(lhs, rhs, group_sizes, g):
+    """``_grouped``'s two transposes for the cotangent ``g`` (m, n), which
+    comes in the operands' type (it is the cotangent of a product's
+    *rounded* rows, so nothing is lost, and the products stay products of
+    two narrow operands): the rows' in float32 as a product hands it back
+    (it is rounded where its rows are next read), ``rhs``'s in ``rhs``'s
+    type. These are the differentiation's own two products."""
+    return (_grouped(g, jnp.swapaxes(rhs, 1, 2), group_sizes),
+            lax.ragged_dot_general(
+                lhs, g, group_sizes, _BY_ROWS,
+                preferred_element_type=jnp.float32).astype(rhs.dtype))
+
+
+def _round(v, dtype):
+    """A product's float32 rows ``v`` in ``dtype``. The rounding is named
+    before the type narrows: a bare ``astype`` of a chunk the compiler
+    turns into a chunk of ``astype`` of the whole buffer, which is a pass
+    over all ``T k`` rows ahead of the loop."""
+    info = jnp.finfo(dtype)
+    return lax.reduce_precision(v, info.nexp, info.nmant).astype(dtype)
+
+
+def _gate(act, a1, *a3):
+    h = _ACTS[act](a1)
+    return h * a3[0] if a3 else h
+
+
+def _weigh(y, ws):
+    return (y.astype(jnp.float32) * ws).astype(y.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(act, xs, ws, rows, sort, w1, w3, w2):
+    """The sorted rows ``xs`` through their experts, times their weights
+    ``ws``. A row's arithmetic: operands in ``xs``'s type, each product in
+    float32 and rounded to that type, the weight applied in float32. The
+    products run over the whole order (they skip what is not real), every
+    other pass over the chunks the held rows fill. The pull-back is the
+    differentiation's own, operation for operation, written out so that
+    its passes run over those chunks too: left to the differentiation,
+    each transposed product's rounding, the widening of each cotangent
+    and the sum of the two cotangents of ``xs`` are passes over all
+    ``T k`` rows."""
+    return _experts_fwd(act, xs, ws, rows, sort, w1, w3, w2)[0]
+
+
+def _experts_fwd(act, xs, ws, rows, sort, w1, w3, w2):
+    def gate(*a):
+        r = tuple(_round(v, xs.dtype) for v in a)
+        return (_gate(act, *r),) + r
+
+    def weigh(y, ws):
+        y = _round(y, xs.dtype)
+        return _weigh(y, ws), y
+
+    # the rounded rows are what the pull-back reads: they are kept
+    h, *r = _rows(gate, sort, tuple(
+        _grouped(xs, u, rows) for u in (w1, w3) if u is not None))
+    out, y = _rows(weigh, sort, (_grouped(h, w2, rows), ws))
+    return out, (xs, ws, rows, sort, w1, w3, w2, tuple(r), h, y)
+
+
+def _experts_bwd(act, res, g):
+    xs, ws, rows, sort, w1, w3, w2, r, h, y = res
+
+    def gate(gh, *r):
+        return jax.vjp(functools.partial(_gate, act), *r)[1](
+            _round(gh, xs.dtype))
+
+    gy, gws = _rows(lambda g, y, ws: jax.vjp(_weigh, y, ws)[1](g), sort,
+                    (g, y, ws))
+    gh, gw2 = _grouped_back(h, w2, rows, gy)
+    # a product's float32 is rounded, and added to the one before, as
+    # soon as it is there: one float32 buffer of ``xs``'s size at a time
+    gxs, gw = (), []
+    for ga, u in zip(_rows(gate, sort, (gh,) + r), (w1, w3)):
+        gx, gu = _grouped_back(xs, u, rows, ga)
+        gxs = _rows(lambda v, *s: (sum(s, _round(v, xs.dtype)),), sort,
+                    (gx,) + gxs)
+        gw.append(gu)
+    gxs, = gxs
+    gw.append(None)
+    return gxs, gws, None, None, gw[0], gw[1], gw2
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+# jitted by itself: a model's expert layers are of one shape, so the step
+# traces, differentiates and lowers these passes once and calls them a layer
+@functools.partial(jax.jit, static_argnames=("first", "act"))   # mxlint: disable=jit-site -- a body inside the caller's program (the fused step's card covers it), never a dispatch of its own
+def held_experts_ffn(x, sel, w, counts, w1, w3, w2, first=0, act="silu"):
     """The held experts' terms of ``sum_e w_e expert_e(x)``.
 
-    ``x`` (T, d); ``sel``/``w`` (T, k) from ``route``; ``w1``/``w3``
-    (count, d, f) and ``w2`` (count, f, d) are experts ``first ..
-    first + count - 1`` (``w3=None``: no gate, ``act(x w1) w2``).
-    Returns ``out`` (T, d) in x's type.
+    ``x`` (T, d); ``sel``/``w`` (T, k) from ``route`` and ``counts``
+    (num_experts,) int32, the selections of each of the router's experts
+    (``moe_layer`` counts them; its length is what the held share is of);
+    ``w1``/``w3`` (count, d, f) and ``w2`` (count, f, d) are experts
+    ``first .. first + count - 1`` (``w3=None``: no gate, ``act(x w1)
+    w2``). Returns ``out`` (T, d) in x's type.
 
-    The sorted buffers have ``T k`` rows, the most a step can route here;
-    only the first ``sum(rows)`` are real. What the grouped product leaves
-    in the others is undefined (on the chip it is not nought), so they are
-    masked on the way in, which masks their gradient on the way back, and
-    on the way out."""
+    The selections are sorted by expert, those held elsewhere last; the
+    first ``sum(counts[first:first + count])`` sorted rows are real. The
+    grouped products skip the others themselves; every other pass over
+    the sorted rows (dispatch, the products' rounding to x's type, gate,
+    weights) is made ``chunk_rows(T k, count, num_experts)`` rows at a
+    time over the chunks the real rows fill, and the combine takes
+    nothing from a row past them. Past the chunk after those a buffer
+    holds whatever the memory held: no pass reads it."""
     t, k = sel.shape
     count = w1.shape[0]
+    rows = counts[first:first + count]
+    size = chunk_rows(t * k, count, counts.shape[0])
     with jax.named_scope("dispatch"):
         local = sel.reshape(-1) - first
         held = (local >= 0) & (local < count)
@@ -107,26 +300,16 @@ def held_experts_ffn(x, sel, w, w1, w3, w2, first=0, act="silu"):
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
-        rows = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
-        real = (jnp.arange(t * k) < jnp.sum(rows))[:, None]
-        xs = jnp.where(real, _spread(x, order, inv, k), 0)
-        ws = jnp.take(w.reshape(-1), order)[:, None]
+        # whole chunks, whatever the last one's start
+        order = jnp.pad(order, (0, -(t * k) % size)).reshape(-1, size)
+        sort = (order, inv, jnp.sum(rows),
+                chunk_load(rows, t * k, counts.shape[0])[0])
+        xs = _spread(x, sort, k)
+        ws = _spread(w.reshape(-1, 1), sort, 1)
     with jax.named_scope("grouped"):
-        h = _ACTS[act](_grouped(xs, w1, rows))
-        if w3 is not None:
-            h = h * _grouped(xs, w3, rows)
-        y = _grouped(h.astype(x.dtype), w2, rows)
+        y = _experts(act, xs, ws, rows, sort, w1, w3, w2)
     with jax.named_scope("combine"):
-        y = jnp.where(real, y.astype(jnp.float32) * ws, 0).astype(x.dtype)
-        return _collect(y, order, inv, k)
-
-
-def _grouped(lhs, rhs, group_sizes):
-    """``lhs`` (m, k) rows sorted by group, ``rhs`` (g, k, n): row r of
-    group e times ``rhs[e]``. Rows past the last group are undefined."""
-    return lax.ragged_dot(lhs, rhs, group_sizes,
-                          preferred_element_type=jnp.float32
-                          ).astype(lhs.dtype)
+        return _collect(y, sort, k)
 
 
 def bias_update(bias, counts, coeff):
@@ -157,5 +340,5 @@ def moe_layer(x, router_w, bias, w1, w3, w2, top_k, experts_held=None,
         sel, w = route(flat, router_w, bias, top_k, score_func, route_norm,
                        route_scale)
         counts = jnp.bincount(sel.reshape(-1), length=n).astype(jnp.int32)
-    out = held_experts_ffn(flat, sel, w, w1, w3, w2, first, act)
+    out = held_experts_ffn(flat, sel, w, counts, w1, w3, w2, first, act)
     return out.reshape(x.shape), counts

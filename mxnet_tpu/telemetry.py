@@ -192,8 +192,10 @@ COUNTERS = (
     "dist.straggler",
     # the routed-expert layer (ops/lm.py ``_contrib_MoE``), summed over
     # training steps and layers: steps, rows the held experts took, rows
-    # of the fullest held expert
-    "moe.steps", "moe.rows_held", "moe.rows_max",
+    # of the fullest held expert, chunks of the sorted order the layers
+    # ran, held rows past a layer-step's first chunk
+    "moe.steps", "moe.rows_held", "moe.rows_max", "moe.chunks_run",
+    "moe.rows_overflow",
 )
 
 

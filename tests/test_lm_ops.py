@@ -132,7 +132,7 @@ def test_moe_op():
               experts_held=tuple(c["experts_held"]), route_scale=2.826)
 
     def got(x, router, w1, w3, w2):
-        return lm.moe(x, router, w1, w3, w2, bias, jnp.zeros(3), **kw)[0]
+        return lm.moe(x, router, w1, w3, w2, bias, jnp.zeros(5), **kw)[0]
 
     def want(x, router, w1, w3, w2):
         return _ref_moe(x, dict(router=router, w1=w1, w3=w3, w2=w2), bias,
@@ -140,15 +140,15 @@ def test_moe_op():
 
     _check(got, want, (x, p["router"], p["w1"], p["w3"], p["w2"]), tol=1e-4)
     counts = lm.moe(x, p["router"], p["w1"], p["w3"], p["w2"], bias,
-                    jnp.zeros(3), **kw)[1]
+                    jnp.zeros(5), **kw)[1]
     _close(counts, _ref_moe(x, p, bias, c)[1])
     # the state a training step writes: the bias by the rule, the load
     new = get_op("_contrib_MoE").stateful_update(
-        [x, p["router"], p["w1"], p["w3"], p["w2"], bias, jnp.zeros(3)],
+        [x, p["router"], p["w1"], p["w3"], p["w2"], bias, jnp.zeros(5)],
         (None, counts), dict(kw, _train=True, load_balance_coeff=0.001))
     _close(new[5], ref.bias_update(bias, counts.astype(jnp.float32), 0.001))
     rows = np.asarray(counts)[2:6]
-    _close(new[6], [1, rows.sum(), rows.max()])
+    _close(new[6], [1, rows.sum(), rows.max(), 1, 0])
 
 
 def test_token_cross_entropy():
@@ -234,6 +234,214 @@ def test_dropless_under_forced_imbalance():
     assert list(np.asarray(counts)) == [0, 64, 0, 0]
     _close(out, _ref_moe(x, p, jnp.zeros(4), c)[0], tol=1e-4)
     assert (np.abs(np.asarray(out)).sum(1) > 0).all()
+
+
+# -- the chunks of the sorted order ------------------------------------------
+
+def _plain_whole_buffer(x, sel, w, counts, w1, w3, w2, first=0):
+    """The held experts' sum as the layer made it before it had chunks:
+    every pass over all ``T k`` sorted rows, the rows past the held ones
+    masked on the way in and out, each grouped product in float32 and
+    rounded to x's type. What the chunked layer must equal bit for bit."""
+    t, k = sel.shape
+    count = w1.shape[0]
+    local = sel.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    rows = jnp.asarray(counts)[first:first + count]
+    real = (jnp.arange(t * k) < jnp.sum(rows))[:, None]
+
+    def dot(a, b):
+        return jax.lax.ragged_dot(
+            a, b, rows, preferred_element_type=jnp.float32).astype(x.dtype)
+
+    @jax.custom_vjp
+    def spread(x):              # pulled back as the combine runs forward
+        return x[order // k]
+
+    def collect(y):
+        return jnp.sum(y[inv].reshape(t, k, -1).astype(jnp.float32),
+                       axis=1).astype(y.dtype)
+
+    spread.defvjp(lambda x: (spread(x), None), lambda _, g: (collect(g),))
+    xs = jnp.where(real, spread(x), 0)
+    y = dot(jax.nn.silu(dot(xs, w1)) * dot(xs, w3), w2)
+    y = jnp.where(real, y.astype(jnp.float32)
+                  * w.reshape(-1)[order][:, None], 0).astype(x.dtype)
+    return collect(y)
+
+
+#: 8 of 64 experts held, T 512, k 8: the even share of the 4,096
+#: selections is 512 rows, one tile, so the sorted order has 8 chunks.
+#: ``lift`` is the held experts' selection bias (it moves the top-k and
+#: not the weights): it sets how many selections land here. The last
+#: case holds all of its 8 experts: one chunk of T k rows.
+_CHUNK_CASES = [
+    # id, experts, held, T, k, lift, held rows, chunks they fill
+    ("no_row_held", 64, (8, 8), 512, 8, -2.0, 0, 0),
+    ("half_a_chunk", 64, (8, 8), 512, 8, -0.05, 258, 1),
+    ("one_chunk_nearly_full", 64, (8, 8), 512, 8, 0.0, 504, 1),
+    ("group_split_at_the_boundary", 64, (8, 8), 512, 8, 0.05, 729, 2),
+    ("two_chunks", 64, (8, 8), 512, 8, 0.1, 933, 2),
+    ("three_chunks", 64, (8, 8), 512, 8, 0.2, 1301, 3),
+    ("four_chunks", 64, (8, 8), 512, 8, 0.4, 2001, 4),
+    ("every_selection_here", 64, (8, 8), 512, 8, 2.0, 4096, 8),
+    ("all_experts_held", 8, (0, 8), 64, 2, 0.0, 128, 1),
+]
+
+
+@pytest.mark.parametrize("case", _CHUNK_CASES, ids=lambda c: c[0])
+def test_chunked_layer_matches_reference(case):
+    """Forward and the gradients of ``x``, the router and the three
+    expert stacks against the dense reference, at every load: no token is
+    dropped however many chunks the held rows fill."""
+    _, n, held, t, k, lift, want_rows, want_chunks = case
+    first, count = held
+    rs = np.random.RandomState(11)
+    d, f = 16, 8
+    c = dict(CONFIG, num_experts=n, num_experts_per_tok=k,
+             experts_held=list(held))
+    x = _rand(rs, t, d)
+    router = _rand(rs, n, d, scale=0.5)
+    bias = jnp.zeros(n).at[first:first + count].set(lift)
+    p = _moe_params(rs, c, d, f, held)
+
+    size = moe.chunk_rows(t * k, count, n)
+    assert size == (512 if count < n else t * k)
+    counts = np.asarray(moe.moe_layer(
+        x, router, bias, p["w1"], p["w3"], p["w2"], k, held,
+        route_scale=2.826)[1])[first:first + count]
+    assert counts.sum() == want_rows
+    assert -(-int(counts.sum()) // size) == want_chunks
+    # the row passes run those and, where there is one, a chunk of noughts
+    chunks, overflow = moe.chunk_load(jnp.asarray(counts), t * k, n)
+    assert int(chunks) == min(want_chunks + 1, t * k // size)
+    assert int(overflow) == max(want_rows - size, 0)
+    if case[0] == "group_split_at_the_boundary":
+        ends = np.cumsum(counts)
+        assert ((ends - counts < size) & (ends > size)).any()
+
+    def got(x, router, w1, w3, w2):
+        return moe.moe_layer(x, router, bias, w1, w3, w2, k, held,
+                             route_scale=2.826)[0]
+
+    def want(x, router, w1, w3, w2):
+        return _ref_moe(x, dict(router=router, w1=w1, w3=w3, w2=w2), bias,
+                        c)[0]
+
+    args = (x, router, p["w1"], p["w3"], p["w2"])
+    _check(got, want, args, tol=1e-4)
+    if not want_rows:
+        grads = jax.grad(lambda *a: jnp.sum(got(*a)), (0, 1, 2, 3, 4))(*args)
+        assert not np.asarray(got(*args)).any()
+        assert not any(np.asarray(g).any() for g in grads)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("share", [(8, 8, 64, 2, 0.0), (64, 8, 512, 8, 0.2)],
+                         ids=["all_held_one_chunk", "an_eighth_three_chunks"])
+def test_chunked_layer_is_the_whole_buffer_layer(share, dtype, monkeypatch):
+    """A row's arithmetic is what it was before the chunks: operands in
+    the data's type, products accumulated and handed back in float32 and
+    rounded where they are next read, a token's k terms added in float32.
+    Forward bit for bit against the whole-buffer evaluation; the gradients
+    to one place of the type at the largest entry's size (XLA's CPU
+    backend drops a rounding between two fused passes, so which sums are
+    rounded twice differs with the program's structure, and terms
+    cancel). The buffers start as NaN: nothing is taken from a row no
+    pass wrote."""
+    n, count, t, k, lift = share
+    rs = np.random.RandomState(13)
+    d, f = 16, 8
+    x = _rand(rs, t, d).astype(dtype)
+    router = _rand(rs, n, d, scale=0.5).astype(dtype)
+    bias = jnp.zeros(n).at[:count].set(lift)
+    p = {k_: v.astype(dtype) for k_, v in _moe_params(
+        rs, dict(CONFIG, num_experts=n, experts_held=[0, count]), d, f,
+        (0, count)).items()}
+    sel, w = moe.route(x, router, bias, k, route_scale=2.826)
+    counts = jnp.bincount(sel.reshape(-1), length=n).astype(jnp.int32)
+    assert int(moe.chunk_load(counts[:count], t * k, n)[0]) \
+        == (1 if count == n else 3 + 1)
+    monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
+        shape, jnp.nan, dtype))
+    moe.held_experts_ffn.clear_cache()
+
+    def chunked(x, w1, w3, w2):
+        return moe.held_experts_ffn(x, sel, w, counts, w1, w3, w2)
+
+    def plain(x, w1, w3, w2):
+        return _plain_whole_buffer(x, sel, w, counts, w1, w3, w2)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+                        (0, 1, 2, 3))(*rest)
+
+    rest = (x, p["w1"], p["w3"], p["w2"])
+    try:
+        np.testing.assert_array_equal(chunked(*rest), plain(*rest))
+        tol = 2e-5 if dtype == "float32" else 2 ** -7
+        for a, b in zip(grads(chunked), grads(plain)):
+            assert np.isfinite(np.asarray(a, np.float32)).all()
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                rtol=tol, atol=tol * float(jnp.max(jnp.abs(b))))
+    finally:
+        moe.held_experts_ffn.clear_cache()
+
+
+@pytest.mark.parametrize("share", [(64, 8), (64, 32), (64, 64)],
+                         ids=lambda s: "%d_of_%d" % s[::-1])
+def test_chunked_layer_holds_one_body(share):
+    """The program's size does not grow with the number of chunks: the
+    layer's value and gradient, under ``jax.checkpoint`` as the step runs
+    a layer, lower (for the TPU, where the grouped product is an op of
+    its own) to 12 grouped products, as the whole-buffer layer did (3
+    forward, 3 made again, 6 transposed), each over the whole sorted
+    order, and to one loop for each pass over the sorted rows (forward
+    and made again: two dispatches, gate, weights; pulled back: the
+    combine's transpose, weights, gate, and the rounding of each of the
+    two cotangents of the dispatched rows, the second added to the
+    first), whatever ``T k / chunk_rows`` is; no branch holds a second
+    copy."""
+    n, count = share
+    t, k, d, f = 512, 8, 16, 8
+    assert t * k // moe.chunk_rows(t * k, count, n) == n // count
+
+    def loss(x, router, w1, w3, w2):
+        out = moe.moe_layer(x, router, jnp.zeros(n), w1, w3, w2, k,
+                            (0, count))[0]
+        return jnp.sum(out.astype(jnp.float32))
+
+    args = (jnp.ones((t, d)), jnp.ones((n, d)), jnp.ones((count, d, f)),
+            jnp.ones((count, d, f)), jnp.ones((count, f, d)))
+    text = jax.jit(jax.value_and_grad(jax.checkpoint(loss), (0, 1, 2, 3, 4))
+                   ).trace(*args).lower(lowering_platforms=("tpu",)
+                                        ).as_text()
+    assert text.count('"chlo.ragged_dot"') == 12
+    assert text.count("stablehlo.while") == 4 + 4 + 5
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+
+
+def test_layers_of_one_shape_share_one_body():
+    """A model's expert layers are of one shape: they are calls of one
+    lowered function, so the step program holds the layer's passes once
+    however many layers it has (and is traced once for all of them)."""
+    n, count, t, k, d, f = 64, 8, 512, 8, 16, 8
+
+    def loss(x, router, w1, w3, w2):
+        for _ in range(3):
+            x = x + jax.checkpoint(lambda x: moe.moe_layer(
+                x, router, jnp.zeros(n), w1, w3, w2, k, (0, count))[0])(x)
+        return jnp.sum(x)
+
+    args = (jnp.ones((t, d)), jnp.ones((n, d)), jnp.ones((count, d, f)),
+            jnp.ones((count, d, f)), jnp.ones((count, f, d)))
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4))).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count('"chlo.ragged_dot"') == 12
+    assert text.count("stablehlo.while") == 4 + 4 + 5
 
 
 # -- the whole model through Module.fit --------------------------------------
@@ -334,6 +542,32 @@ def test_fit_publishes_moe_counters():
     assert 0 < fullest <= held <= 9 * 2 * 16 * 2
     mod._publish_aux_counters()         # nothing new: nothing added
     assert telemetry.counters()["moe.steps"] == now["moe.steps"]
+
+
+def test_fit_publishes_chunk_counters():
+    """Beside ``moe.rows_held``: the chunks of the sorted order the layers
+    ran and the held rows past a layer-step's first chunk. At this size a
+    chunk is the whole order (2 x 16 x 2 selections are under one tile),
+    so each of the 3 layers' 3 steps runs one chunk and nothing is past
+    it; the sums' arithmetic at a load of several chunks is the op's."""
+    from mxnet_tpu import telemetry
+    before = dict(telemetry.counters())
+    _fit_three_steps()
+    now = telemetry.counters()
+    assert now["moe.rows_held"] > before.get("moe.rows_held", 0)
+    assert now["moe.chunks_run"] - before.get("moe.chunks_run", 0) == 3 * 3
+    assert now.get("moe.rows_overflow", 0) \
+        == before.get("moe.rows_overflow", 0)
+    # 4,096 selections, 8 of 64 experts held: chunks of 512 rows; 1,301
+    # rows fill 3, and the passes run the chunk after them too
+    counts = jnp.zeros(64, jnp.int32).at[8:16].set(
+        jnp.asarray([171, 162, 155, 163, 155, 168, 159, 168]))
+    new = get_op("_contrib_MoE").stateful_update(
+        [jnp.zeros((1, 512, 16)), None, None, None, None, jnp.zeros(64),
+         jnp.asarray([4.0, 10.0, 5.0, 6.0, 7.0])], (None, counts),
+        dict(num_experts=64, top_k=8, experts_held=(8, 8), _train=True,
+             load_balance_coeff=0.001))
+    _close(new[6], [5, 10 + 1301, 5 + 171, 6 + 3 + 1, 7 + 1301 - 512])
 
 
 def test_mirror_stages_cut_one_segment_a_layer():

@@ -127,3 +127,52 @@ def test_flash_attention_carry_vmem_limit_at_8k(one_chip):
 
     with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
         _compile(carry, q, q, q, o, m, m)
+
+
+def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip):
+    """The routed layer at ``trinity_mini.fit``'s shapes (8,192 tokens,
+    top-8, d 2,048, f 1,024, 16 of 128 experts held, bfloat16): forward
+    and gradient compile, the sorted order in 8 chunks of 8,192 rows. The
+    grouped products stay 3 forward and 3 + 6 with the gradient, each one
+    kernel over the whole order; the row passes are loops whose trip count
+    is the step's own, and no branch holds a second body; the products'
+    row tile is the 512 rows that ``chunk_rows`` rounds to. Under
+    ``jax.checkpoint``, as the step holds a layer, the program's
+    temporaries stay at the whole-buffer layer's 2.01 GB."""
+    from mxnet_tpu.parallel import moe
+    t, k, d, f, n, count = 8192, 8, 2048, 1024, 128, 16
+    assert moe.chunk_rows(t * k, count, n) == 8192
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+
+    args = (shape(t, d), shape(n, d), shape(count, d, f),
+            shape(count, d, f), shape(count, f, d))
+
+    def layer(x, router, w1, w3, w2):
+        return moe.moe_layer(x, router, jnp.zeros(n), w1, w3, w2, k,
+                             (0, count), route_scale=2.826)[0]
+
+    def step(*a):
+        out, pull = jax.vjp(jax.checkpoint(layer), *a)
+        return out, pull(jnp.cos(out))
+
+    # the suite's "highest" is for float32 parity on the CPU; the chip's
+    # grouped product takes bfloat16 operands at the default precision only
+    with jax.default_matmul_precision("default"):
+        fwd = _compile(layer, *args)
+        grad = _compile(step, *args)
+    assert fwd.as_text().count('op_name="ragged-dot-none"') == 3
+    text = grad.as_text()
+    assert text.count('op_name="ragged-dot-none"') == 3 + 3 + 6
+    assert " while(" in text and " conditional(" not in text
+    # the grouped product walks its rows in tiles of ``moe._TILE``: its
+    # metadata lists T k / tile + count - 1 of them. A chunk is a multiple
+    # of the tile, so a tile never reaches past the chunk after the last
+    # filled one, which the row passes keep nought
+    tiles = t * k // moe._TILE + count - 1
+    metadata = [line for line in text.splitlines()
+                if 'op_name="ragged-dot-metadata"' in line
+                and "custom-call(" in line]
+    assert metadata and all("s32[%d]" % tiles in m for m in metadata)
+    assert grad.memory_analysis().temp_size_in_bytes < 2.1 * 2 ** 30
