@@ -161,7 +161,7 @@ def cross_entropy(probs, labels):
 
 
 def optimizer_params(batch):
-    """bench.py's optimizer, with the gradient averaged over the batch as
+    """SGD with momentum, with the gradient averaged over the batch as
     the reference's ``Module.init_optimizer`` does by default. This
     repo's default is ``rescale_grad=1`` (the sum): at batch 128 that is
     128 times the step, and the loss here must fall."""

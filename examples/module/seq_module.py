@@ -80,8 +80,11 @@ def run_sequential(args, train_it, val_it):
     mod_seq = mx.mod.SequentialModule()
     mod_seq.add(feature_module()) \
            .add(head_module(), take_labels=True, auto_wiring=True)
+    # SoftmaxOutput's gradient is the batch's SUM (rescale_grad is 1.0
+    # by default here): three layers deep, 0.02 diverged on one seed in
+    # five
     mod_seq.fit(train_it,
-                optimizer_params={"learning_rate": 0.02},
+                optimizer_params={"learning_rate": 0.005},
                 initializer=mx.initializer.Xavier(),
                 num_epoch=args.num_epochs)
     metric = mx.metric.Accuracy()
@@ -93,6 +96,7 @@ def run_sequential(args, train_it, val_it):
 def run_python_loss(args, train_it, val_it):
     mod = mx.mod.SequentialModule() \
             .add(feature_module()) \
+            .add(scores_module(), auto_wiring=True) \
             .add(mx.mod.PythonLossModule(grad_func=mc_hinge_grad),
                  take_labels=True, auto_wiring=True)
     # hinge grads are batch-normalised (unlike SoftmaxOutput's summed
@@ -159,6 +163,8 @@ def main():
     args = ap.parse_args()
 
     mx.random.seed(5)
+    # Xavier and NDArrayIter's shuffle draw from numpy's GLOBAL generator
+    np.random.seed(5)
     rs = np.random.RandomState(7)
     protos = rs.normal(0, 1.0, (10, 64)).astype(np.float32)
     xtr, ytr = make_data(rs, 1024, protos)

@@ -190,9 +190,9 @@ def _compiled_memory(compiled):
 def card_from_compiled(kind, compiled, entry=None, signature=None,
                        donated=(), extra=None):
     """Build one JSON-safe program card from an AOT-compiled
-    executable. The ONE card builder — the executor's instrumented
-    wrapper and bench.py's AOT step both use it, so the card schema
-    cannot drift between the user path and the bench lane. Cost and
+    executable. The ONE card builder, so the card schema cannot drift
+    between the paths that compile (the executor's instrumented
+    wrapper is its user). Cost and
     memory analysis failures degrade to ``None`` fields (older jaxlib /
     backend quirks must never break dispatch)."""
     card = {

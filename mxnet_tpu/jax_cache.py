@@ -1,6 +1,5 @@
 """Where JAX's persistent compilation cache lives for the entry points
-that touch the chip (``chip_smoke.py``, the children of ``bench.py``,
-``tools/mfu_capture.py``).
+that touch the chip (``chip_smoke.py``, ``benchmarks/run.py``).
 
 The directory is part of every cache key's world: a cache that moves
 never hits. So there are exactly two places. Where the outside set

@@ -107,9 +107,10 @@ _DURATIONS_PER_NAME = 4096
 # a crash postmortem dumps next to the span ring
 EVENT_RING_SIZE = 2048
 
-# the fit-loop phase span names — the ONE list the bench/probe artifact
-# summaries filter on, kept next to the code that records them so the
-# BENCH/MULTICHIP accountings can't silently diverge
+# the fit-loop phase span names — the ONE list the benchmark's readers
+# (benchmarks/harness/program_spans.py) and the compile cache's warm-up
+# report filter on, kept next to the code that records them so their
+# accountings can't silently diverge
 FIT_PHASE_SPANS = ("fit_batch", "feed", "step_prep", "step",
                    "step_install", "shard_put",
                    "metric_update", "metric_fetch", "opt_update",
@@ -1010,8 +1011,7 @@ def snapshot():
     """One self-describing dict: counters + span percentiles + program
     cards + the online MFU estimate + the buffer ledger + the process
     identity block. This is what ``Module.telemetry_snapshot()``
-    returns, what ``bench.py`` embeds in the BENCH/MULTICHIP artifacts
-    and what ``callback.TelemetryLogger`` diffs per log line. Every
+    returns and what ``callback.TelemetryLogger`` diffs per log line. Every
     value is JSON-serializable end to end."""
     return {
         "enabled": _state.enabled,

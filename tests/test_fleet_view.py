@@ -23,10 +23,16 @@ import flight_view  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
-def _clean_registry():
+def _clean_registry(monkeypatch):
+    """Fresh telemetry and an inert flight recorder around every test.
+    Both are process-global, and so are the recorder's dump sequence
+    and per-reason throttle: whatever dumped before this file in the
+    same xdist worker must not number or suppress a dump made here."""
     telemetry.enable()
     telemetry.reset()
     flight.configure(None)
+    monkeypatch.setattr(flight, "_seq", 0)
+    monkeypatch.setattr(flight, "_last_dump", {})
     yield
     flight.configure(None)
     telemetry.enable()

@@ -277,15 +277,18 @@ def test_openmetrics_endpoint_loopback_scrape():
 
 def test_postmortem_schema_and_flight_view_summary(tmp_path):
     flight.configure(str(tmp_path))
-    # a synthetic request trajectory in the rings: breakdown material
-    with telemetry.span("serve_wait", ctx={"req_id": 11}):
-        time.sleep(0.002)
-    with telemetry.span("serve_batch", ctx={"req_ids": [11]}):
-        time.sleep(0.001)
-    with telemetry.span("serve_d2h", ctx={"req_ids": [11]}):
-        pass
-    with telemetry.span("serve_request", ctx={"req_id": 11}):
-        time.sleep(0.004)
+    # a synthetic request trajectory in the rings: breakdown material,
+    # with explicit endpoints (sleeps of 2 and 4 ms came back 5.3 and
+    # 4.1 ms on a loaded box, and the request is to hold its own wait)
+    t0 = time.perf_counter_ns()
+    ms = 1_000_000
+    telemetry.record_span("serve_wait", t0, t0 + 2 * ms, {"req_id": 11})
+    telemetry.record_span("serve_batch", t0 + 2 * ms, t0 + 3 * ms,
+                          {"req_ids": [11]})
+    telemetry.record_span("serve_d2h", t0 + 3 * ms, t0 + 3 * ms,
+                          {"req_ids": [11]})
+    telemetry.record_span("serve_request", t0, t0 + 4 * ms,
+                          {"req_id": 11})
     telemetry.record_event("serving.batch", req_ids=[11], bucket=8,
                            rows=1, pad_rows=7)
     from mxnet_tpu.faults import InjectedFault

@@ -377,29 +377,53 @@ def test_gate_wait_span_attributes_last_arriver(tmp_path, _telemetry):
     assert cnt.get("heartbeat.gate_wait_ms.step", 0) >= 50
 
 
-def test_gate_straggler_streak_emits_event(tmp_path, _telemetry):
+def test_gate_straggler_streak_emits_event(tmp_path, _telemetry,
+                                           monkeypatch):
     """One slow crossing is noise; the SAME rank trailing the fleet
     median by >= the threshold for K consecutive crossings is a
-    straggler — a structured dist.straggler event naming it, every
-    crossing the streak persists."""
+    straggler: a structured dist.straggler event naming it, every
+    crossing the streak persists. Driven with explicit arrival samples
+    (what ``_arrivals`` reads back from the gate files), not with
+    sleeps: the streak machine is arithmetic on them."""
     root = str(tmp_path)
-    _fresh_worker(root, 0)
-    _fresh_worker(root, 1)
-    g0 = CollectiveGate(0, (0, 1), root=root, poll=0.01)
-    g1 = CollectiveGate(1, (0, 1), root=root, poll=0.01)
-    assert g0.straggler_k == 3          # default
-    _cross_pair(g0, g1, delay1=0.08, n=4)
-    evs = [e for e in _telemetry.events()
-           if e["kind"] == "dist.straggler"]
-    # streak hits K=3 at crossing 3 and persists through 4 — both
-    # ranks run the same verdict from the same files
+    gates = [CollectiveGate(r, (0, 1), root=root, poll=0.01)
+             for r in (0, 1)]
+    assert gates[0].straggler_k == 3    # default
+    assert gates[0].straggler_ms == 50  # default
+
+    def cross(gen, rank1_late_ms):
+        """Both ranks run the same verdict from the same files."""
+        for g in gates:
+            monkeypatch.setattr(g, "_arrivals", lambda _gen: [
+                (0, 1000.0 + gen, None),
+                (1, 1000.0 + gen + rank1_late_ms / 1e3, None)])
+            g._record_crossing(gen, time.perf_counter_ns())
+
+    def stragglers():
+        return [e["data"] for e in _telemetry.events()
+                if e["kind"] == "dist.straggler"]
+
+    cross(1, 80)
+    cross(2, 80)
+    assert stragglers() == []           # two slow crossings: noise yet
+    cross(3, 80)
+    cross(4, 80)
+    # the streak hits K=3 at crossing 3 and persists through 4, on
+    # both ranks
+    evs = stragglers()
     assert len(evs) == 4
-    for e in evs:
-        d = e["data"]
+    for d in evs:
         assert d["rank"] == 1
         assert d["channel"] == "step"
-        assert d["excess_ms"] >= 50
+        assert d["excess_ms"] == pytest.approx(80, abs=1e-3)
         assert d["streak"] >= 3
+    assert sorted(d["generation"] for d in evs) == [3, 3, 4, 4]
+    # one crossing under the threshold breaks the streak: the next two
+    # slow ones are noise again
+    cross(5, 10)
+    cross(6, 80)
+    cross(7, 80)
+    assert len(stragglers()) == 4
     assert _telemetry.counters().get("dist.straggler") == 4
 
 

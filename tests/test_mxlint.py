@@ -10,9 +10,9 @@ Three layers:
 2. **framework** — suppression grammar (justification REQUIRED),
    baseline grandfathering, stale-baseline tolerance + pruning, parse
    errors as findings, JSON shape, CLI exit codes;
-3. **tier-1 gate lane** — ``python tools/mxlint.py mxnet_tpu tools
-   bench.py`` exits 0 with ZERO unsuppressed findings, and the
-   ``--json`` artifact banks next to the bench JSONs
+3. **tier-1 gate lane** — ``python tools/mxlint.py mxnet_tpu
+   tools`` exits 0 with ZERO unsuppressed findings, and the
+   ``--json`` artifact is written to
    (``$MXTPU_ARTIFACT_DIR/mxlint.json``, default /tmp/mxtpu_artifacts)
    so the lint trajectory is recorded every round.
 """
@@ -578,15 +578,15 @@ def _full_repo_gate_run():
     os.makedirs(art_dir, exist_ok=True)
     art = os.path.join(art_dir, "mxlint.json")
     t0 = _time.monotonic()
-    proc = _cli(["--json", art, "mxnet_tpu", "tools", "bench.py"])
+    proc = _cli(["--json", art, "mxnet_tpu", "tools"])
     wall = _time.monotonic() - t0
     return proc, wall, art
 
 
 def test_mxlint_gate_lane():
     """`run_checks.sh lint` equivalent: zero unsuppressed findings over
-    mxnet_tpu/ tools/ bench.py against the committed baseline, with the
-    JSON report banked next to the bench artifacts."""
+    mxnet_tpu/ tools/ against the committed baseline, with the
+    JSON report written under ``$MXTPU_ARTIFACT_DIR``."""
     proc, _, art = _full_repo_gate_run()
     assert proc.returncode == 0, proc.stdout + proc.stderr
     with open(art) as f:
@@ -1308,7 +1308,7 @@ def test_changed_cli_smoke():
     # takes the cheap nothing-touched path instead of re-linting the
     # whole branch's worth of files on every tier-1 run
     proc = _cli(["--changed", "--changed-base", "HEAD",
-                 "mxnet_tpu", "tools", "bench.py"])
+                 "mxnet_tpu", "tools"])
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "--changed" in proc.stdout or "mxlint" in proc.stdout
     proc = _cli(["--changed", "--changed-base", "no-such-ref-xyz",
@@ -1326,16 +1326,14 @@ def test_changed_cli_dep_cache_self_primes(tmp_path):
     out entirely."""
     cache = str(tmp_path / "dep.json")
     first = _cli(["--changed", "--changed-base", "HEAD",
-                  "--dep-cache", cache, "mxnet_tpu", "tools",
-                  "bench.py"])
+                  "--dep-cache", cache, "mxnet_tpu", "tools"])
     assert first.returncode == 0, first.stdout + first.stderr
     if "no python files touched" in first.stdout:
         pytest.skip("clean tree: --changed has nothing to lint")
     assert "dep cache miss:absent" in first.stdout
     assert os.path.exists(cache)
     second = _cli(["--changed", "--changed-base", "HEAD",
-                   "--dep-cache", cache, "mxnet_tpu", "tools",
-                   "bench.py"])
+                   "--dep-cache", cache, "mxnet_tpu", "tools"])
     assert second.returncode == 0, second.stdout + second.stderr
     # a touched registry-DECLARING file legitimately forces the full
     # parse every time (string-keyed uses have no call edges to follow)
@@ -1343,8 +1341,7 @@ def test_changed_cli_dep_cache_self_primes(tmp_path):
             or "miss:registry-decl-touched" in second.stdout), \
         second.stdout
     off = _cli(["--changed", "--changed-base", "HEAD",
-                "--dep-cache", "none", "mxnet_tpu", "tools",
-                "bench.py"])
+                "--dep-cache", "none", "mxnet_tpu", "tools"])
     assert off.returncode == 0
     assert "dep cache off" in off.stdout
 

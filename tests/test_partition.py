@@ -19,11 +19,13 @@ Pinned properties:
    optimizer state re-committed to the weight's RULE-derived placement
    (the ``Updater._sync_state`` regression).
 4. SERVING — ``InferenceEngine(partition_rules=...)`` serves with
-   mp-sharded device-resident params BIT-equal to the replicated path.
+   mp-sharded device-resident params, equal to the replicated path as
+   far as two programs can be (``helpers.assert_equal_across_shapes``).
 5. ERRORS — batch divisibility on a 2-D mesh is checked (and reported)
    against the ``dp`` AXIS, not the device count.
 """
 import contextlib
+import gc
 import os
 
 import numpy as np
@@ -33,6 +35,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 import mxnet_tpu as mx
+from helpers import assert_equal_across_shapes
 from mxnet_tpu import nd, sym, telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.io import DataBatch, DataDesc
@@ -271,6 +274,11 @@ def test_dpxmp_ledger_param_bytes_one_over_mp():
     telemetry.enable()
     try:
         def param_bytes(**kw):
+            # collect earlier modules' parameter wrappers first: a
+            # charge stays on the ledger until its wrapper is collected
+            # (reset() keeps what is alive), so a neighbour's module
+            # not yet collected would be counted under the same mesh key
+            gc.collect()
             telemetry.reset()
             mod = _make(contexts, **kw)
             led = telemetry.ledger().get("mesh(%ddev)" % N_DEV, {})
@@ -465,11 +473,12 @@ def test_serving_mp_sharded_bit_equal_to_replicated():
         r_mp = eng.predict(data=x)
         # a second request exercises a different bucket
         r_mp1 = eng.predict(data=x[:1])
-    assert all(np.array_equal(a, b) for a, b in zip(r_repl, r_mp))
     # per-bucket comparison: each bucket's program vs the SAME bucket
-    # on the replicated engine (different buckets may legitimately
-    # compile different kernels)
-    assert all(np.array_equal(a, b) for a, b in zip(r_repl1, r_mp1))
+    # on the replicated engine; the mp-sharded program is still another
+    # program (each device's dot is an eighth as wide), so equal as far
+    # as helpers.assert_equal_across_shapes holds two programs to
+    for a, b in zip(r_repl + r_repl1, r_mp + r_mp1):
+        assert_equal_across_shapes(a, b)
 
 
 @needs_mesh

@@ -1,134 +1,146 @@
-"""Tier-1 smoke lane for the serving path.
+"""Tier-1 lanes for the serving path.
 
-Runs ``tools/serve_probe.py --serve-smoke`` (CPU backend, tiny MLP,
-256 one-row requests) as a subprocess and pins the ISSUE 5 acceptance
-numbers:
-
-- the micro-batched ``serving.InferenceEngine`` sustains >= 3x the
-  throughput of the one-request-at-a-time ``Predictor.forward`` loop at
-  max_batch >= 8;
-- EXACTLY one compiled program per bucket signature (the probe asserts
-  it via ``telemetry.programs()``) and zero compiles inside the timed
-  steady-state window;
-- request p95 latency lands in the JSON artifact.
-
-The probe's JSON banks as an artifact (``$MXTPU_ARTIFACT_DIR/
-serve_smoke.json``, default /tmp/mxtpu_artifacts) so the serving
-trajectory of the CPU lane is recorded every round, chip or no chip.
+``tools/serve_probe.py`` runs each lane once on one CPU device:
+``--serve-smoke`` (the micro-batching ``serving.InferenceEngine``),
+``--warm-smoke`` (two fresh processes over one persisted compile
+cache), ``--chaos-smoke`` (overload control under injected faults) and
+``--postmortem-smoke`` (the flight recorder). Each test below holds one
+property of a lane's JSON: counts, equality, and the guarantees the
+engine itself promises. A rate is read on the chip
+(``benchmarks/run.py``).
 """
-import json
 import os
 import subprocess
 import sys
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import pytest
+
+from helpers import ROOT, rate_keys, run_lane
 
 
-def _run_probe(art, lane_flag="--serve-smoke"):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)   # single-device lane
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "serve_probe.py"),
-         lane_flag, "--json-out", art],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        text=True, timeout=900, env=env, cwd=ROOT)
-    assert proc.returncode == 0, proc.stdout[-2000:]
-    with open(art) as f:
-        return json.loads(f.read())
+def _lane(flag):
+    @pytest.fixture(scope="module")
+    def lane(tmp_path_factory):
+        out = run_lane("serve_probe.py", flag,
+                       tmp_path_factory.mktemp("serving_lane"))
+        assert out["lane"] == flag.strip("-").replace("-", "_")
+        return out
+    return lane
 
 
-def test_serve_smoke_lane():
-    art_dir = os.environ.get("MXTPU_ARTIFACT_DIR", "/tmp/mxtpu_artifacts")
-    os.makedirs(art_dir, exist_ok=True)
-    art = os.path.join(art_dir, "serve_smoke.json")
-    try:
-        out = _run_probe(art)
-    except AssertionError:
-        out = _run_probe(art)   # one retry under CI timing noise
-    assert out["lane"] == "serve_smoke"
-    assert out["gates_passed"] is True, out
-    assert out["max_batch"] >= 8
-    # deterministic guards (no timing): one compile per bucket, none in
-    # the steady-state window, and the latency percentiles are banked
-    assert out["compiles_per_bucket"] == 1.0, out
-    assert out["telemetry"]["jit_compiles"] == 0, out
-    assert out["latency_ms"]["p95_ms"] is not None
-    assert out["batched_req_s"] > 0 and out["unbatched_req_s"] > 0
-    assert out["serve_speedup"] >= 3.0, out
+serve = _lane("--serve-smoke")
+warm = _lane("--warm-smoke")
+chaos = _lane("--chaos-smoke")
+postmortem = _lane("--postmortem-smoke")
 
 
-def test_chaos_smoke_lane():
-    """The fault-tolerant-serving acceptance lane (ISSUE 7): the
-    open-loop ladder at 2x measured capacity with injected dispatch
-    faults (delay throttle + probabilistic raises) against the bounded
-    admission queue and per-request deadlines. The probe gates: zero
-    hung futures, shed counters > 0 at 2x, admitted-request p99 <= the
-    configured deadline, and exact injected-fault accounting
-    (telemetry counter == registry fire count). This test pins the
-    artifact schema and re-asserts the deterministic halves."""
-    art_dir = os.environ.get("MXTPU_ARTIFACT_DIR", "/tmp/mxtpu_artifacts")
-    os.makedirs(art_dir, exist_ok=True)
-    art = os.path.join(art_dir, "chaos_smoke.json")
-    try:
-        out = _run_probe(art, "--chaos-smoke")
-    except AssertionError:
-        out = _run_probe(art, "--chaos-smoke")   # one retry under noise
-    assert out["lane"] == "chaos_smoke"
-    assert out["gates_passed"] is True, out
-    hot = out["offered_loads"]["2.0"]
-    # the engine degraded DELIBERATELY: structured sheds, not a hung
-    # queue — and admitted requests kept the deadline promise
+# -- serve: the micro-batching engine ---------------------------------------
+
+def test_serve_lane_one_program_a_bucket(serve):
+    assert serve["max_batch"] >= 8
+    assert len(serve["programs"]) == len(serve["buckets"]), \
+        (serve["programs"], serve["buckets"])
+
+
+def test_serve_lane_no_compile_in_window(serve):
+    assert serve["telemetry"]["jit_compiles"] == 0, serve["telemetry"]
+
+
+def test_serve_lane_one_dispatch_a_full_batch(serve):
+    """The burst goes out in full batches (the probe's coalescing
+    deadline is one no burst reaches): 256 one-row requests are 16
+    dispatches of 16 rows, none padded. This is the count behind
+    "batched serving beats one request at a time"."""
+    c = serve["telemetry"]["counters"]
+    n, width = serve["n_requests"], serve["max_batch"]
+    assert c["serving.requests"] == c["serving.resolved"] == n, c
+    assert c["dispatch.serve"] == c["serving.batches"] == n // width, c
+    assert c["serving.batch_rows"] == n and c["serving.pad_rows"] == 0, c
+
+
+def test_serve_lane_reports_no_rate(serve):
+    assert rate_keys(serve) == []
+
+
+# -- warm: the persisted compile cache --------------------------------------
+
+def test_warm_lane_cold_leg_compiles_and_stores_every_bucket(warm):
+    cold, n = warm["cold"], warm["n_buckets"]
+    assert cold["jit_compile_spans"] >= n, cold
+    assert cold["compile_cache"].get("compile_cache.store", 0) >= n, cold
+
+
+def test_warm_lane_warm_leg_never_compiles(warm):
+    assert warm["warm"]["jit_compile_spans"] == 0, warm["warm"]
+
+
+def test_warm_lane_every_program_is_a_deserialize_hit(warm):
+    leg, n = warm["warm"], warm["n_buckets"]
+    assert leg["compile_cache"].get("compile_cache.hit", 0) >= n, leg
+    assert leg["jit_deserialize_spans"] >= n, leg
+    assert leg["sources"] == ["disk_cache"], leg
+
+
+def test_warm_lane_deserialized_programs_compute_the_same_bits(warm):
+    assert warm["warm"]["probe_sum"] == warm["cold"]["probe_sum"], warm
+
+
+def test_warm_lane_reports_no_rate(warm):
+    assert rate_keys(warm) == []
+
+
+# -- chaos: overload control at 2x offered load under injected faults -------
+
+@pytest.fixture(scope="module")
+def hot(chaos):
+    return chaos["at_twice_capacity"]
+
+
+def test_chaos_lane_no_future_hangs(hot):
     assert hot["hung"] == 0, hot
-    assert hot["shed_admission"] + hot["shed_deadline"] > 0, hot
-    assert hot["admitted_latency_ms"]["p99"] <= out["deadline_ms"], hot
+
+
+def test_chaos_lane_every_admitted_request_resolves(hot):
     assert hot["ok"] + hot["shed_deadline"] + hot["failed"] \
         == hot["submitted"], hot
-    # exact injection accounting survived the trip through telemetry
-    assert hot["faults_fired"] > 0
+
+
+def test_chaos_lane_engine_sheds_under_overload(chaos, hot):
+    """The engine degraded DELIBERATELY: structured sheds, not a hung
+    queue."""
+    assert hot["shed_admission"] + hot["shed_deadline"] > 0, hot
+    assert chaos["shed_requests"] > 0, chaos
+
+
+def test_chaos_lane_admitted_p99_within_promised_deadline(chaos, hot):
+    """The one duration a lane compares: the deadline the engine itself
+    promised every admitted request."""
+    assert hot["admitted_p99_ms"] <= chaos["deadline_ms"], hot
+
+
+def test_chaos_lane_queue_stays_bounded(chaos, hot):
+    assert hot["queued_rows"] <= chaos["max_queue_rows"], hot
+
+
+def test_chaos_lane_fault_accounting_is_exact(hot):
+    """The telemetry counter equals the fault registry's fire count."""
+    assert hot["faults_fired"] > 0, hot
     assert hot["faults_injected_counter"] == hot["faults_fired"], hot
-    assert hot["queued_rows"] <= out["max_queue_rows"], hot
-    assert out["stats"]["shed_requests"] > 0
 
 
-def test_postmortem_smoke_lane():
-    """The flight-recorder acceptance lane (ISSUE 10): the chaos ladder
-    with an injected TERMINAL dispatch fault (raise:first outlasting
-    the retry budget) and the metrics sampler on. The probe gates: a
-    postmortem file appears, ``flight_view`` parses it (and rejects a
-    corrupted copy non-zero), the dump names the injected fault's site
-    and exactly the dying batch's member req_ids, the sampler banked a
-    non-empty series window, zero hung futures, and the recorder's
-    measured work stays under the <2% overhead guard. This test pins
-    the artifact schema, re-asserts the deterministic halves, and runs
-    the flight_view CLI over the banked dump itself."""
-    art_dir = os.environ.get("MXTPU_ARTIFACT_DIR", "/tmp/mxtpu_artifacts")
-    os.makedirs(art_dir, exist_ok=True)
-    art = os.path.join(art_dir, "postmortem_smoke.json")
-    try:
-        out = _run_probe(art, "--postmortem-smoke")
-    except AssertionError:
-        out = _run_probe(art, "--postmortem-smoke")  # one retry (noise)
-    assert out["lane"] == "postmortem_smoke"
-    assert out["gates_passed"] is True, out
-    # the injected terminal fault produced a REAL postmortem naming the
-    # fault's site and the dying batch's member req_ids
-    assert out["failed_requests"] > 0
-    assert out["view_summary"]["reason"] == "serving_dispatch_failure"
-    assert out["view_summary"]["exception"]["fault_site"] == "dispatch"
-    assert sorted(out["view_summary"]["extra"]["req_ids"]) \
-        == out["failed_req_ids"], out["view_summary"]
-    # the sampler banked a non-empty time-series window with samples
-    # shaped like the schema the bench artifacts embed
-    win = out["series_window"]
-    assert win["n"] > 0 and len(win["samples"]) == win["n"]
-    assert {"ts", "dt_ms", "counters", "queue_depth"} \
-        <= set(win["samples"][-1])
-    # no hung futures, and the flight-recorder work fits the <2% guard
-    assert out["hung"] == 0
-    assert out["overhead"]["frac"] < out["overhead"]["gate"], out
-    # the banked dump parses through the CLI end to end
-    pm = out["postmortem_path"]
+def test_chaos_lane_reports_no_rate(chaos):
+    assert rate_keys(chaos) == []
+
+
+# -- postmortem: the flight recorder ----------------------------------------
+
+def test_postmortem_lane_terminal_fault_leaves_a_dump_that_parses(
+        postmortem):
+    assert postmortem["failed_requests"] > 0
+    pm = postmortem["postmortem_path"]
     assert pm and os.path.exists(pm), pm
+    assert postmortem["view_rc"] == 0
+    # the dump parses through the CLI end to end
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "flight_view.py"),
          pm], stdout=subprocess.PIPE, stderr=subprocess.PIPE,
@@ -137,61 +149,50 @@ def test_postmortem_smoke_lane():
     assert "slowest requests" in proc.stdout
 
 
-def test_warm_smoke_lane():
-    """The zero-cold-start acceptance lane (ISSUE 6): two fresh
-    processes over one shared compile-cache dir. The probe gates the
-    warm leg at zero ``jit_compile`` spans, deserialize hits >= bucket
-    count, bit-identical outputs and warm startup <= 25% of cold; this
-    test pins the artifact schema and the deterministic halves of the
-    gate (the wall-clock ratio gets the usual one retry under CI
-    noise)."""
-    art_dir = os.environ.get("MXTPU_ARTIFACT_DIR", "/tmp/mxtpu_artifacts")
-    os.makedirs(art_dir, exist_ok=True)
-    art = os.path.join(art_dir, "warm_smoke.json")
-    try:
-        out = _run_probe(art, "--warm-smoke")
-    except AssertionError:
-        out = _run_probe(art, "--warm-smoke")   # one retry under noise
-    assert out["lane"] == "warm_smoke"
-    assert out["gates_passed"] is True, out
-    # the deterministic contract, independent of the timing gate: a
-    # warm process serving every bucket never invokes XLA
-    assert out["warm"]["jit_compile_spans"] == 0, out
-    assert out["warm"]["jit_deserialize_spans"] >= out["n_buckets"], out
-    assert out["warm"]["compile_cache"].get(
-        "compile_cache.hit", 0) >= out["n_buckets"], out
-    assert out["cold"]["compile_cache"].get(
-        "compile_cache.store", 0) >= out["n_buckets"], out
-    assert out["warm"]["sources"] == ["disk_cache"], out
-    # deserialized executables compute the SAME function, bit for bit
-    assert out["warm"]["probe_sum"] == out["cold"]["probe_sum"], out
-    assert out["warm_vs_cold"] <= out["ratio_gate"], out
+def test_postmortem_lane_dump_names_fault_site_and_dying_batch(postmortem):
+    summary = postmortem["view_summary"]
+    assert summary["reason"] == "serving_dispatch_failure", summary
+    assert summary["exception"]["fault_site"] == "dispatch", summary
+    assert sorted(summary["extra"]["req_ids"]) \
+        == postmortem["failed_req_ids"], summary["extra"]
 
 
-def test_recalibrated_warm_gate_math():
-    """The in-run warm-gate recalibration (ISSUE 14): gate =
-    clamp(1.4 * (1 - compile_share), 0.25, 0.85) from the cold leg's
-    own span accounting; unusable accounting degrades to the cap
-    (only demand SOME win)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_serve_probe", os.path.join(ROOT, "tools", "serve_probe.py"))
-    sp = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sp)
-    gate = sp._recalibrated_warm_gate
-    # compile-dominated box: clamps to the old absolute strength
-    p, g = gate({"startup_s": 10.0, "jit_compile_s": 8.0,
-                 "jit_trace_s": 1.0})
-    assert p == 0.1 and g == sp.WARM_RATIO_FLOOR == 0.25
-    # share-throttled box (this one): the gate relaxes to what the
-    # box can actually show, with margin
-    p, g = gate({"startup_s": 1.335, "jit_compile_s": 0.6057,
-                 "jit_trace_s": 0.2191})
-    assert 0.35 < p < 0.42 and 0.5 < g < 0.6
-    # overhead-only box: caps — a warm leg must still show a real win
-    p, g = gate({"startup_s": 10.0, "jit_compile_s": 0.5,
-                 "jit_trace_s": 0.0})
-    assert g == sp.WARM_RATIO_CAP == 0.85
-    # no usable accounting: cap, never a crash
-    p, g = gate({"startup_s": 0.0})
-    assert p is None and g == sp.WARM_RATIO_CAP
+def test_postmortem_lane_corrupted_dump_is_rejected(postmortem):
+    assert postmortem["corrupt_view_rc"] not in (None, 0)
+
+
+def test_postmortem_lane_sampler_keeps_a_series_window(postmortem):
+    win = postmortem["series_window"]
+    assert win["n"] > 0 and len(win["samples"]) == win["n"]
+    assert {"ts", "dt_ms", "counters", "queue_depth"} \
+        <= set(win["samples"][-1])
+
+
+def test_postmortem_lane_no_future_hangs(postmortem):
+    assert postmortem["hung"] == 0
+
+
+def test_postmortem_lane_recorder_work_is_bounded_a_request(postmortem):
+    """What the recorder does in the waves' window, counted: two spans a
+    request (wait, request), two a batch (batch, d2h), one
+    ``serving.batch`` event a batch, and nothing else but the lane's
+    own injected-fault events and a shed's. This is the count that the
+    old share-of-wall-clock guard stood for."""
+    rec = postmortem["recorder"]
+    requests = rec["counters"]["serving.requests"]
+    batches = rec["counters"]["serving.batches"]
+    assert requests == rec["requests"] and 0 < batches <= requests, rec
+    spans = rec["span_counts"]
+    assert set(spans) <= {"serve_wait", "serve_request", "serve_batch",
+                          "serve_d2h"}, spans
+    assert spans["serve_wait"] <= requests, rec
+    assert spans["serve_request"] <= requests, rec
+    assert spans["serve_batch"] == spans["serve_d2h"] == batches, rec
+    events = rec["event_counts"]
+    assert events.pop("serving.batch") == batches, rec
+    assert events.pop("serving.shed", 0) <= requests, rec
+    assert set(events) <= {"fault.injected"}, events
+
+
+def test_postmortem_lane_reports_no_rate(postmortem):
+    assert rate_keys(postmortem) == []

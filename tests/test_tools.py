@@ -255,6 +255,10 @@ def test_launch_dead_node_visibility(tmp_path):
         "kv = mx.kv.create('dist_sync')\n"
         "kv.barrier()\n"
         "assert kv.num_dead_node() == 0, kv.num_dead_node()\n"
+        # rank 1 may die only once rank 0 has looked: a stopped
+        # heartbeat reads as dead at once, and a rank 0 held up after
+        # the first barrier saw 1 here
+        "kv.barrier()\n"
         "if kv.rank == 1:\n"
         "    from mxnet_tpu import heartbeat\n"
         "    heartbeat.stop_heartbeat()\n"
@@ -297,20 +301,6 @@ def test_launch_push_discipline_mismatch_fails_loudly(tmp_path):
     combined = p.stdout + p.stderr
     assert "discipline violated" in combined, combined
     assert "UNREACHABLE" not in p.stdout
-
-
-def test_mfu_capture_smoke():
-    """The fresh-capture roofline tool: traced bench child on CPU, xplane
-    parsed, category shares extracted (the on-chip run reuses this path)."""
-    import json
-    p = _run([os.path.join(TOOLS, "mfu_capture.py"), "--timeout", "420"],
-             env={**os.environ, "MXTPU_BENCH_SMOKE": "1"}, timeout=500)
-    assert p.returncode == 0, p.stderr[-1500:]
-    out = json.loads(p.stdout.strip().splitlines()[-1])
-    assert out["hlo_rows"] > 100
-    shares = out["self_time_share"]
-    assert "convolution fusions" in shares
-    assert abs(sum(shares.values()) - 1.0) < 0.01
 
 
 def test_accnn_low_rank_factorization(tmp_path):

@@ -99,7 +99,8 @@ def test_cifar_shape_conv_bf16_converges():
     test_dtype.py run_cifar10 shape: conv+BN stack on 3x32x32, low-
     precision data iterator): bf16 activations with fp32 master weights
     (multi_precision) and fp32 BN params via the InferType pass — the
-    exact numeric regime bench.py's ResNet-50 measurement relies on.
+    exact numeric regime the ``resnet50.fit`` cell's measurement relies
+    on.
     Must clear an accuracy threshold far above the reference's 0.08."""
     import jax.numpy as jnp
     rng = np.random.RandomState(5)

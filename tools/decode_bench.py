@@ -5,7 +5,7 @@ iter_image_recordio_2's multithreaded decode (src/io/
 iter_image_recordio_2.cc:660-760); this probe packs synthetic JPEGs into
 RecordIO and measures ImageIter decode img/s at a given thread count, so
 a deployment can check the pipeline feeds the accelerator (compare
-against bench.py's img/s).
+against the ledger's ``train_img_s`` for ``resnet50.fit``).
 
 Usage: python tools/decode_bench.py [--threads N] [--images M]
                                     [--size HxW] [--batch B]

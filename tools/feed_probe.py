@@ -8,7 +8,7 @@ OVERLAPS decode with consumption, so feeding a consumer that takes
 `t_step` per batch costs max(decode, consume) wall-clock, not the sum.
 
 A deployment points `--target-img-s` at its measured train throughput
-(bench.py's img/s): the probe reports whether the feed sustained it,
+(the ledger's ``train_img_s``): the probe reports whether the feed sustained it,
 the overlap efficiency, and how many decode cores at the measured
 per-core rate the target needs.
 
@@ -234,7 +234,7 @@ def main():
     ap.add_argument("--size", default="224x224")
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--target-img-s", type=float, default=None,
-                    help="consumer rate to sustain (e.g. bench.py's "
+                    help="consumer rate to sustain (e.g. the chip's "
                          "measured img/s); default: decode capacity "
                          "scaled by --target-fraction")
     ap.add_argument("--target-fraction", type=float, default=1.0)

@@ -5,11 +5,11 @@ Usage::
 
     python tools/mxlint.py [options] <paths...>
 
-    python tools/mxlint.py mxnet_tpu tools bench.py        # the CI gate
+    python tools/mxlint.py mxnet_tpu tools        # the CI gate
     python tools/mxlint.py --json out.json mxnet_tpu       # JSON report
     python tools/mxlint.py --rules jit-site mxnet_tpu      # one rule
-    python tools/mxlint.py --update-baseline mxnet_tpu tools bench.py
-    python tools/mxlint.py --changed mxnet_tpu tools bench.py  # pre-commit
+    python tools/mxlint.py --update-baseline mxnet_tpu tools
+    python tools/mxlint.py --changed mxnet_tpu tools  # pre-commit
 
 Options:
     --rules a,b,...      run only these rule ids (default: all)

@@ -1,53 +1,57 @@
 #!/usr/bin/env python3
-"""Serving-path probe: the micro-batching engine vs the one-request-
-at-a-time Predictor facade, and the zero-cold-start compile tier.
+"""The count-only tier-1 lanes of the serving path: the micro-batching
+engine, the persisted compile cache, overload control, the flight
+recorder and the continuous-batching decode engine.
+
+Every lane runs on XLA's CPU backend, prints ONE JSON object (and writes
+it to ``--json-out``) and exits 0 whenever it ran to the end: counts,
+bytes, equality and the guarantees the engine itself promises, never a
+rate or a ratio of two clocks. ``tests/test_serving_lane.py`` and
+``tests/test_decode_lane.py`` hold each property as a test of its own.
+A rate is read on the chip by ``benchmarks/run.py`` and nowhere else.
 
 Serve-smoke lane:   python tools/serve_probe.py --serve-smoke \
                         [--json-out PATH]
-  (tier-1 CI: tiny-MLP on the CPU backend — the batched
-  ``serving.InferenceEngine`` vs a sequential ``Predictor.forward``
-  loop, interleaved best-of timing. Gates: batched sustained
-  throughput >= 3x unbatched at max_batch >= 8, and EXACTLY one
-  compile per bucket signature via ``telemetry.programs()``. The JSON
-  artifact banks both throughputs, the request p50/p95/p99 and the
-  per-bucket program cards every round; the engine's measured serving
-  data lands in the card corpus for the autotuner.)
+  (tiny MLP, 256 one-row requests through ``serving.InferenceEngine``:
+  one compiled program per bucket signature via
+  ``telemetry.programs()``, zero ``jit_compile`` spans in the window,
+  and the window's dispatches against the requests it served)
 
 Warm-smoke lane:    python tools/serve_probe.py --warm-smoke \
                         [--json-out PATH]
-  (tier-1 CI for the PERSISTED compile cache, ISSUE 6: two fresh
-  processes construct the same serving engine over one shared
-  ``MXNET_COMPILE_CACHE`` dir. The first (cold) compiles and stores
-  every bucket program; the second (warm) must register ZERO
-  ``jit_compile`` spans, >= bucket-count deserialize hits, produce
-  bit-identical outputs, and start up inside the in-run recalibrated
-  ratio gate — the compile share the cold leg's own spans prove the
-  warm leg skips, with margin, clamped to [0.25x, 0.85x] of cold.)
+  (the PERSISTED compile cache, ISSUE 6: two fresh processes construct
+  the same serving engine over one shared ``MXNET_COMPILE_CACHE`` dir.
+  The first (cold) compiles and stores every bucket program; the second
+  (warm) is to register ZERO ``jit_compile`` spans and a deserialize
+  hit for every bucket, and to answer the probe request with the cold
+  leg's bits.)
 
 Chaos-smoke lane:   python tools/serve_probe.py --chaos-smoke \
                         [--json-out PATH]
-  (tier-1 CI for the OVERLOAD-CONTROL path, ISSUE 7: the engine runs
-  an open-loop offered-load ladder up to 2x its measured capacity with
+  (the OVERLOAD-CONTROL path, ISSUE 7: the engine runs an open-loop
+  offered-load ladder up to 2x the capacity it shows under
   ``MXNET_FAULTS``-style injected dispatch faults (a per-dispatch
   delay throttling capacity + probabilistic raises exercising the
-  retry budget), a bounded admission queue and per-request deadlines.
-  Gates: ZERO hung futures (every submitted future resolves), shed
-  counters > 0 at 2x offered load, admitted-request p99 <= the
-  configured deadline, and the injected-fault telemetry counter equals
-  the registry's exact fire count.)
+  retry budget), with a bounded admission queue and per-request
+  deadlines. Reported: hung futures, shed counters at 2x offered load,
+  admitted-request p99 beside the deadline the engine promised, and the
+  injected-fault telemetry counter beside the registry's fire count.)
 
 Postmortem-smoke lane:  python tools/serve_probe.py --postmortem-smoke \
                             [--json-out PATH]
-  (tier-1 CI for the FLIGHT RECORDER, ISSUE 10: the chaos ladder runs
-  with the metrics sampler on and an injected TERMINAL dispatch fault
-  — ``dispatch:raise:first=K`` outlasting the retry budget, so one
-  batch fails for good. Gates: a postmortem JSON appears in the flight
-  dir, ``tools/flight_view.py`` parses it (and REJECTS a corrupted
-  copy non-zero), the dump names the injected fault's site and exactly
-  the dying batch's member req_ids, the sampler banked a non-empty
-  time-series window, and the measured flight-recorder work — causal-
-  id spans, events, sampler ticks — stays under the <2% telemetry
-  overhead guard.)
+  (the FLIGHT RECORDER, ISSUE 10: closed-loop waves run with the
+  metrics sampler on after an injected TERMINAL dispatch fault
+  (``dispatch:raise:first=K`` outlasting the retry budget, so one
+  batch fails for good). Reported: the postmortem JSON in the flight
+  dir as ``tools/flight_view.py`` parses it (and whether it REJECTS a
+  corrupted copy), the fault site and the dying batch's member req_ids
+  the dump names, the sampler's time-series window, and what the
+  recorder did in the waves' window: spans and events by name, beside
+  the requests and batches they were recorded for.)
+
+Decode-smoke lane:  python tools/serve_probe.py --decode-smoke \
+                        [--json-out PATH]
+  (the continuous-batching decode engine; see ``decode_smoke``)
 """
 import json
 import os
@@ -59,6 +63,13 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+from lane_common import emit, force_cpu_devices, main
+
+DEC_MP = 8                         # mp-sharded KV-cache leg mesh width
+
+if "--decode-smoke" in sys.argv:
+    force_cpu_devices(DEC_MP)      # the decode lane's mp leg needs the mesh
+
 import numpy as np
 import jax
 
@@ -66,53 +77,17 @@ jax.config.update("jax_platforms", "cpu")
 
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
-from mxnet_tpu import compile_cache
-from mxnet_tpu.predictor import Predictor
 from mxnet_tpu.serving import InferenceEngine
 
 D, C, HID = 16, 4, 64
 N_REQ = 256
 MAX_BATCH = 16
-ROUNDS = 5
-SPEEDUP_GATE = 3.0
 
-# warm-smoke model: deep enough that XLA compile dominates a cold
-# start (the tier this lane gates exists to delete that cost); the
-# fixed startup work (bind, shape inference, rng key) is identical
-# across the legs
+# warm-smoke model: deep enough that every bucket is a program worth
+# persisting; the fixed startup work (bind, shape inference, rng key)
+# is identical across the legs
 WARM_LAYERS, WARM_HID, WARM_D = 32, 192, 32
 WARM_MAX_BATCH = 32
-# warm-smoke startup-ratio gate, recalibrated IN-RUN (ISSUE 14): the
-# old absolute <=0.25x false-fails on share-throttled boxes (0.47x
-# measured at seed there) where the python/infer overhead BOTH legs
-# pay dwarfs the compile time the warm leg skips. Predict the
-# achievable ratio from the COLD leg's own compile-span share —
-# warm ~= cold - (trace+compile) + deserialize, so the ratio floor is
-# 1 - compile_share — gate at WARM_GATE_MARGIN of that prediction
-# (deserialize + noise headroom), clamped to [FLOOR, CAP]: a healthy
-# compile-dominated box still gates at the old 0.25x strength, and no
-# box ever passes without a REAL warm win. The fit-smoke gate (PR 6,
-# tools/module_fit_probe.py) pioneered this recalibrate-from-the-
-# oracle-leg's-own-accounting pattern.
-WARM_RATIO_FLOOR = 0.25      # never demands better than the old gate
-WARM_RATIO_CAP = 0.85        # always demands a real warm win
-WARM_GATE_MARGIN = 1.4       # headroom over the span-predicted ratio
-
-
-def _recalibrated_warm_gate(cold):
-    """(predicted warm/cold ratio, gate) from the cold leg's banked
-    compile/trace span seconds; (None, CAP) when the cold leg carries
-    no usable accounting (the gate then only demands some win)."""
-    startup = float(cold.get("startup_s") or 0.0)
-    skipped = (float(cold.get("jit_compile_s") or 0.0)
-               + float(cold.get("jit_trace_s") or 0.0))
-    if startup <= 0 or skipped <= 0:
-        return None, WARM_RATIO_CAP
-    share = min(skipped / startup, 1.0)
-    predicted = max(1.0 - share, 0.0)
-    gate = min(WARM_RATIO_CAP,
-               max(WARM_RATIO_FLOOR, predicted * WARM_GATE_MARGIN))
-    return round(predicted, 3), round(gate, 3)
 
 
 def _mlp():
@@ -132,130 +107,55 @@ def _params(symbol):
             if n not in ("data", "softmax_label")}
 
 
-def serve_smoke(json_out=None, n_req=N_REQ, rounds=ROUNDS):
-    # bank this lane's measured serving data into the card corpus
-    # (engine.close() appends) so the autotuner has a trajectory even
-    # on rounds where nothing else served traffic
-    os.environ.setdefault("MXNET_CARD_CORPUS", os.path.join(
-        os.environ.get("MXTPU_ARTIFACT_DIR", "/tmp/mxtpu_artifacts"),
-        "card_corpus.jsonl"))
+def serve_smoke(json_out=None, n_req=N_REQ):
     sym = _mlp()
     params = _params(sym)
     rng = np.random.RandomState(1)
     reqs = [rng.normal(size=(1, D)).astype(np.float32)
             for _ in range(n_req)]
-
-    pred = Predictor(sym, params, {"data": (1, D)})
-    pred.forward(data=reqs[0])        # compile the unbatched signature
-    pred.get_output(0).asnumpy()
+    # a coalescing deadline no burst reaches: a batch goes out when it
+    # is FULL (and the flush sends the rest), so how the burst forms
+    # batches does not depend on how fast this host submits it
     engine = InferenceEngine(sym, params, {"data": (1, D)},
-                             max_batch=MAX_BATCH, max_wait_ms=1.0,
+                             max_batch=MAX_BATCH, max_wait_ms=60e3,
                              max_inflight=4)
-    # the bucket cache as warmup built it — captured BEFORE the timed
-    # windows (each window telemetry.reset() clears the registry; cards
-    # re-register on dispatch, so the post-traffic registry only shows
-    # the buckets the last window happened to use)
-    cards = engine.program_cards()
-
-    def unbatched_epoch():
-        t0 = time.perf_counter()
-        for x in reqs:
-            pred.forward(data=x)
-            pred.get_output(0).asnumpy()
-        return time.perf_counter() - t0
-
-    def batched_epoch():
-        t0 = time.perf_counter()
-        futs = [engine.submit(data=x) for x in reqs]
-        for f in futs:
-            f.result(timeout=300)
-        return time.perf_counter() - t0
-
-    # interleaved best-of (the module_fit_probe timing discipline:
-    # back-to-back legs keep the RATIO honest under CI share drift; the
-    # min converges on the dispatch floor under spike noise)
-    dt_un = dt_b = float("inf")
-    batched_window = {}
+    # the bucket cache as warmup built it, captured BEFORE the window
+    # (telemetry.reset() clears the registry; cards re-register on
+    # dispatch, so the registry afterwards only shows the buckets the
+    # window happened to use)
+    programs = sorted(engine.program_cards())
     was_enabled = telemetry.enabled()
     telemetry.enable()
     try:
-        for _ in range(rounds):
-            dt_un = min(dt_un, unbatched_epoch())
-            telemetry.reset()
-            dt = batched_epoch()
-            if dt <= dt_b:
-                dt_b = dt
-                snap = telemetry.snapshot()
-                batched_window = {
-                    "counters": {k: v for k, v in snap["counters"].items()
-                                 if k.startswith(("serving.",
-                                                  "dispatch."))},
-                    "spans": {k: v for k, v in snap["spans"].items()
-                              if k in telemetry.SERVE_SPANS},
-                    # _InstrumentedProgram._build times every program
-                    # build as a jit_compile span — the engine dispatch
-                    # path never touches the jit.compile COUNTER (that
-                    # counts _GraphProgram entry-point lookups), so the
-                    # span count is the one signal that catches a
-                    # per-batch recompile inside the timed window
-                    "jit_compiles": snap["spans"].get(
-                        "jit_compile", {}).get("count", 0),
-                }
+        telemetry.reset()
+        futs = [engine.submit(data=x) for x in reqs]
+        engine.flush()
+        for f in futs:
+            f.result(timeout=300)
+        snap = telemetry.snapshot()
     finally:
         if not was_enabled:
             telemetry.disable()
-
-    lat = batched_window.get("spans", {}).get("serve_request", {})
     out = {
         "lane": "serve_smoke",
-        "platform": jax.devices()[0].platform,
         "n_requests": n_req,
         "max_batch": MAX_BATCH,
         "buckets": engine.buckets,
-        "unbatched_req_s": round(n_req / dt_un, 1),
-        "batched_req_s": round(n_req / dt_b, 1),
-        "serve_speedup": round(dt_un / dt_b, 2),
-        "latency_ms": {k: lat.get(k)
-                       for k in ("p50_ms", "p95_ms", "p99_ms")},
-        "batch_fill": engine.stats()["batch_fill"],
-        "telemetry": batched_window,
-        "program_cards": {
-            k: {kk: c.get(kk) for kk in
-                ("kind", "signature", "flops", "peak_bytes",
-                 "compile_ms", "dispatches")}
-            for k, c in cards.items()},
-        "compiles_per_bucket": round(len(cards) / len(engine.buckets), 2),
+        "programs": programs,
+        "telemetry": {
+            "counters": {k: v for k, v in snap["counters"].items()
+                         if k.startswith(("serving.", "dispatch."))},
+            # _InstrumentedProgram._build times every program build as
+            # a jit_compile span: the engine dispatch path never
+            # touches the jit.compile COUNTER (that counts
+            # _GraphProgram entry-point lookups), so the span count is
+            # the one signal that catches a recompile inside the window
+            "jit_compiles": snap["spans"].get(
+                "jit_compile", {}).get("count", 0),
+        },
     }
     engine.close()
-    # what the corpus-fed autotuner would plan from the recorded
-    # trajectory (informational here; unit-tested in test_tuner.py)
-    try:
-        from mxnet_tpu.tuner import plan_serving
-        out["autotune_plan"] = plan_serving(
-            compile_cache.corpus_records(kind="serving"),
-            max_batch=MAX_BATCH)
-    except Exception:
-        out["autotune_plan"] = None
-    # the serving acceptance gates (ISSUE 5): exactly one compiled
-    # program per bucket signature, ZERO compiles inside the timed
-    # steady-state window (every dispatch a cache hit), and sustained
-    # batched throughput >= SPEEDUP_GATE x the sequential Predictor loop
-    try:
-        assert len(cards) == len(engine.buckets), \
-            ("compiles != buckets", sorted(cards), engine.buckets)
-        assert batched_window.get("jit_compiles", -1) == 0, batched_window
-        assert out["serve_speedup"] >= SPEEDUP_GATE, out["serve_speedup"]
-        out["gates_passed"] = True
-    except AssertionError:
-        out["gates_passed"] = False
-        raise
-    finally:
-        line = json.dumps(out)
-        print(line, flush=True)
-        if json_out:
-            with open(json_out, "w") as f:
-                f.write(line + "\n")
-    return out
+    return emit(out, json_out)
 
 
 def _warm_mlp():
@@ -270,11 +170,11 @@ def _warm_mlp():
 
 
 def warm_child():
-    """One process's leg of the warm-smoke A/B: construct (and warm up)
+    """One process's leg of the warm-smoke pair: construct (and warm up)
     the serving engine over the ambient ``MXNET_COMPILE_CACHE``, serve
-    a fixed probe request, and report the startup wall next to the
-    compile-vs-deserialize telemetry split. Cold or warm is decided
-    entirely by what the cache dir already holds."""
+    a fixed probe request, and report the compile-tier spans and the
+    cache's counters. Cold or warm is decided entirely by what the
+    cache dir already holds."""
     sym = _warm_mlp()
     rng = np.random.RandomState(0)
     shapes, _, _ = sym.infer_shape_partial(data=(2, WARM_D))
@@ -285,31 +185,18 @@ def warm_child():
     probe_req = rng.normal(size=(1, WARM_D)).astype(np.float32)
     telemetry.enable()
     telemetry.reset()
-    t0 = time.perf_counter()
     engine = InferenceEngine(sym, params, {"data": (1, WARM_D)},
                              max_batch=WARM_MAX_BATCH, max_wait_ms=1.0,
                              max_inflight=4)
-    startup_s = time.perf_counter() - t0
     outs = engine.submit(data=probe_req).result(timeout=120)
     snap = telemetry.snapshot()
     spans = {k: snap["spans"].get(k, {}).get("count", 0)
              for k in telemetry.COMPILE_SPANS}
-    span_s = {k: round(snap["spans"].get(k, {}).get("total_ms", 0.0)
-                       / 1e3, 4)
-              for k in telemetry.COMPILE_SPANS}
     out = {
         "lane": "warm_child",
-        "cache_dir": compile_cache.cache_dir(),
-        "startup_s": round(startup_s, 3),
         "buckets": engine.buckets,
-        "jit_trace_spans": spans["jit_trace"],
         "jit_compile_spans": spans["jit_compile"],
         "jit_deserialize_spans": spans["jit_deserialize"],
-        # wall SECONDS per compile-tier span — the cold leg's own
-        # accounting the in-run gate recalibration predicts from
-        "jit_trace_s": span_s["jit_trace"],
-        "jit_compile_s": span_s["jit_compile"],
-        "jit_deserialize_s": span_s["jit_deserialize"],
         "compile_cache": {k: v for k, v in snap["counters"].items()
                           if k.startswith("compile_cache.")},
         "sources": sorted({c.get("source") for c in
@@ -324,14 +211,11 @@ def warm_child():
 
 
 def warm_smoke(json_out=None):
-    """The warm-start acceptance lane (ISSUE 6): two FRESH processes
-    over one shared compile-cache dir. Process 1 (cold) populates the
-    store; process 2 (warm) must skip XLA entirely — zero
-    ``jit_compile`` spans, deserialize hits >= bucket count — match
-    the cold outputs bit-for-bit, and start inside the IN-RUN
-    recalibrated ratio gate (the compile share the cold leg's own
-    spans say the warm leg can skip, with margin, clamped to
-    [0.25, 0.85] — see ``_recalibrated_warm_gate``, ISSUE 14)."""
+    """The warm-start lane (ISSUE 6): two FRESH processes over one
+    shared compile-cache dir. Process 1 (cold) populates the store;
+    process 2 (warm) is to skip XLA entirely (zero ``jit_compile``
+    spans, a deserialize hit for every bucket) and to match the cold
+    leg's output bit for bit."""
     cache = tempfile.mkdtemp(prefix="mxtpu_warm_smoke_cc_")
     legs = {}
     try:
@@ -348,61 +232,19 @@ def warm_smoke(json_out=None):
                 if line.strip().startswith("{"):
                     parsed = json.loads(line)
                     break
-            assert proc.returncode == 0 and parsed is not None, \
-                ("warm-smoke %s child failed" % leg, proc.returncode,
-                 proc.stdout[-2000:])
+            if proc.returncode != 0 or parsed is None:
+                raise SystemExit("warm-smoke %s child failed (rc %d): %s"
+                                 % (leg, proc.returncode,
+                                    proc.stdout[-2000:]))
             legs[leg] = parsed
     finally:
         shutil.rmtree(cache, ignore_errors=True)
-    cold, warm = legs["cold"], legs["warm"]
-    n_buckets = len(cold["buckets"])
-    predicted, gate = _recalibrated_warm_gate(cold)
-    out = {
+    return emit({
         "lane": "warm_smoke",
-        "platform": jax.devices()[0].platform,
-        "n_buckets": n_buckets,
-        "cold": cold,
-        "warm": warm,
-        "warm_vs_cold": round(warm["startup_s"] / cold["startup_s"], 3)
-        if cold["startup_s"] else None,
-        # the in-run recalibrated gate + its inputs, banked so a lane
-        # failure is diagnosable from the artifact alone
-        "ratio_gate": gate,
-        "predicted_warm_vs_cold": predicted,
-        "ratio_gate_floor": WARM_RATIO_FLOOR,
-        "ratio_gate_cap": WARM_RATIO_CAP,
-        "ratio_gate_margin": WARM_GATE_MARGIN,
-    }
-    try:
-        # cold leg: every bucket compiled AND persisted
-        assert cold["jit_compile_spans"] >= n_buckets, cold
-        assert cold["compile_cache"].get(
-            "compile_cache.store", 0) >= n_buckets, cold
-        # warm leg: ZERO XLA compiles, every program a deserialize hit
-        assert warm["jit_compile_spans"] == 0, warm
-        assert warm["compile_cache"].get(
-            "compile_cache.hit", 0) >= n_buckets, warm
-        assert warm["jit_deserialize_spans"] >= n_buckets, warm
-        assert warm["sources"] == ["disk_cache"], warm
-        # the deserialized programs compute the SAME function
-        assert warm["probe_sum"] == cold["probe_sum"], (cold, warm)
-        # and the whole point: the warm start is the fraction of the
-        # cold wall this box can actually show (the compile share the
-        # warm leg skips, with margin — clamped so a compile-dominated
-        # box still gates at the old 0.25x strength)
-        assert out["warm_vs_cold"] <= out["ratio_gate"], \
-            (out["warm_vs_cold"], out["ratio_gate"], predicted)
-        out["gates_passed"] = True
-    except AssertionError:
-        out["gates_passed"] = False
-        raise
-    finally:
-        line = json.dumps(out)
-        print(line, flush=True)
-        if json_out:
-            with open(json_out, "w") as f:
-                f.write(line + "\n")
-    return out
+        "n_buckets": len(legs["cold"]["buckets"]),
+        "cold": legs["cold"],
+        "warm": legs["warm"],
+    }, json_out)
 
 
 # chaos-smoke knobs: the injected per-dispatch DELAY throttles the CPU
@@ -422,7 +264,10 @@ CHAOS_SPEC_FAULTY = CHAOS_SPEC + \
 
 
 def chaos_smoke(json_out=None, n_req=CHAOS_N_REQ):
-    """The fault-tolerant-serving acceptance lane (ISSUE 7)."""
+    """The fault-tolerant-serving lane (ISSUE 7). What it reports are
+    guarantees and not rates: every future resolves, the queue stays
+    inside its bound, an admitted request keeps the deadline the engine
+    promised it, and every injected fault is accounted for."""
     from mxnet_tpu import faults
     from mxnet_tpu.serving import (DeadlineExceeded, QueueOverflow,
                                    CircuitOpen)
@@ -441,13 +286,8 @@ def chaos_smoke(json_out=None, n_req=CHAOS_N_REQ):
         breaker_threshold=50)          # tripping would mask the ladder
     out = {
         "lane": "chaos_smoke",
-        "platform": jax.devices()[0].platform,
-        "n_requests": n_req,
-        "max_batch": MAX_BATCH,
         "deadline_ms": CHAOS_DEADLINE_MS,
         "max_queue_rows": CHAOS_QUEUE_ROWS,
-        "fault_spec": CHAOS_SPEC_FAULTY,
-        "offered_loads": {},
     }
     try:
         # capacity under the injected dispatch DELAY (the throttle is
@@ -467,12 +307,16 @@ def chaos_smoke(json_out=None, n_req=CHAOS_N_REQ):
             for f in futs:
                 f.result(timeout=120)
             done += wave
+        # the ladder needs the capacity this host shows to offer twice
+        # it: a number of this CPU run that stays in here
         capacity = done / (time.perf_counter() - t0)
-        out["capacity_req_s"] = round(capacity, 1)
 
         # open-loop ladder with raises on top of the delay; latency is
         # measured from the SCHEDULED arrival (coordinated-omission-
-        # free), admission sheds raise synchronously at submit
+        # free), admission sheds raise synchronously at submit. The
+        # rung at capacity is the ramp: the engine meets twice its
+        # capacity from a working queue, and that rung is the one
+        # reported
         faults.configure(CHAOS_SPEC_FAULTY)
         for frac in (1.0, 2.0):
             faults.reset_counts()
@@ -507,114 +351,63 @@ def chaos_smoke(json_out=None, n_req=CHAOS_N_REQ):
                 except Exception:
                     failed += 1
             hung = sum(0 if f.done() else 1 for f in pend)
-            lats.sort()
-            pct = telemetry._percentile
-            st = engine.stats()
-            fired = faults.counts().get("dispatch", {}).get("fired", 0)
-            injected = telemetry.counters().get(
-                "faults.injected.dispatch", 0)
-            out["offered_loads"]["%.1f" % frac] = {
-                "offered_req_s": round(rate, 1),
-                "submitted": len(pend),
-                "ok": ok,
-                "shed_admission": admission_shed,
-                "shed_deadline": shed,
-                "failed": failed,
-                "hung": hung,
-                "shed_rate": round(
-                    (admission_shed + shed) / float(n_req), 4),
-                "admitted_latency_ms": {
-                    "p50": round(pct(lats, 50), 3),
-                    "p95": round(pct(lats, 95), 3),
-                    "p99": round(pct(lats, 99), 3),
-                } if lats else None,
-                "retries": st["retries"],
-                "dispatch_failures": st["dispatch_failures"],
-                "breaker": st["breaker"],
-                "faults_fired": fired,
-                "faults_injected_counter": injected,
-                "queued_rows": st["queued_rows"],
-            }
-            print(json.dumps(dict(out, partial=True)), flush=True)
+        lats.sort()
+        out["at_twice_capacity"] = {
+            "submitted": len(pend),
+            "ok": ok,
+            "shed_admission": admission_shed,
+            "shed_deadline": shed,
+            "failed": failed,
+            "hung": hung,
+            # read beside deadline_ms, which the engine promised every
+            # request it admitted
+            "admitted_p99_ms": round(
+                telemetry._percentile(lats, 99), 3) if lats else None,
+            "faults_fired": faults.counts().get("dispatch", {}).get(
+                "fired", 0),
+            "faults_injected_counter": telemetry.counters().get(
+                "faults.injected.dispatch", 0),
+            "queued_rows": engine.stats()["queued_rows"],
+        }
     finally:
         faults.clear()
         engine.close()
-    out["stats"] = {k: v for k, v in engine.stats().items()
-                    if k in ("requests", "resolved", "shed_requests",
-                             "shed_rows", "shed_by_cause", "retries",
-                             "dispatch_failures", "breaker")}
-    hot = out["offered_loads"]["2.0"]
-    try:
-        # the ISSUE 7 chaos gates, all deterministic:
-        # 1. zero hung futures at 2x offered load under injected faults
-        assert hot["hung"] == 0, hot
-        # 2. the engine SHED (bounded queue / deadlines actually bit)
-        assert hot["shed_admission"] + hot["shed_deadline"] > 0, hot
-        # 3. admitted requests kept their deadline promise
-        assert hot["admitted_latency_ms"]["p99"] <= CHAOS_DEADLINE_MS, hot
-        # 4. exact injection accounting: telemetry == registry, > 0
-        assert hot["faults_fired"] > 0, hot
-        assert hot["faults_injected_counter"] == hot["faults_fired"], hot
-        # 5. the bounded queue held
-        assert hot["queued_rows"] <= CHAOS_QUEUE_ROWS, hot
-        # 6. every admitted request resolved one way or the other
-        assert hot["ok"] + hot["shed_deadline"] + hot["failed"] \
-            == hot["submitted"], hot
-        out["gates_passed"] = True
-    except AssertionError:
-        out["gates_passed"] = False
-        raise
-    finally:
-        line = json.dumps(out)
-        print(line, flush=True)
-        if json_out:
-            with open(json_out, "w") as f:
-                f.write(line + "\n")
-    return out
+    out["shed_requests"] = engine.stats()["shed_requests"]
+    return emit(out, json_out)
 
 
 # postmortem-smoke knobs: the raise rule must outlast the retry budget
 # on ONE batch (initial attempt + retry_budget retries all land inside
-# first=K) so the failure is TERMINAL; the delay keeps the CPU lane's
-# capacity overloadable like the chaos lane
+# first=K) so the failure is TERMINAL; the delay keeps the waves' batches
+# in flight long enough for the sampler to see them
 PM_RETRY_BUDGET = 1
 PM_RAISE_FIRST = PM_RETRY_BUDGET + 2     # every attempt of batch 1 + slack
 PM_SPEC_TERMINAL = "%s;dispatch:raise:first=%d" % (CHAOS_SPEC,
                                                    PM_RAISE_FIRST)
 PM_SAMPLER_MS = 25.0
 PM_N_REQ = 192
-PM_OVERHEAD_FRAC = 0.02
 
 
 def postmortem_smoke(json_out=None, n_req=PM_N_REQ):
-    """The flight-recorder acceptance lane (ISSUE 10)."""
-    import subprocess as _subprocess
+    """The flight-recorder lane (ISSUE 10)."""
     from mxnet_tpu import faults, flight
     sym = _mlp()
     params = _params(sym)
     rng = np.random.RandomState(1)
     reqs = [rng.normal(size=(1, D)).astype(np.float32)
             for _ in range(64)]
-    art_dir = os.environ.get("MXTPU_ARTIFACT_DIR", "/tmp/mxtpu_artifacts")
-    fdir = os.path.join(art_dir, "flight")
+    # the dump outlives the probe beside its JSON: the lane's test runs
+    # the flight_view CLI over it
+    fdir = os.path.join(os.path.dirname(os.path.abspath(json_out)),
+                        "flight") if json_out \
+        else tempfile.mkdtemp(prefix="mxtpu_pm_smoke_flight_")
     os.makedirs(fdir, exist_ok=True)
-    for name in os.listdir(fdir):          # this RUN's dumps only
-        if name.startswith("postmortem-") and name.endswith(".json"):
-            os.unlink(os.path.join(fdir, name))
     telemetry.enable()
     telemetry.reset()
     flight.configure(fdir)
     flight.series_clear()
     flight.sampler_start(PM_SAMPLER_MS)
-    out = {
-        "lane": "postmortem_smoke",
-        "platform": jax.devices()[0].platform,
-        "n_requests": n_req,
-        "max_batch": MAX_BATCH,
-        "fault_spec": PM_SPEC_TERMINAL,
-        "flight_dir": fdir,
-        "sampler_interval_ms": PM_SAMPLER_MS,
-    }
+    out = {"lane": "postmortem_smoke"}
     engine = InferenceEngine(
         sym, params, {"data": (1, D)}, max_batch=MAX_BATCH,
         max_wait_ms=1.0, max_inflight=4,
@@ -624,7 +417,7 @@ def postmortem_smoke(json_out=None, n_req=PM_N_REQ):
         breaker_threshold=0)       # the TERMINAL failure is the story,
                                    # not a breaker fast-fail masking it
     try:
-        # phase 1: the terminal fault — one batch's every attempt
+        # phase 1: the terminal fault: one batch's every attempt
         # raises, its futures fail, the flight recorder dumps
         faults.configure(PM_SPEC_TERMINAL)
         doomed = [engine.submit(data=reqs[i % len(reqs)])
@@ -639,12 +432,20 @@ def postmortem_smoke(json_out=None, n_req=PM_N_REQ):
         out["failed_requests"] = len(failed_rids)
         out["failed_req_ids"] = sorted(failed_rids)
 
-        # phase 2: the chaos ladder under the delay throttle (faults
-        # still active minus the spent raise rule) — closed-loop waves
-        # like the chaos capacity phase; zero hung futures gates the
-        # recorder added no new stalls
+        # phase 2: closed-loop waves under the delay throttle (faults
+        # still active minus the spent raise rule), in a registry window
+        # of their own: zero hung futures says the recorder added no
+        # stall, and the window's spans and events, beside the requests
+        # and batches they were recorded for, say what the recorder
+        # does for a request. The engine fails a dying batch's futures
+        # BEFORE it dumps, so wait for phase 1's dump: it reads the
+        # registry that the new window clears
+        for _ in range(3000):
+            if flight.last_postmortem() is not None:
+                break
+            time.sleep(0.01)
         faults.configure(CHAOS_SPEC)
-        t0 = time.perf_counter()
+        telemetry.reset()
         hung = 0
         done = 0
         while done < n_req:
@@ -660,65 +461,17 @@ def postmortem_smoke(json_out=None, n_req=PM_N_REQ):
                 if not f.done():
                     hung += 1
             done += wave
-        wall = time.perf_counter() - t0
-        out["ladder_req_s"] = round(done / wall, 1)
         out["hung"] = hung
-
-        # phase 3: the flight-recorder work model (the <2% guard with
-        # the SAMPLER and CAUSAL IDS on): count the recorder ops the
-        # ladder actually performed, microbenchmark their unit costs
-        # (min over reps — throttle only inflates), and bound
-        # ops x cost against the measured wall
-        span_ops = sum(telemetry.span_count(n)
-                       for n in telemetry.span_stats())
-        # one counter_inc per event regardless of the value added:
-        # byte-valued counters (pad_bytes, h2d_bytes) are one op per
-        # event too, and their event counts already ride in the
-        # sibling unit counters — summing their VALUES would model
-        # each byte as a registry op
-        counter_ops = sum(v for k, v in telemetry.counters().items()
-                          if k.startswith(("serving.", "dispatch.",
-                                           "faults.", "transfer."))
-                          and not k.endswith("_bytes"))
-        event_ops = len(telemetry.events())
-        ticks = len(flight.series())
-
-        def op_cost(fn, iters=4000, reps=5):
-            best = float("inf")
-            for _ in range(reps):
-                t1 = time.perf_counter_ns()
-                for _ in range(iters):
-                    fn()
-                best = min(best, (time.perf_counter_ns() - t1) / iters)
-            return best / 1e9
-
-        ctx = {"req_id": 1}
-
-        def one_span():
-            with telemetry.span("_pm_probe", ctx=ctx):
-                pass
-
-        span_s = op_cost(one_span)
-        counter_s = op_cost(
-            lambda: telemetry.counter_inc("_pm_probe"))   # mxlint: disable=registry-consistency -- microbench probe counter (cost measurement), never a production metric
-
-        event_s = op_cost(
-            lambda: telemetry.record_event("_pm_probe", req_id=1))
-        tick_s = op_cost(lambda: flight._build_sample({}, 0.025),
-                         iters=200)
-        overhead_s = (span_ops * span_s + counter_ops * counter_s
-                      + event_ops * event_s + ticks * tick_s)
-        out["overhead"] = {
-            "span_ops": span_ops, "counter_ops": counter_ops,
-            "event_ops": event_ops, "sampler_ticks": ticks,
-            "span_us": round(span_s * 1e6, 3),
-            "counter_us": round(counter_s * 1e6, 3),
-            "event_us": round(event_s * 1e6, 3),
-            "tick_us": round(tick_s * 1e6, 3),
-            "work_ms": round(overhead_s * 1e3, 3),
-            "wall_s": round(wall, 3),
-            "frac": round(overhead_s / wall, 5),
-            "gate": PM_OVERHEAD_FRAC,
+        events = {}
+        for ev in telemetry.events():
+            events[ev["kind"]] = events.get(ev["kind"], 0) + 1
+        out["recorder"] = {
+            "requests": done,
+            "counters": {k: v for k, v in telemetry.counters().items()
+                         if k.startswith("serving.")},
+            "span_counts": {k: v["count"]
+                            for k, v in telemetry.span_stats().items()},
+            "event_counts": events,
         }
     finally:
         faults.clear()
@@ -734,71 +487,40 @@ def postmortem_smoke(json_out=None, n_req=PM_N_REQ):
                         "flight_view.py")
 
     def run_view(path, extra=()):
-        return _subprocess.run(
+        return subprocess.run(
             [sys.executable, view, path, *extra],
-            stdout=_subprocess.PIPE, stderr=_subprocess.PIPE,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True, timeout=60)
 
-    try:
-        # gate 1: the terminal fault produced a postmortem that PARSES
-        assert pm_path is not None and os.path.exists(pm_path), pm_path
+    out["view_rc"] = out["view_summary"] = out["corrupt_view_rc"] = None
+    if pm_path is not None and os.path.exists(pm_path):
+        # the terminal fault's postmortem as flight_view PARSES it
         proc = run_view(pm_path, ("--json",))
-        assert proc.returncode == 0, proc.stderr[-1000:]
-        summary = json.loads(proc.stdout)
-        out["view_summary"] = {k: summary.get(k) for k in
-                               ("reason", "exception", "extra",
-                                "n_events", "n_spans", "n_series")}
-        # gate 2: the dump names the injected fault's site...
-        assert summary["reason"] == "serving_dispatch_failure", summary
-        assert summary["exception"]["fault_site"] == "dispatch", summary
-        # ...and exactly the dying batch's member req_ids
-        assert failed_rids, "terminal fault failed no requests"
-        assert sorted(summary["extra"]["req_ids"]) \
-            == sorted(failed_rids), (summary["extra"], failed_rids)
-        # gate 3: a corrupted dump is REJECTED non-zero
+        out["view_rc"] = proc.returncode
+        if proc.returncode == 0:
+            summary = json.loads(proc.stdout)
+            out["view_summary"] = {k: summary.get(k) for k in
+                                   ("reason", "exception", "extra")}
+        # a corrupted dump is to be REJECTED non-zero
         bad = pm_path + ".corrupt"
         with open(pm_path) as f:
             with open(bad, "w") as g:
                 g.write(f.read()[:200])   # truncated JSON
-        proc_bad = run_view(bad)
+        out["corrupt_view_rc"] = run_view(bad).returncode
         os.unlink(bad)
-        assert proc_bad.returncode != 0, "flight_view accepted garbage"
-        # gate 4: the sampler banked a real time-series window
-        assert out["series_window"]["n"] > 0, out["series_window"]
-        # gate 5: zero hung futures, and the recorder work fits the
-        # existing <2% telemetry overhead guard
-        assert out["hung"] == 0, out
-        assert out["overhead"]["frac"] < PM_OVERHEAD_FRAC, out["overhead"]
-        out["gates_passed"] = True
-    except AssertionError:
-        out["gates_passed"] = False
-        raise
-    finally:
-        line = json.dumps(out)
-        print(line, flush=True)
-        if json_out:
-            with open(json_out, "w") as f:
-                f.write(line + "\n")
-    return out
+    return emit(out, json_out)
 
 
 # decode-smoke knobs: the continuous-batching decode engine
-# (mxnet_tpu/decode.py) vs wave-synchronized static whole-batch decode
-# through the SAME engine and programs. The workload skews generation
-# lengths (1 long per wave of 8) because that skew is WHY continuous
-# batching exists: static batching pays the longest member's steps for
-# every wave while finished lanes idle; slot-level admission keeps the
-# pool full. On the dispatch-dominated CPU backend the dispatch-count
-# ratio is the throughput ratio, so the 2x gate is conservative
-# (measured ~2.5-3x; a real accelerator with wide decode batches gains
-# more).
+# (mxnet_tpu/decode.py). The workload skews generation lengths (1 long
+# per wave of 8) because that skew is WHY continuous batching exists: a
+# wave-synchronized static whole-batch decoder pays the longest member's
+# steps for every wave while finished lanes idle; slot-level admission
+# keeps the pool full, so the same tokens take fewer decode dispatches.
 DEC_SLOTS = 8
 DEC_WAVES = 6
 DEC_SHORT, DEC_LONG = 4, 40        # generated tokens per sequence kind
 DEC_PROMPT = 4
-DEC_ROUNDS = 3
-DECODE_SPEEDUP_GATE = 2.0
-DEC_MP = 8                         # mp-sharded KV-cache leg mesh width
 
 
 def _decode_cell(heads=8):
@@ -808,46 +530,39 @@ def _decode_cell(heads=8):
 
 
 def decode_smoke(json_out=None):
-    """Continuous-batching decode acceptance lane (tier-1 CI).
+    """Continuous-batching decode lane (tier-1 CI), three legs:
 
-    Three legs, one artifact (``decode_smoke.json``):
-
-    * correctness — slot-batched decode is BIT-EXACT (tokens and
-      logits) against one-at-a-time decode through the same engine;
-    * throughput — open-loop skewed-length stream through the
-      continuous engine vs wave-synchronized static whole-batch
-      submission of the same work, interleaved best-of; gates
-      continuous >= 2x static tokens/s and ZERO ``jit_compile`` spans
-      anywhere in the timed windows (per-token p50/p95/p99 ride along,
-      coordinated-omission-free: the step spans time the dispatch
-      cadence itself, all work is queued up front, so a slow step
-      cannot hide follow-on latency);
-    * mp-sharded KV cache — under ``DECODE_PARTITION_RULES`` on a
-      1x{mp} mesh the cache pool's committed ledger bytes read exactly
-      1/mp of the same pool replicated onto that mesh.
+    * equality: slot-batched decode against one-at-a-time decode
+      through the same engine: tokens and arg-max compared exactly, and
+      of the float32 logits (two programs of different slot-bucket
+      width) the largest gap beside the largest magnitude, for the
+      lane's test to hold to ``tests/helpers.py``'s tolerance;
+    * schedule: a skewed-length stream queued up front through the
+      continuous engine, in one registry window: ``jit_compile`` spans
+      (warmup built every prompt-length and slot-count bucket program
+      up front), ``decode.steps`` and ``decode.tokens``, and beside
+      them the step count of the static whole-batch schedule of the
+      same work, COMPUTED as waves x longest generation;
+    * mp-sharded KV cache: under ``DECODE_PARTITION_RULES`` on a
+      1x{mp} mesh, the cache pool's committed ledger bytes beside the
+      same pool replicated onto that mesh.
     """
     from mxnet_tpu.decode import DecodeEngine
     from mxnet_tpu.parallel.ring_attention import DECODE_PARTITION_RULES
 
-    art_dir = os.environ.get("MXTPU_ARTIFACT_DIR", "/tmp/mxtpu_artifacts")
-    os.environ.setdefault("MXNET_CARD_CORPUS",
-                          os.path.join(art_dir, "card_corpus.jsonl"))
     rng = np.random.RandomState(0)
     out = {
         "lane": "decode_smoke",
-        "platform": jax.devices()[0].platform,
         "devices": jax.device_count(),
         "slots": DEC_SLOTS,
         "waves": DEC_WAVES,
-        "gen_short": DEC_SHORT,
         "gen_long": DEC_LONG,
-        "speedup_gate": DECODE_SPEEDUP_GATE,
     }
 
     def prompt():
         return rng.randint(1, 255, DEC_PROMPT).astype(np.int32)
 
-    # -- leg 1: bit-exact slot-batched vs one-at-a-time ---------------------
+    # -- leg 1: slot-batched vs one-at-a-time -------------------------------
     cell = _decode_cell()
     eng = DecodeEngine(cell, cell.init_params(1), slots=4,
                        max_prompt_len=8, max_new_tokens=8,
@@ -856,88 +571,53 @@ def decode_smoke(json_out=None):
     serial = [eng.generate(p) for p in probes]
     batched = [f.result(timeout=300)
                for f in [eng.submit(p) for p in probes]]
-    bit_exact = all(
-        a.tokens == b.tokens and np.array_equal(a.logits, b.logits)
-        for a, b in zip(serial, batched))
-    out["bit_exact"] = bit_exact
     eng.close()
+    pairs = [(np.asarray(a.logits, np.float64),
+              np.asarray(b.logits, np.float64))
+             for a, b in zip(serial, batched)]
+    out["equality"] = {
+        "tokens_equal": all(a.tokens == b.tokens
+                            for a, b in zip(serial, batched)),
+        "argmax_equal": all(np.array_equal(a.argmax(-1), b.argmax(-1))
+                            for a, b in pairs),
+        "logits_max_abs_diff": max(float(np.abs(a - b).max())
+                                   for a, b in pairs),
+        "logits_max_abs": max(float(np.abs(a).max()) for a, _ in pairs),
+    }
 
-    # -- leg 2: continuous vs static whole-batch throughput -----------------
+    # -- leg 2: the continuous schedule, counted ----------------------------
     eng = DecodeEngine(cell, cell.init_params(1), slots=DEC_SLOTS,
                        max_prompt_len=8, max_new_tokens=DEC_LONG)
-    # one wave = a slot pool's worth of sequences, one long member
-    waves = [[(prompt(), DEC_LONG if s == 0 else DEC_SHORT)
-              for s in range(DEC_SLOTS)] for _ in range(DEC_WAVES)]
-    total_tokens = sum(n for wave in waves for _, n in wave)
-    # continuous submission order: longs first, so their long tails
-    # overlap the short churn instead of trailing an empty pool
-    stream = sorted((seq for wave in waves for seq in wave),
-                    key=lambda s: -s[1])
-
-    def static_epoch():
-        """Wave-synchronized static whole-batch decode: the next wave
-        enters only when the whole previous wave finished — finished
-        lanes idle exactly as a slotless whole-batch decoder's would
-        (same dispatch count: the longest member's steps per wave)."""
-        t0 = time.perf_counter()
-        for wave in waves:
-            futs = [eng.submit(p, max_new_tokens=n) for p, n in wave]
-            for f in futs:
-                f.result(timeout=300)
-        return time.perf_counter() - t0
-
-    def continuous_epoch():
-        """Open-loop: every sequence queued up front; per-step slot
-        admission keeps the pool full until the work runs dry."""
-        t0 = time.perf_counter()
+    # one wave = a slot pool's worth of sequences, one long member;
+    # submitted longs first, so their long tails overlap the short
+    # churn instead of trailing an empty pool
+    stream = sorted(((prompt(), DEC_LONG if s == 0 else DEC_SHORT)
+                     for _ in range(DEC_WAVES) for s in range(DEC_SLOTS)),
+                    key=lambda seq: -seq[1])
+    was_enabled = telemetry.enabled()
+    telemetry.enable()
+    try:
+        telemetry.reset()
+        # every sequence queued up front; per-step slot admission keeps
+        # the pool full until the work runs dry
         futs = [eng.submit(p, max_new_tokens=n) for p, n in stream]
         for f in futs:
             f.result(timeout=300)
-        return time.perf_counter() - t0
-
-    was_enabled = telemetry.enabled()
-    telemetry.enable()
-    dt_st = dt_ct = float("inf")
-    jit_compiles = 0
-    window = {}
-    try:
-        for _ in range(DEC_ROUNDS):
-            telemetry.reset()
-            dt_st = min(dt_st, static_epoch())
-            jit_compiles += telemetry.span_stats().get(
-                "jit_compile", {}).get("count", 0)
-            telemetry.reset()
-            dt = continuous_epoch()
-            snap = telemetry.snapshot()
-            jit_compiles += snap["spans"].get(
-                "jit_compile", {}).get("count", 0)
-            if dt <= dt_ct:
-                dt_ct = dt
-                window = {
-                    "counters": {k: v for k, v in
-                                 snap["counters"].items()
-                                 if k.startswith("decode.")},
-                    "spans": {k: v for k, v in snap["spans"].items()
-                              if k in telemetry.DECODE_SPANS},
-                }
+        snap = telemetry.snapshot()
     finally:
         if not was_enabled:
             telemetry.disable()
-    stats = eng.stats()
     eng.close()
-
-    tok_lat = window.get("spans", {}).get("serve_decode_step", {})
     out.update({
-        "total_tokens": total_tokens,
-        "static_tok_s": round(total_tokens / dt_st, 1),
-        "continuous_tok_s": round(total_tokens / dt_ct, 1),
-        "decode_speedup": round(dt_st / dt_ct, 2),
-        "token_latency_ms": {k: tok_lat.get(k)
-                             for k in ("p50_ms", "p95_ms", "p99_ms")},
-        "jit_compiles_timed": jit_compiles,
-        "kv_cache_bytes": stats["kv_cache_bytes"],
-        "kv_cache_bytes_per_slot": stats["kv_cache_bytes_per_slot"],
-        "telemetry": window,
+        "total_tokens": sum(n for _, n in stream),
+        # a static whole-batch decoder admits the next wave only when
+        # the whole previous wave finished: the longest member's steps
+        # for every wave
+        "static_schedule_steps": DEC_WAVES * DEC_LONG,
+        "jit_compiles_in_window": snap["spans"].get(
+            "jit_compile", {}).get("count", 0),
+        "counters": {k: v for k, v in snap["counters"].items()
+                     if k.startswith("decode.")},
     })
 
     # -- leg 3: the mp-sharded KV cache on the rule engine -------------------
@@ -963,82 +643,17 @@ def decode_smoke(json_out=None):
             "mesh": axes,
             "sharded_kv_bytes": sharded_bytes,
             "replicated_kv_bytes": repl_bytes,
-            "ledger_ratio": round(repl_bytes / sharded_bytes, 2)
-            if sharded_bytes else None,
             "decoded_tokens": len(mp_tokens),
         }
     else:
         out["mp"] = None
-
-    # the ISSUE 16 decode acceptance gates, all deterministic except
-    # the (conservative) throughput ratio:
-    try:
-        assert bit_exact, "slot-batched decode diverged from unbatched"
-        assert jit_compiles == 0, \
-            ("compiles inside the timed windows", jit_compiles)
-        assert out["decode_speedup"] >= DECODE_SPEEDUP_GATE, \
-            out["decode_speedup"]
-        assert out["mp"] is not None, "mp leg needs %d devices" % DEC_MP
-        assert out["mp"]["replicated_kv_bytes"] \
-            == DEC_MP * out["mp"]["sharded_kv_bytes"], out["mp"]
-        assert out["mp"]["decoded_tokens"] == 8, out["mp"]
-        out["gates_passed"] = True
-    except AssertionError:
-        out["gates_passed"] = False
-        raise
-    finally:
-        line = json.dumps(out)
-        print(line, flush=True)
-        if json_out:
-            with open(json_out, "w") as f:
-                f.write(line + "\n")
-    return out
-
-
-def _respawn_with_mesh(n):
-    """Re-exec this probe with an ``n``-device forced host platform.
-    The decode lane's mp leg needs the multi-device CPU mesh, and
-    XLA_FLAGS must be set BEFORE the jax backend initialises — which
-    module import already did — so a direct invocation without the
-    flag bounces through one child process. Returns the child's exit
-    code."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=%d"
-                        % n).strip()
-    env["MXTPU_PROBE_RESPAWNED"] = "1"
-    proc = subprocess.run([sys.executable,
-                           os.path.abspath(__file__)] + sys.argv[1:],
-                          env=env)
-    return proc.returncode
-
-
-def _json_out_arg():
-    if "--json-out" not in sys.argv:
-        return None
-    i = sys.argv.index("--json-out") + 1
-    if i >= len(sys.argv) or sys.argv[i].startswith("--"):
-        raise SystemExit("--json-out: missing output path")
-    return sys.argv[i]
+    return emit(out, json_out)
 
 
 if __name__ == "__main__":
-    if "--serve-smoke" in sys.argv:
-        serve_smoke(json_out=_json_out_arg())
-    elif "--warm-smoke" in sys.argv:
-        warm_smoke(json_out=_json_out_arg())
-    elif "--warm-child" in sys.argv:
-        warm_child()
-    elif "--chaos-smoke" in sys.argv:
-        chaos_smoke(json_out=_json_out_arg())
-    elif "--postmortem-smoke" in sys.argv:
-        postmortem_smoke(json_out=_json_out_arg())
-    elif "--decode-smoke" in sys.argv:
-        if jax.device_count() < DEC_MP \
-                and not os.environ.get("MXTPU_PROBE_RESPAWNED"):
-            sys.exit(_respawn_with_mesh(DEC_MP))
-        decode_smoke(json_out=_json_out_arg())
-    else:
-        raise SystemExit("usage: serve_probe.py --serve-smoke|"
-                         "--warm-smoke|--chaos-smoke|--postmortem-smoke|"
-                         "--decode-smoke [--json-out PATH]")
+    main({"--serve-smoke": serve_smoke, "--warm-smoke": warm_smoke,
+          "--warm-child": lambda json_out: warm_child(),
+          "--chaos-smoke": chaos_smoke,
+          "--postmortem-smoke": postmortem_smoke,
+          "--decode-smoke": decode_smoke},
+         children=("--warm-child",))
