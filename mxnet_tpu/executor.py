@@ -36,6 +36,19 @@ from . import faults
 #: (``_GraphProgram.mirror_stages``)
 MIRROR_STAGE = "__mirror_stage__"
 
+#: the names an op may give a value (``jax.ad_checkpoint.checkpoint_name``)
+#: that only it can produce and that costs more to make again than to hold:
+#: a checkpoint segment keeps these between forward and backward and makes
+#: everything else again. Outside a checkpoint a name is the identity.
+MIRROR_KEEPS = ("flash_attention_out", "flash_attention_lse")
+
+# what every mirrored evaluation hands ``jax.checkpoint``: nothing is kept
+# from inside a segment but the values its ops have named; where no op names
+# one, the plain checkpoint. ONE object for every segment: jax caches the
+# split of a jitted function inside a segment by the policy's identity, so a
+# policy made anew for each segment would lower such a function once a segment
+_MIRROR_POLICY = jax.checkpoint_policies.save_only_these_names(*MIRROR_KEEPS)
+
 __all__ = ["Executor", "infer_graph_shapes", "record_dispatch",
            "card_from_compiled", "DeviceMemoryError"]
 
@@ -815,7 +828,13 @@ class _GraphProgram:
         (graph_executor.cc:282-305) recast as TPU-first checkpointing.
         (One checkpoint around the whole graph would save nothing: the
         recomputed forward and the backward would hold every activation
-        live at once.)"""
+        live at once.)
+
+        Besides its boundary values a segment keeps the values an op
+        inside it has named (``MIRROR_KEEPS``: the attention kernel's
+        output and log-sum-exp, which only the kernel can make again);
+        a segment in which no op names a value lowers as under a bare
+        ``jax.checkpoint``."""
         import math
 
         if not self.can_segment():
@@ -886,7 +905,8 @@ class _GraphProgram:
                             local[(id(n), i)] = v
                     return [local[key] for key in _needed], ups
 
-                out_vals, ups = jax.checkpoint(chunk_fn)(
+                out_vals, ups = jax.checkpoint(
+                    chunk_fn, policy=_MIRROR_POLICY)(
                     [val_env[key] for key in ext])
                 aux_updates.update(ups)
                 for key, v in zip(needed, out_vals):
@@ -934,7 +954,7 @@ class _GraphProgram:
             # segment-checkpointed; one checkpoint around the whole
             # graph still frees activation buffers between forward and
             # backward
-            f = jax.checkpoint(f)
+            f = jax.checkpoint(f, policy=_MIRROR_POLICY)
         return jax.vjp(f, grad_args, has_aux=True)
 
     def fwd_bwd_fn(self, train, grad_names):

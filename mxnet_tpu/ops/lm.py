@@ -84,7 +84,16 @@ def causal_attention(query, key, value, num_heads=1, num_kv_heads=0,
     and, with ``window``, only i - j < window (itself counted); scores
     ``q.k / sqrt(head_dim)``, softmax in float32. Forward and backward are
     the Pallas kernels of ``pallas.flash_attention`` (interpreted off the
-    TPU)."""
+    TPU).
+
+    In a mirrored graph (``__mirror_stage__`` segments, or
+    ``MXNET_BACKWARD_DO_MIRROR``) every node of this op holds its output
+    in head-major form and the rows' log-sum-exp between forward and
+    backward (batch x T x heads x head_dim in the operands' type, 2 bytes
+    each in bfloat16, and 4 bytes x batch x T x heads), so that the backward pass does not run the forward
+    kernel a second time: memory traded for the kernel's time, on
+    purpose. Queries, keys and values are made again from the segment's
+    input."""
     from ..pallas.flash_attention import flash_attention
     hq, hkv = int(num_heads), int(num_kv_heads) or int(num_heads)
     b, t, f = query.shape

@@ -50,6 +50,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -507,6 +508,16 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     Differentiable; forward and backward are Pallas kernels on the TPU
     (interpret mode elsewhere) whose VMEM use does not grow with S.
+
+    The backward needs the output and the log-sum-exp of every row, and
+    only the forward kernel can make them: the forward rule names both
+    (``jax.ad_checkpoint.checkpoint_name``: ``flash_attention_out``,
+    ``flash_attention_lse``), so a ``jax.checkpoint`` whose policy saves
+    those names (a mirrored segment, ``executor.MIRROR_KEEPS``) holds them
+    between forward and backward, (B, Hq, Sq, D) in ``q``'s type and (B,
+    Hq, Sq) float32, and does not run the kernel again; ``q``, ``k``,
+    ``v`` it makes again. Under any other checkpoint, and outside one, a name is
+    the identity.
     """
     return _fwd(q, k, v, causal, scale, block_q, interpret, window,
                 block_k)[0]
@@ -519,7 +530,13 @@ def _fwd(q, k, v, causal, scale, block_q, interpret, window, block_k):
     band = _plan(q, k, causal, window, block_q, block_k)
     out, lse = _forward(_pad_seq(q, band.bq), _pad_seq(k, band.bk),
                         _pad_seq(v, band.bk), band, scale, interpret)
-    out = out[:, :, :q.shape[2]]
+    # the two residuals only the kernel can produce carry a name
+    # (``executor.MIRROR_KEEPS``): a checkpoint segment that saves by name
+    # keeps them and does not run the kernel a second time. The log-sum-exp
+    # crosses as (B, Hq, Sq'): a last dimension of 1 is padded to a whole
+    # lane tile in HBM, 128 times its size.
+    out = checkpoint_name(out[:, :, :q.shape[2]], "flash_attention_out")
+    lse = checkpoint_name(lse[..., 0], "flash_attention_lse")
     return out, (q, k, v, out, lse)
 
 
@@ -535,8 +552,8 @@ def _bwd(causal, scale, block_q, interpret, window, block_k, res, g):
                     keepdims=True)
     dq, dk, dv = _backward(
         _pad_seq(q, band.bq), _pad_seq(k, band.bk), _pad_seq(v, band.bk),
-        _pad_seq(g.astype(q.dtype), band.bq), lse, _pad_seq(delta, band.bq),
-        band, scale, interpret)
+        _pad_seq(g.astype(q.dtype), band.bq), lse[..., None],
+        _pad_seq(delta, band.bq), band, scale, interpret)
     return (dq[:, :, :q.shape[2]], dk[:, :, :k.shape[2]],
             dv[:, :, :v.shape[2]])
 

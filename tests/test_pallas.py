@@ -1,6 +1,7 @@
 """Pallas kernel tests (interpret mode on the CPU mesh; the same kernels
 compile natively on TPU — the bench/driver exercises that path)."""
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -153,6 +154,47 @@ def test_flash_backward_pallas_vs_xla():
                     np.asarray(gp), np.asarray(gx), rtol=2e-4, atol=2e-4,
                     err_msg="%s causal=%s s=(%d,%d)"
                             % (name, causal, s_q, s_kv))
+
+
+@pytest.mark.parametrize("window", [0, 96])
+def test_flash_named_residuals_are_the_identity(window):
+    """``flash_attention`` names its output and log-sum-exp for a mirrored
+    segment to keep and hands the log-sum-exp over compact; outside a
+    checkpoint that changes no bit: output and gradients equal the two
+    kernels called as the rule called them before the names (grouped-query
+    heads, an uneven length so the padding is on the path)."""
+    import importlib
+    fa = importlib.import_module("mxnet_tpu.pallas.flash_attention")
+
+    rs = np.random.RandomState(3)
+    q = jnp.asarray(rs.randn(1, 4, 200, 32).astype(np.float32))
+    k, v = (jnp.asarray(rs.randn(1, 2, 200, 32).astype(np.float32))
+            for _ in range(2))
+    g = jnp.asarray(rs.randn(1, 4, 200, 32).astype(np.float32))
+    band = fa._plan(q, k, True, window or None, 64, 64)
+    scale = 1.0 / np.sqrt(32)
+
+    def pad(x):
+        return fa._pad_seq(x, 64)
+
+    out, lse = fa._forward(pad(q), pad(k), pad(v), band, scale, True)
+    assert lse.shape == (1, 4, 256, 1)
+    out = out[:, :, :200]
+    delta = jnp.sum(g * out, -1, keepdims=True)
+    want = fa._backward(pad(q), pad(k), pad(v), pad(g), lse, pad(delta),
+                        band, scale, True)
+
+    def loss(qq, kk, vv):
+        return jnp.sum(flash_attention(qq, kk, vv, True, None, 64, None,
+                                       window or None, 64) * g)
+
+    got_out = flash_attention(q, k, v, True, None, 64, None,
+                              window or None, 64)
+    np.testing.assert_array_equal(np.asarray(got_out), np.asarray(out))
+    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(grads, want, ("dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a),
+                                      np.asarray(b[:, :, :200]), name)
 
 
 def test_scale_bias_add_relu_matches_composed():

@@ -110,6 +110,58 @@ def test_flash_attention_compiles_at_8k(one_chip, window):
     assert grad.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window"])
+def test_mirrored_layers_run_the_forward_kernel_once(one_chip, window):
+    """Two layers of projections + attention + gate at the language-model
+    cell's shapes, each under the checkpoint a mirrored segment gets
+    (``executor._MIRROR_POLICY``): the gradient's program holds one
+    forward kernel a layer (a bare checkpoint makes each again), and what
+    the segments hold for the backward pass is their inputs, each layer's
+    output of the kernel (64 MiB) and its log-sum-exp compact: 1 MiB a
+    layer, where the kernel's own (..., 1) form is padded to 128 MiB."""
+    from mxnet_tpu import executor
+    t, hq, hkv, d, f = 8192, 32, 4, 128, 2048
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+
+    def layer(x, wq, wk, wv, wg, wo):
+        def heads(y, h):
+            return y.reshape(1, t, h, d).transpose(0, 2, 1, 3)
+        o = flash_attention(heads(x @ wq, hq), heads(x @ wk, hkv),
+                            heads(x @ wv, hkv), True, None, None, False,
+                            window)
+        o = o.transpose(0, 2, 1, 3).reshape(1, t, hq * d)
+        return x + (o * jax.nn.sigmoid(x @ wg)) @ wo
+
+    def net(x, *ws):
+        for i in range(2):
+            x = jax.checkpoint(layer, policy=executor._MIRROR_POLICY)(
+                x, *ws[5 * i:5 * i + 5])
+        return jnp.sum(x.astype(jnp.float32))
+
+    args = (shape(1, t, f),) + (shape(f, hq * d), shape(f, hkv * d),
+                                shape(f, hkv * d), shape(f, hq * d),
+                                shape(hq * d, f)) * 2
+    grad = _compile(jax.grad(net, argnums=tuple(range(11))), *args)
+    text = grad.as_text()
+    for kernel, count in (("fwd", 2), ("dq", 2), ("dkv", 2)):
+        assert len([line for line in text.splitlines()
+                    if " custom-call(" in line
+                    and "flash_attention_" + kernel in line]) == count
+    # what a segment hands the backward pass: the log-sum-exp as (B, Hq, S)
+    held = jax.tree_util.tree_leaves(
+        jax.eval_shape(lambda *a: jax.vjp(net, *a), *args))
+    assert sum(h.shape == (1, hq, t) and h.dtype == jnp.float32
+               for h in held) == 2
+    assert sum(h.shape == (1, hq, t, d) for h in held) == 2
+    assert not any(h.shape == (1, hq, t, 1) for h in held)
+    # and the chip's buffers say so: 677.0 MiB of temporaries, against
+    # 804.5 when the log-sum-exp crosses a segment in the kernel's form
+    # (both compiled here, full and windowed alike)
+    assert grad.memory_analysis().temp_size_in_bytes < 700 * 2 ** 20
+
+
 def test_flash_attention_carry_vmem_limit_at_8k(one_chip):
     """The carry entry (ring attention's building block) still holds the
     whole local K/V block and a (block_q, S_kv) f32 score tile in VMEM
