@@ -1689,12 +1689,24 @@ class Executor:
     def output_entries_len(self):
         return len(self._prog.output_entries)
 
-    def publish_aux_counters(self):
+    def publish_aux_counters(self, steps=0):
         """Add to the telemetry counters what the ops' auxiliary states
         have summed since the last call (``OpDef.aux_counters``: the
         routed-expert layer's rows). The sums grow on the device inside
         the step; this fetch is the only host sync, so it is called where
-        a sync is due anyway (the end of an epoch), never per batch."""
+        a sync is due anyway (the end of an epoch), never per batch.
+        What an op's shapes fix (``OpDef.step_counters``: the state-space
+        recurrence's chunks) is counted here on the host, ``steps`` times:
+        the training steps the caller has run since the last call."""
+        fixed = [n for n in self._prog.nodes
+                 if steps and n.op is not None and n.op.step_counters]
+        if fixed:
+            inner = self._symbol.get_internals()
+            shapes = dict(zip(inner.list_outputs(), inner.infer_shape(
+                **{k: v.shape for k, v in self.arg_dict.items()})[1]))
+            for node in fixed:
+                node.op.step_counters(shapes[node.name + "_output"],
+                                      node.attrs, steps)
         seen = self.__dict__.setdefault("_aux_published", {})
         for node in self._prog.nodes:
             table = node.op.aux_counters if node.op is not None else None

@@ -83,6 +83,8 @@ class Module(BaseModule):
         self._mesh_axes = dict(mesh_axes) if mesh_axes else None
         self._fused_fallback_reason = None
         self._fused_plan = None
+        # fused steps run since the ops' counters were last published
+        self._steps_unpublished = 0
         # the dist tier (multi-process dist_* kvstore): a PROCESS-
         # SPANNING dp mesh the fused step jits over, committed lazily
         # and dropped whenever a step must phase-split (the explicit
@@ -1129,6 +1131,7 @@ class Module(BaseModule):
                 ts = _spmd.put_replicated_local(ts, spec)
 
         record_dispatch("train_step")
+        self._steps_unpublished += 1
         with telemetry.span("step"):
             new_params, new_states, new_acc, new_aux, outs, grads_out = \
                 plan["fn"](params_raw, states_raw, acc, aux_raw, inputs, rng,   # mxlint: donates 0-3
@@ -1180,7 +1183,8 @@ class Module(BaseModule):
         return list(self._exec.outputs)
 
     def _publish_aux_counters(self):
-        self._exec.publish_aux_counters()
+        steps, self._steps_unpublished = self._steps_unpublished, 0
+        self._exec.publish_aux_counters(steps)
 
     def get_input_grads(self, merge_multi_context=True):
         assert self.binded and self.inputs_need_grad

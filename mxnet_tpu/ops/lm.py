@@ -1,8 +1,11 @@
 """Operators of today's decoder blocks (new-framework extension: the 2017
 reference predates all of them): RMSNorm, rotary embedding, the SiLU
 gate, causal grouped-query attention with an optional window, the
-mixture-of-experts layer with its selection bias as an auxiliary state,
-and a token-level cross-entropy head that holds a small output.
+mixture-of-experts layer (gated, or ungated as ``_contrib_MoEUngated``)
+with its selection bias as an auxiliary state, the Mamba-2 mixer's parts
+(a causal depthwise convolution, the selective state-space recurrence in
+its chunked dual form, the gated group norm), and a token-level
+cross-entropy head that holds a small output.
 
 Layout: activations are ``(batch, T, features)``; heads lie side by side
 in the feature axis (``heads * head_dim``), as ``FullyConnected`` with
@@ -133,12 +136,39 @@ def moe(data, router_weight, expert_w1_weight, expert_w3_weight,
     steps): auxiliary states that a training step's forward pass writes,
     as batch-norm's moving statistics are. Returns ``(out, counts)``;
     ``counts`` is hidden."""
+    return _routed(data, router_weight, bias, expert_w1_weight,
+                   expert_w3_weight, expert_w2_weight, top_k, experts_held,
+                   score_func, route_norm, route_scale, "silu")
+
+
+def _routed(data, router_weight, bias, w1, w3, w2, top_k, experts_held,
+            score_func, route_norm, route_scale, act):
     from ..parallel.moe import moe_layer
     held = _moe_held(dict(experts_held=experts_held,
                           num_experts=router_weight.shape[0]))
-    return moe_layer(data, router_weight, bias, expert_w1_weight,
-                     expert_w3_weight, expert_w2_weight, int(top_k), held,
-                     score_func, bool(route_norm), float(route_scale))
+    return moe_layer(data, router_weight, bias, w1, w3, w2, int(top_k), held,
+                     score_func, bool(route_norm), float(route_scale), act)
+
+
+@register("_contrib_MoEUngated", nin=6, nout=2,
+          arg_names=["data", "router_weight", "expert_w1_weight",
+                     "expert_w2_weight", "bias", "load_running_sum"],
+          defaults={"num_experts": 0, "top_k": 1, "hidden": 0,
+                    "experts_held": (), "score_func": "sigmoid",
+                    "route_norm": True, "route_scale": 1.0,
+                    "load_balance_coeff": 0.0, "act": "relu2"})
+def moe_ungated(data, router_weight, expert_w1_weight, expert_w2_weight,
+                bias, load_running_sum, num_experts=0, top_k=1, hidden=0,
+                experts_held=(), score_func="sigmoid", route_norm=True,
+                route_scale=1.0, load_balance_coeff=0.0, act="relu2",
+                _train=False):
+    """``_contrib_MoE`` with experts of two matrices and no gate:
+    ``act(x W1) W2`` (``act``: a name of ``parallel.moe``'s table;
+    ``relu2`` is ``relu(.)^2``). Routing, the held share, the auxiliary
+    states and the outputs are ``_contrib_MoE``'s."""
+    return _routed(data, router_weight, bias, expert_w1_weight, None,
+                   expert_w2_weight, top_k, experts_held, score_func,
+                   route_norm, route_scale, act)
 
 
 def _moe_held(params):
@@ -147,30 +177,48 @@ def _moe_held(params):
         else (0, int(params["num_experts"]))
 
 
-def _moe_shapes(shapes, params):
-    d, f = shapes[0][-1], int(params["hidden"])
-    n, (_, count) = int(params["num_experts"]), _moe_held(params)
-    return {1: (n, d), 2: (count, d, f), 3: (count, d, f),
-            4: (count, f, d), 5: (n,), 6: (5,)}
+def _install_moe(name):
+    """The hooks of a routed-expert op, by its inputs' names (the gated op
+    has one matrix more than the ungated)."""
+    m = get_op(name)
+    at = {a: i for i, a in enumerate(m.arg_names)}
+    bias, load = at["bias"], at["load_running_sum"]
 
+    def shapes(shapes, params):
+        d, f = shapes[0][-1], int(params["hidden"])
+        n, (_, count) = int(params["num_experts"]), _moe_held(params)
+        out = {at["router_weight"]: (n, d),
+               at["expert_w2_weight"]: (count, f, d), bias: (n,), load: (5,)}
+        out.update((at[a], (count, d, f)) for a in
+                   ("expert_w1_weight", "expert_w3_weight") if a in at)
+        return out
 
-def _moe_stateful_update(raw_inputs, raw_outputs, params):
-    if not params.get("_train"):
-        return {}
-    from ..parallel.moe import bias_update, chunk_load
-    counts = raw_outputs[1]
-    first, count = _moe_held(params)
-    rows = counts[first:first + count]
-    # what the layer's loops ran: their own trip count, from the same counts
-    tokens = raw_inputs[0].size // raw_inputs[0].shape[-1]
-    chunks, overflow = chunk_load(rows, tokens * int(params["top_k"]),
-                                  counts.shape[0])
-    load = raw_inputs[6] + jnp.stack([
-        jnp.ones((), rows.dtype), jnp.sum(rows), jnp.max(rows), chunks,
-        overflow]).astype(_F32)
-    return {5: bias_update(raw_inputs[5], counts,
-                           float(params.get("load_balance_coeff", 0.0))),
-            6: load}
+    def stateful_update(raw_inputs, raw_outputs, params):
+        if not params.get("_train"):
+            return {}
+        from ..parallel.moe import bias_update, chunk_load
+        counts = raw_outputs[1]
+        first, count = _moe_held(params)
+        rows = counts[first:first + count]
+        # what the layer's loops ran: their own trip count, from the same
+        # counts
+        tokens = raw_inputs[0].size // raw_inputs[0].shape[-1]
+        chunks, overflow = chunk_load(rows, tokens * int(params["top_k"]),
+                                      counts.shape[0])
+        grown = raw_inputs[load] + jnp.stack([
+            jnp.ones((), rows.dtype), jnp.sum(rows), jnp.max(rows), chunks,
+            overflow]).astype(_F32)
+        return {bias: bias_update(
+                    raw_inputs[bias], counts,
+                    float(params.get("load_balance_coeff", 0.0))),
+                load: grown}
+
+    m.visible_outputs = 1
+    m.aux_inputs = (bias, load)
+    m.stateful_update = stateful_update
+    m.param_shape_infer = shapes
+    m.param_dtype_infer = _f32_inputs(bias, load)
+    m.aux_counters = {load: _publish_load}
 
 
 def _publish_load(delta):
@@ -184,6 +232,148 @@ def _publish_load(delta):
         telemetry.counter_inc("moe.rows_max", fullest)
         telemetry.counter_inc("moe.chunks_run", chunks)
         telemetry.counter_inc("moe.rows_overflow", overflow)
+
+
+@register("_contrib_CausalConv1D", nin=3,
+          arg_names=["data", "weight", "bias"],
+          defaults={"kernel": 4, "act_type": "silu"})
+def causal_conv1d(data, weight, bias, kernel=4, act_type="silu"):
+    """A causal depthwise convolution along T of ``data`` (batch, T,
+    channels): ``out[t] = bias + sum_k weight[:, k] * data[t - (kernel -
+    1) + k]``, positions before the first counted as nought; ``weight``
+    (channels, kernel), ``bias`` (channels,). ``act_type`` ``"silu"``
+    applies SiLU, ``None`` nothing. Sums in float32, the result in
+    ``data``'s type. Written as ``kernel`` shifted products: one pass over
+    the data for the compiler, where a grouped convolution of one channel
+    a group is ``channels`` convolutions."""
+    k, t = int(kernel), data.shape[1]
+    x = jnp.pad(data.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
+    w = weight.astype(_F32)
+    y = bias.astype(_F32) + sum(x[:, i:i + t] * w[:, i] for i in range(k))
+    if act_type == "silu":
+        y = jax.nn.silu(y)
+    elif act_type is not None:
+        raise ValueError("act_type is 'silu' or None, not %r" % (act_type,))
+    return y.astype(data.dtype)
+
+
+@register("_contrib_GatedRMSNorm", nin=3,
+          arg_names=["data", "gate", "gamma"],
+          defaults={"eps": 1e-5, "group_size": 0})
+def gated_rms_norm(data, gate, gamma, eps=1e-5, group_size=0):
+    """``RMSNorm(data * silu(gate)) * gamma``: the statistics over each run
+    of ``group_size`` features (0: all of them), ``gamma`` one scale a
+    feature (Mamba-2's gated norm, the gate applied before the norm).
+    Float32 throughout, the result in ``data``'s type."""
+    shape = data.shape
+    g = int(group_size) or shape[-1]
+    x = data.astype(_F32) * jax.nn.silu(gate.astype(_F32))
+    x = x.reshape(shape[:-1] + (shape[-1] // g, g))
+    r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return ((x * r).reshape(shape) * gamma.astype(_F32)).astype(data.dtype)
+
+
+def _decay_matrix(to, start, strict=False):
+    """``L[..., i, j] = exp(to[..., i] - start[..., j])`` for ``i >= j`` (``i
+    > j`` if ``strict``) and 0 elsewhere. The mask goes on the exponent:
+    what is masked may overflow, and its gradient is then 0 and not NaN."""
+    q = to.shape[-1]
+    keep = jnp.tril(jnp.ones((q, q), bool), -1 if strict else 0)
+    return jnp.exp(jnp.where(keep, to[..., :, None] - start[..., None, :],
+                             -jnp.inf))
+
+
+@register("_contrib_SSD", nin=7,
+          arg_names=["data", "dt", "B", "C", "A_log", "dt_bias", "D"],
+          defaults={"heads": 1, "head_dim": 0, "state": 0, "groups": 1,
+                    "chunk": 128})
+def ssd(data, dt, B, C, A_log, dt_bias, D, heads=1, head_dim=0, state=0,
+        groups=1, chunk=128):
+    """The selective state-space recurrence of a Mamba-2 mixer, per head h
+    of ``heads`` (``heads / groups`` heads share one B and one C)::
+
+        delta_t = softplus(dt_t + dt_bias)        a_t = -exp(A_log) delta_t
+        S_t = exp(a_t) S_{t-1} + delta_t x_t B_t^T      (head_dim x state)
+        y_t = S_t C_t + D x_t
+
+    over ``data`` (batch, T, heads * head_dim), ``dt`` (batch, T, heads),
+    ``B`` and ``C`` (batch, T, groups * state); ``A_log``, ``dt_bias``, ``D``
+    (heads,). Computed in the chunked dual form (Dao & Gu 2024, "SSD"),
+    never as a scan over T: with chunks of ``chunk`` positions and ``cs``
+    the cumulative sum of ``a`` inside a chunk, a chunk's own positions
+    give ``(L o (C B^T)) (delta x)`` with ``L[i, j] = exp(cs_i - cs_j)``,
+    ``i >= j`` (``ssd/diag``); each chunk leaves the state ``sum_j
+    exp(cs_last - cs_j) delta_j x_j B_j^T`` (``ssd/state``); the states
+    that enter the chunks are those sums decayed over the chunks between,
+    the same L-form over chunks (``ssd/pass``); and they add ``exp(cs_i)
+    C_i S_in`` (``ssd/off``). Decays, cumulative sums and states are
+    float32; the four products take operands of ``data``'s type and
+    accumulate in float32. A T that is no multiple of ``chunk`` is padded
+    with positions that change no state. The chunks a step computes are
+    fixed by the shapes: the counters ``ssm.steps`` and ``ssm.chunks_run``
+    are counted on the host (``_ssd_count_steps``)."""
+    h, p, n, g, q = (int(heads), int(head_dim), int(state), int(groups),
+                     int(chunk))
+    r = h // g
+    b, t, _ = data.shape
+    dtype = data.dtype
+    with jax.named_scope("ssd/decay"):
+        delta = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
+        a = -jnp.exp(A_log.astype(_F32)) * delta                  # (b, t, h)
+        x = data.reshape(b, t, h, p)
+        xd = x.astype(_F32) * delta[..., None]
+        pad = -t % q
+        if pad:         # a = 0 and delta x = 0: the state passes through
+            a, xd, B, C = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
+                                   * (v.ndim - 2)) for v in (a, xd, B, C))
+        c = (t + pad) // q
+        cs = jnp.cumsum(a.reshape(b, c, q, h), axis=2)
+        last = cs[:, :, -1]                                       # (b, c, h)
+        # within a chunk: from j to i, from j to the chunk's end, from the
+        # chunk's start to i
+        by_head = jnp.swapaxes(cs, 2, 3)
+        within = _decay_matrix(by_head, by_head)            # (b, c, h, q, q)
+        to_end = jnp.exp(last[:, :, None] - cs)
+        from_start = jnp.exp(cs)
+        xd = xd.reshape(b, c, q, g, r, p)
+        bc, cc = B.reshape(b, c, q, g, n), C.reshape(b, c, q, g, n)
+    with jax.named_scope("ssd/diag"):
+        cb = jnp.einsum("bcqgn,bckgn->bcgqk", cc, bc,
+                        preferred_element_type=_F32)
+        m = (within.reshape(b, c, g, r, q, q) * cb[:, :, :, None])
+        y = jnp.einsum("bcgrqk,bckgrp->bcqgrp", m.astype(dtype),
+                       xd.astype(dtype), preferred_element_type=_F32)
+    with jax.named_scope("ssd/state"):
+        xs = (xd * to_end.reshape(b, c, q, g, r, 1)).astype(dtype)
+        own = jnp.einsum("bckgrp,bckgn->bcgrpn", xs, bc,
+                         preferred_element_type=_F32)
+    with jax.named_scope("ssd/pass"):
+        # into chunk z: chunk k's state for k < z, decayed over the chunks
+        # between them (float32 states: the product at full precision)
+        done = jnp.swapaxes(jnp.cumsum(last, axis=1), 1, 2)      # (b, h, c)
+        across = _decay_matrix(done - jnp.swapaxes(last, 1, 2), done,
+                               strict=True)
+        s_in = jnp.einsum("bgrzk,bkgrpn->bzgrpn",
+                          across.reshape(b, g, r, c, c), own,
+                          precision=jax.lax.Precision.HIGHEST)
+    with jax.named_scope("ssd/off"):
+        y = y + jnp.einsum("bcqgn,bcgrpn->bcqgrp", cc, s_in.astype(dtype),
+                           preferred_element_type=_F32) \
+            * from_start.reshape(b, c, q, g, r, 1)
+        y = y.reshape(b, c * q, h, p)[:, :t] \
+            + D.astype(_F32)[:, None] * x.astype(_F32)
+    return y.reshape(b, t, h * p).astype(dtype)
+
+
+def _ssd_count_steps(out_shape, params, steps):
+    """``steps`` training steps of one recurrence into the telemetry
+    counters (``Executor.publish_aux_counters``): its chunks are fixed by
+    its shapes, so they are counted here on the host."""
+    from .. import telemetry
+    b, t = out_shape[:2]
+    telemetry.counter_inc("ssm.steps", steps)
+    telemetry.counter_inc(
+        "ssm.chunks_run", steps * b * -(-t // int(params.get("chunk", 128))))
 
 
 @register("_contrib_TokenCrossEntropy", nin=3,
@@ -225,13 +415,23 @@ def install():
     rms = get_op("_contrib_RMSNorm")
     rms.param_shape_infer = _rms_shapes
     rms.param_dtype_infer = _f32_inputs(1)
-    m = get_op("_contrib_MoE")
-    m.visible_outputs = 1
-    m.aux_inputs = (5, 6)
-    m.stateful_update = _moe_stateful_update
-    m.param_shape_infer = _moe_shapes
-    m.param_dtype_infer = _f32_inputs(5, 6)
-    m.aux_counters = {6: _publish_load}
+    _install_moe("_contrib_MoE")
+    _install_moe("_contrib_MoEUngated")
+    conv = get_op("_contrib_CausalConv1D")
+    conv.param_shape_infer = lambda shapes, params: {
+        1: (shapes[0][-1], int(params.get("kernel", 4))),
+        2: (shapes[0][-1],)}
+    # the taps may come in float32 (a start plus learned offsets): the
+    # result is of the data's type, and inference without shapes says so
+    conv.param_dtype_infer = lambda in_types, params: {}
+    gated = get_op("_contrib_GatedRMSNorm")
+    gated.param_shape_infer = lambda shapes, params: {2: (shapes[0][-1],)}
+    gated.param_dtype_infer = _f32_inputs(2)
+    s = get_op("_contrib_SSD")
+    s.param_shape_infer = lambda shapes, params: dict.fromkeys(
+        (4, 5, 6), (int(params["heads"]),))
+    s.param_dtype_infer = _f32_inputs(4, 5, 6)
+    s.step_counters = _ssd_count_steps
     ce = get_op("_contrib_TokenCrossEntropy")
     ce.param_shape_infer = _ce_shapes
     ce.param_dtype_infer = lambda in_types, params: {2: np.int32}

@@ -90,6 +90,11 @@ class OpDef:
         # running sums, and ``Executor.publish_aux_counters`` hands ``fn``
         # what they grew by since its last call, for telemetry counters.
         self.aux_counters = None
+        # Optional fn(output_shape, params, steps): adds to the telemetry
+        # counters the work that the op's shapes fix, for the ``steps``
+        # fused steps run since ``Executor.publish_aux_counters`` last
+        # called it.
+        self.step_counters = None
 
     def __repr__(self):
         return "OpDef(%s)" % self.name

@@ -22,13 +22,17 @@ products skip the rest by themselves (and accumulate and hand back
 float32), and every other pass over the sorted rows (dispatch, rounding a
 product to the data's type, gate, weights, and their transposes) is a
 loop over *chunks* of ``chunk_rows`` rows, the held experts' even share
-of the selections, that runs as many chunks as the held rows fill
-(``chunk_load``). A step that routes its even share here pays for one
-chunk and one of noughts after it (the next product's tile may read
-there), one that routes every selection here for all ``T k /
-chunk_rows``; what lies past is never written and never read, and the
-combine takes nothing from it. A layer that holds every expert has one chunk of
-``T k`` rows.
+of the selections, that runs the chunks that the held rows and a product's
+tile of noughts after them fill (the next product's tile may read there),
+counted up to whole stairs of three chunks (``chunk_load``). A router that
+is still learning sends a device up to twice its even share within a few
+hundred steps, more with every step and differently with every seed; all
+of that is one stair, so a step's row passes cost the same whatever it
+routes here, and only the products follow the load. A step that routes
+every selection here runs all ``T k / chunk_rows``; what lies past the
+chunks a step runs is never written and never read, and the combine takes
+nothing from it. A layer that holds every expert has one chunk of ``T k``
+rows.
 """
 from __future__ import annotations
 
@@ -52,7 +56,8 @@ PARTITION_RULES = [
     (r"expert", P("ep")),
 ]
 
-_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+         "relu2": lambda a: jnp.square(jax.nn.relu(a))}
 
 
 def route(x, router_w, bias, top_k, score_func="sigmoid", route_norm=True,
@@ -80,9 +85,27 @@ def route(x, router_w, bias, top_k, score_func="sigmoid", route_norm=True,
 #: rows of the grouped product's row tile where the chunks matter (the
 #: compiler's kernel at the benchmark's shapes, (65,536 x 2,048) by
 #: (16, 2,048, 1,024): its metadata lists ``T k / 512 + count - 1``
-#: tiles, which ``tests/test_tpu_compile.py`` holds it to). A chunk is a
-#: multiple of it, so no tile lies across more than two chunks.
+#: tiles, which ``tests/test_tpu_compile.py`` holds it to, there and at
+#: the hybrid cell's). A chunk is a multiple of it and tiles start at its
+#: multiples, so the tile that holds the last held row ends less than one
+#: tile past it; and the products' widths are padded to multiples of it
+#: (``held_experts_ffn``).
 _TILE = 512
+
+#: chunks in one stair of the row passes' trip count. The passes' cost
+#: steps by what the trip count steps by, so a stair holds what a step may
+#: route here while its router learns: over ``Module.fit`` windows of some
+#: hundred steps a layer's held rows climb from about the even share to
+#: 1.7 times it (``trinity_mini.fit``, 16 of 128 held) and, in the last
+#: expert block of ``nemotron3_nano.fit`` (8 of 128), to a mean of 1.5 to
+#: 2.0 times it over the window, the faster the later the layer and at a
+#: pace that follows the seed. With a stair of one chunk those layers
+#: crossed a step of the passes' cost at a time of the seed's choosing,
+#: and the cell's rate followed the seed (PERF.md, PR 34). The chunk
+#: itself stays one even share: passes over chunks three times as long
+#: cost the chip a quarter more a row at 9,216 rows and four fifths more
+#: at 24,576.
+_STAIR = 3
 
 
 def chunk_rows(selections, count, num_experts):
@@ -97,13 +120,16 @@ def chunk_rows(selections, count, num_experts):
 def chunk_load(rows, selections, num_experts):
     """``(chunks, overflow)`` of a layer whose held experts take ``rows``
     (count,) of the step's ``selections``: the chunks of the sorted order
-    each row pass runs (the loops' trip count: those the held rows fill
-    and, where there is one, the chunk after them, which is written as
-    nought) and the held rows past the first chunk. Both int32 scalars."""
+    each row pass runs (the loops' trip count: as many whole stairs of
+    ``_STAIR`` chunks as the held rows and one product tile after them,
+    which is written as nought, reach into, and never more chunks than
+    the order has) and the held rows past the first chunk. Both int32
+    scalars."""
     size = chunk_rows(selections, rows.shape[0], num_experts)
     total = jnp.sum(rows.astype(jnp.int32))
-    filled = (total + size - 1) // size
-    return (jnp.minimum(filled + 1, -(-selections // size)),
+    stair = _STAIR * size
+    return (jnp.minimum(_STAIR * ((total + _TILE + stair - 1) // stair),
+                        -(-selections // size)),
             jnp.maximum(total - size, 0))
 
 
@@ -118,9 +144,9 @@ def _rows(fn, sort, bufs):
     a sorted selection, ``fn`` maps a chunk of each to a tuple of such
     chunks, row by row. What a grouped product leaves past the held rows
     is undefined, and the next product's tile may read past them: so the
-    rows past the held ones are written as nought, in the last chunk they
-    fill and in the one after it (a tile is no longer than a chunk). The
-    chunks after that are never written, nor read."""
+    rows past the held ones are written as nought to the end of the last
+    chunk that runs, which lies a tile or more past them (``chunk_load``).
+    The chunks after that are never written, nor read."""
     order, _, total, trips = sort
     size = order.shape[1]
 
@@ -286,12 +312,25 @@ def held_experts_ffn(x, sel, w, counts, w1, w3, w2, first=0, act="silu"):
     grouped products skip the others themselves; every other pass over
     the sorted rows (dispatch, the products' rounding to x's type, gate,
     weights) is made ``chunk_rows(T k, count, num_experts)`` rows at a
-    time over the chunks the real rows fill, and the combine takes
-    nothing from a row past them. Past the chunk after those a buffer
+    time over the chunks ``chunk_load`` counts, and the combine takes
+    nothing from a row past the real ones. Past those chunks a buffer
     holds whatever the memory held: no pass reads it."""
     t, k = sel.shape
-    count = w1.shape[0]
+    count, d, f = w1.shape
     rows = counts[first:first + count]
+    # the grouped product's kernel tiles its widths too: at widths its
+    # tile does not divide (2,688 x 1,856) a product costs the chip three
+    # to five times what it costs at the next multiple (3,072 x 2,048,
+    # a quarter more arithmetic), and three times as much a further row.
+    # Noughts change no sum: a padded input feature meets a nought weight,
+    # a padded hidden unit is act(0) = 0, a padded output is cut off again
+    wide = (-d % _TILE, -f % _TILE)
+    if any(wide):
+        x = jnp.pad(x, ((0, 0), (0, wide[0])))
+        w1, w3 = (u if u is None else
+                  jnp.pad(u, ((0, 0), (0, wide[0]), (0, wide[1])))
+                  for u in (w1, w3))
+        w2 = jnp.pad(w2, ((0, 0), (0, wide[1]), (0, wide[0])))
     size = chunk_rows(t * k, count, counts.shape[0])
     with jax.named_scope("dispatch"):
         local = sel.reshape(-1) - first
@@ -309,7 +348,7 @@ def held_experts_ffn(x, sel, w, counts, w1, w3, w2, first=0, act="silu"):
     with jax.named_scope("grouped"):
         y = _experts(act, xs, ws, rows, sort, w1, w3, w2)
     with jax.named_scope("combine"):
-        return _collect(y, sort, k)
+        return _collect(y, sort, k)[:, :d]
 
 
 def bias_update(bias, counts, coeff):

@@ -197,6 +197,9 @@ COUNTERS = (
     # ran, held rows past a layer-step's first chunk
     "moe.steps", "moe.rows_held", "moe.rows_max", "moe.chunks_run",
     "moe.rows_overflow",
+    # the state-space recurrence (ops/lm.py ``_contrib_SSD``), summed over
+    # training steps and mixers: steps, chunks computed
+    "ssm.steps", "ssm.chunks_run",
 )
 
 

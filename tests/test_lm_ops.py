@@ -314,9 +314,12 @@ def test_chunked_layer_matches_reference(case):
         route_scale=2.826)[1])[first:first + count]
     assert counts.sum() == want_rows
     assert -(-int(counts.sum()) // size) == want_chunks
-    # the row passes run those and, where there is one, a chunk of noughts
+    # the row passes run whole stairs of three chunks: those that the held
+    # rows and a tile of noughts after them reach into
     chunks, overflow = moe.chunk_load(jnp.asarray(counts), t * k, n)
-    assert int(chunks) == min(want_chunks + 1, t * k // size)
+    assert int(chunks) == min(3 * -(-(want_rows + 512) // (3 * size)),
+                              t * k // size)
+    assert int(chunks) * size >= min(want_rows + 512, t * k)
     assert int(overflow) == max(want_rows - size, 0)
     if case[0] == "group_split_at_the_boundary":
         ends = np.cumsum(counts)
@@ -363,7 +366,7 @@ def test_chunked_layer_is_the_whole_buffer_layer(share, dtype, monkeypatch):
     sel, w = moe.route(x, router, bias, k, route_scale=2.826)
     counts = jnp.bincount(sel.reshape(-1), length=n).astype(jnp.int32)
     assert int(moe.chunk_load(counts[:count], t * k, n)[0]) \
-        == (1 if count == n else 3 + 1)
+        == (1 if count == n else 2 * 3)
     monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
         shape, jnp.nan, dtype))
     moe.held_experts_ffn.clear_cache()
@@ -559,7 +562,7 @@ def test_fit_publishes_chunk_counters():
     assert now.get("moe.rows_overflow", 0) \
         == before.get("moe.rows_overflow", 0)
     # 4,096 selections, 8 of 64 experts held: chunks of 512 rows; 1,301
-    # rows fill 3, and the passes run the chunk after them too
+    # rows and the tile after them reach into the second stair of three
     counts = jnp.zeros(64, jnp.int32).at[8:16].set(
         jnp.asarray([171, 162, 155, 163, 155, 168, 159, 168]))
     new = get_op("_contrib_MoE").stateful_update(
@@ -567,7 +570,7 @@ def test_fit_publishes_chunk_counters():
          jnp.asarray([4.0, 10.0, 5.0, 6.0, 7.0])], (None, counts),
         dict(num_experts=64, top_k=8, experts_held=(8, 8), _train=True,
              load_balance_coeff=0.001))
-    _close(new[6], [5, 10 + 1301, 5 + 171, 6 + 3 + 1, 7 + 1301 - 512])
+    _close(new[6], [5, 10 + 1301, 5 + 171, 6 + 2 * 3, 7 + 1301 - 512])
 
 
 def test_mirror_stages_cut_one_segment_a_layer():

@@ -181,29 +181,38 @@ def test_flash_attention_carry_vmem_limit_at_8k(one_chip):
         _compile(carry, q, q, q, o, m, m)
 
 
-def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip):
-    """The routed layer at ``trinity_mini.fit``'s shapes (8,192 tokens,
-    top-8, d 2,048, f 1,024, 16 of 128 experts held, bfloat16): forward
-    and gradient compile, the sorted order in 8 chunks of 8,192 rows. The
-    grouped products stay 3 forward and 3 + 6 with the gradient, each one
-    kernel over the whole order; the row passes are loops whose trip count
-    is the step's own, and no branch holds a second body; the products'
-    row tile is the 512 rows that ``chunk_rows`` rounds to. Under
-    ``jax.checkpoint``, as the step holds a layer, the program's
-    temporaries stay at the whole-buffer layer's 2.01 GB."""
+@pytest.mark.parametrize("cell", [
+    # top-k, d, f, held, gated, GiB of temporaries
+    (8, 2048, 1024, 16, True, 2.1), (6, 2688, 1856, 8, False, 2.6)],
+    ids=["trinity_mini.fit", "nemotron3_nano.fit"])
+def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip, cell):
+    """The routed layer at the cells' shapes (8,192 tokens, bfloat16;
+    ``trinity_mini.fit``: top-8, d 2,048, f 1,024, 16 of 128 gated experts
+    held; ``nemotron3_nano.fit``: top-6, 2,688 x 1,856, 8 of 128, no
+    gate): forward and gradient compile, the sorted order in chunks of
+    the even share (8 of 8,192 rows, 16 of 3,072). The grouped products stay 3
+    forward and 3 + 6 with the gradient (2 and 2 + 4 without a gate), each
+    one kernel over the whole order; the row passes are loops whose trip
+    count is the step's own, and no branch holds a second body; the
+    products' row tile is the 512 rows that ``chunk_rows`` rounds to.
+    Under ``jax.checkpoint``, as the step holds a layer, the gated layer's
+    temporaries stay at the whole-buffer layer's 2.01 GB (1.93 GiB; 2.44
+    GiB for the ungated layer at its wider, padded shapes)."""
     from mxnet_tpu.parallel import moe
-    t, k, d, f, n, count = 8192, 8, 2048, 1024, 128, 16
-    assert moe.chunk_rows(t * k, count, n) == 8192
+    t, n = 8192, 128
+    k, d, f, count, gated, room = cell
+    assert moe.chunk_rows(t * k, count, n) == t * k * count // n
 
     def shape(*s):
         return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
 
-    args = (shape(t, d), shape(n, d), shape(count, d, f),
-            shape(count, d, f), shape(count, f, d))
+    args = (shape(t, d), shape(n, d), shape(count, d, f)) \
+        + (shape(count, d, f),) * gated + (shape(count, f, d),)
 
-    def layer(x, router, w1, w3, w2):
-        return moe.moe_layer(x, router, jnp.zeros(n), w1, w3, w2, k,
-                             (0, count), route_scale=2.826)[0]
+    def layer(x, router, w1, *w):
+        return moe.moe_layer(x, router, jnp.zeros(n), w1, *(None,) * (not gated),
+                             *w, k, (0, count), route_scale=2.826,
+                             act="silu" if gated else "relu2")[0]
 
     def step(*a):
         out, pull = jax.vjp(jax.checkpoint(layer), *a)
@@ -214,17 +223,48 @@ def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip):
     with jax.default_matmul_precision("default"):
         fwd = _compile(layer, *args)
         grad = _compile(step, *args)
-    assert fwd.as_text().count('op_name="ragged-dot-none"') == 3
+    products = 3 if gated else 2
+    assert fwd.as_text().count('op_name="ragged-dot-none"') == products
     text = grad.as_text()
-    assert text.count('op_name="ragged-dot-none"') == 3 + 3 + 6
+    assert text.count('op_name="ragged-dot-none"') == 4 * products
     assert " while(" in text and " conditional(" not in text
     # the grouped product walks its rows in tiles of ``moe._TILE``: its
     # metadata lists T k / tile + count - 1 of them. A chunk is a multiple
-    # of the tile, so a tile never reaches past the chunk after the last
-    # filled one, which the row passes keep nought
+    # of the tile, so the tile that holds the last held row ends less than
+    # a tile past it, and the row passes keep that far nought
     tiles = t * k // moe._TILE + count - 1
     metadata = [line for line in text.splitlines()
                 if 'op_name="ragged-dot-metadata"' in line
                 and "custom-call(" in line]
     assert metadata and all("s32[%d]" % tiles in m for m in metadata)
-    assert grad.memory_analysis().temp_size_in_bytes < 2.1 * 2 ** 30
+    assert grad.memory_analysis().temp_size_in_bytes < room * 2 ** 30
+
+
+def test_ssd_compiles_at_the_cell_shapes(one_chip):
+    """``_contrib_SSD`` at ``nemotron3_nano.fit``'s shapes (one sequence of
+    8,192 tokens, 64 heads of 64, state 128 in 8 groups, chunks of 128,
+    bfloat16), forward and gradient under ``jax.checkpoint`` as the step
+    holds a block: no loop over tokens or chunks (the pass of the states is
+    the L-form over chunks, a product), and the temporaries of one mixer's
+    recurrence stay under 2 GB."""
+    from mxnet_tpu.ops import lm
+    t, h, p, n, g = 8192, 64, 64, 128, 8
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    args = (shape(1, t, h * p), shape(1, t, h), shape(1, t, g * n),
+            shape(1, t, g * n)) + (shape(h, dtype=jnp.float32),) * 3
+
+    def ssd(*a):
+        return lm.ssd(*a, heads=h, head_dim=p, state=n,
+                      groups=g, chunk=128)
+
+    def step(*a):
+        out, pull = jax.vjp(jax.checkpoint(ssd), *a)
+        return out, pull(jnp.cos(out))
+
+    with jax.default_matmul_precision("default"):
+        grad = _compile(step, *args)
+    assert " while(" not in grad.as_text()
+    assert grad.memory_analysis().temp_size_in_bytes < 2.0 * 2 ** 30
