@@ -13,6 +13,8 @@ in the feature axis (``heads * head_dim``), as ``FullyConnected`` with
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -299,70 +301,82 @@ def ssd(data, dt, B, C, A_log, dt_bias, D, heads=1, head_dim=0, state=0,
     over ``data`` (batch, T, heads * head_dim), ``dt`` (batch, T, heads),
     ``B`` and ``C`` (batch, T, groups * state); ``A_log``, ``dt_bias``, ``D``
     (heads,). Computed in the chunked dual form (Dao & Gu 2024, "SSD"),
-    never as a scan over T: with chunks of ``chunk`` positions and ``cs``
-    the cumulative sum of ``a`` inside a chunk, a chunk's own positions
-    give ``(L o (C B^T)) (delta x)`` with ``L[i, j] = exp(cs_i - cs_j)``,
-    ``i >= j`` (``ssd/diag``); each chunk leaves the state ``sum_j
-    exp(cs_last - cs_j) delta_j x_j B_j^T`` (``ssd/state``); the states
-    that enter the chunks are those sums decayed over the chunks between,
-    the same L-form over chunks (``ssd/pass``); and they add ``exp(cs_i)
-    C_i S_in`` (``ssd/off``). Decays, cumulative sums and states are
-    float32; the four products take operands of ``data``'s type and
-    accumulate in float32. A T that is no multiple of ``chunk`` is padded
-    with positions that change no state. The chunks a step computes are
-    fixed by the shapes: the counters ``ssm.steps`` and ``ssm.chunks_run``
-    are counted on the host (``_ssd_count_steps``)."""
-    h, p, n, g, q = (int(heads), int(head_dim), int(state), int(groups),
-                     int(chunk))
-    r = h // g
+    never as a scan over T. With chunks of ``chunk`` positions and ``cs``
+    the cumulative sum of ``a`` inside a chunk (``ssd/decay``, with
+    ``delta``: elementwise over (batch, T, heads), handed on lane-major as
+    (batch, chunks, heads, chunk)), everything that reads a chunk's
+    positions runs in the Pallas kernels of ``pallas.ssd`` (interpreted
+    off the TPU), one program a sequence, a few chunks and a group, and no
+    ``(chunk, chunk)`` block exists outside them:
+
+    - ``ssd/state`` (``ssd_states``: ``ssd_state_fwd`` / ``ssd_state_bwd``):
+      each chunk leaves the state ``sum_j exp(cs_last - cs_j) delta_j x_j
+      B_j^T``, (batch, heads, chunks, head_dim, state) float32;
+    - ``ssd/pass`` (``jax.numpy``): the states that enter the chunks are
+      those sums decayed over the chunks between, the same L-form over
+      chunks: one product of (chunks, chunks) a head at full precision;
+    - ``ssd/chunk`` (``ssd_chunk``: ``ssd_chunk_fwd`` / ``ssd_chunk_bwd``):
+      a chunk's own positions give ``(L o (C B^T)) (delta x)`` with ``L[i,
+      j] = exp(cs_i - cs_j)``, ``i >= j``, the mask on the exponent, and
+      the entering state adds ``exp(cs_i) C_i S_in``; the backward kernel
+      makes ``L``, ``C B^T`` and their product again on the chip.
+
+    Decays, cumulative sums, exponents, ``L o (C B^T)`` and states are
+    float32; the products take operands of ``data``'s type (``delta x``,
+    ``L o (C B^T)``, the entering states and, backward, the cotangents are
+    rounded to it) and accumulate in float32. The kernels' backward rules
+    keep their inputs and nothing a forward kernel made, so a mirrored
+    segment runs the forward kernels again only because what follows the
+    node needs its output. ``head_dim`` and ``state`` that are not the
+    kernels' tile are padded with noughts inside ``pallas.ssd``; a T that
+    is no multiple of ``chunk`` is padded with positions that change no
+    state; the chunk stays the configured one. On the chip ``chunk`` has to
+    be a multiple of 128, ``heads / groups`` of 8 and ``head_dim`` at most
+    128. The chunks a step
+    computes are fixed by the shapes: the counters ``ssm.steps`` and
+    ``ssm.chunks_run`` are counted on the host (``_ssd_count_steps``)."""
+    h, n = int(heads), int(state)
+    if data.shape[-1] != h * int(head_dim) or B.shape[-1] != int(groups) * n:
+        raise ValueError(
+            "data %s is not heads x head_dim = %d x %d wide, or B %s not "
+            "groups x state = %d x %d" % (data.shape, h, int(head_dim),
+                                          B.shape, int(groups), n))
+    return _ssd(data, dt, B, C, A_log, dt_bias, D, h, n, int(chunk))
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))   # mxlint: disable=jit-site -- a body inside the caller's program (the fused step's card covers it), never a dispatch of its own
+def _ssd(data, dt, B, C, A_log, dt_bias, D, h, n, q):
+    """``_contrib_SSD``'s body, jitted by itself so that a model's mixers
+    lower once."""
+    from ..pallas.ssd import ssd_chunk, ssd_states
     b, t, _ = data.shape
     dtype = data.dtype
     with jax.named_scope("ssd/decay"):
         delta = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
         a = -jnp.exp(A_log.astype(_F32)) * delta                  # (b, t, h)
-        x = data.reshape(b, t, h, p)
-        xd = x.astype(_F32) * delta[..., None]
+        x = data
         pad = -t % q
-        if pad:         # a = 0 and delta x = 0: the state passes through
-            a, xd, B, C = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
-                                   * (v.ndim - 2)) for v in (a, xd, B, C))
+        if pad:     # a = 0 and delta = 0: the state passes through
+            a, delta, x, B, C = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                                 for v in (a, delta, x, B, C))
         c = (t + pad) // q
         cs = jnp.cumsum(a.reshape(b, c, q, h), axis=2)
-        last = cs[:, :, -1]                                       # (b, c, h)
-        # within a chunk: from j to i, from j to the chunk's end, from the
-        # chunk's start to i
-        by_head = jnp.swapaxes(cs, 2, 3)
-        within = _decay_matrix(by_head, by_head)            # (b, c, h, q, q)
-        to_end = jnp.exp(last[:, :, None] - cs)
-        from_start = jnp.exp(cs)
-        xd = xd.reshape(b, c, q, g, r, p)
-        bc, cc = B.reshape(b, c, q, g, n), C.reshape(b, c, q, g, n)
-    with jax.named_scope("ssd/diag"):
-        cb = jnp.einsum("bcqgn,bckgn->bcgqk", cc, bc,
-                        preferred_element_type=_F32)
-        m = (within.reshape(b, c, g, r, q, q) * cb[:, :, :, None])
-        y = jnp.einsum("bcgrqk,bckgrp->bcqgrp", m.astype(dtype),
-                       xd.astype(dtype), preferred_element_type=_F32)
+        last = jnp.swapaxes(cs[:, :, -1], 1, 2)                   # (b, h, c)
+        # the kernels take the row statistics lane-major: (b, c, h, q)
+        delta, cs = jnp.swapaxes(delta.reshape(cs.shape), 2, 3), \
+            jnp.swapaxes(cs, 2, 3)
     with jax.named_scope("ssd/state"):
-        xs = (xd * to_end.reshape(b, c, q, g, r, 1)).astype(dtype)
-        own = jnp.einsum("bckgrp,bckgn->bcgrpn", xs, bc,
-                         preferred_element_type=_F32)
+        own = ssd_states(x, delta, cs, B, n)                # (b, h, c, p, n)
     with jax.named_scope("ssd/pass"):
         # into chunk z: chunk k's state for k < z, decayed over the chunks
         # between them (float32 states: the product at full precision)
-        done = jnp.swapaxes(jnp.cumsum(last, axis=1), 1, 2)      # (b, h, c)
-        across = _decay_matrix(done - jnp.swapaxes(last, 1, 2), done,
-                               strict=True)
-        s_in = jnp.einsum("bgrzk,bkgrpn->bzgrpn",
-                          across.reshape(b, g, r, c, c), own,
+        done = jnp.cumsum(last, axis=2)
+        across = _decay_matrix(done - last, done, strict=True)
+        s_in = jnp.einsum("bhzk,bhkpn->bhzpn", across, own,
                           precision=jax.lax.Precision.HIGHEST)
-    with jax.named_scope("ssd/off"):
-        y = y + jnp.einsum("bcqgn,bcgrpn->bcqgrp", cc, s_in.astype(dtype),
-                           preferred_element_type=_F32) \
-            * from_start.reshape(b, c, q, g, r, 1)
-        y = y.reshape(b, c * q, h, p)[:, :t] \
-            + D.astype(_F32)[:, None] * x.astype(_F32)
-    return y.reshape(b, t, h * p).astype(dtype)
+    with jax.named_scope("ssd/chunk"):
+        y = ssd_chunk(x, delta, cs, B, C, s_in.astype(dtype), D)
+    return y[:, :t]
 
 
 def _ssd_count_steps(out_shape, params, steps):

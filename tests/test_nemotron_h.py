@@ -68,18 +68,35 @@ def _check(op_fn, ref_fn, args, tol=2e-5):
 
 # -- the mixer's ops ----------------------------------------------------------
 
-def _recurrence(x, dt, b, c, a_log, dt_bias, d):
-    """The reference's token-by-token scan, a sequence at a time."""
+def _recurrence(x, dt, b, c, a_log, dt_bias, d, dims=(H, P, N, G),
+                rounded=None):
+    """The reference's token-by-token scan, a sequence at a time, in
+    float32. ``rounded``: the type the configuration rounds the products'
+    operand ``delta x`` to (the inputs are then of that type too)."""
+    h, p, n, g = dims
+    x, dt, b, c = (v.astype(jnp.float32) for v in (x, dt, b, c))
+
     def one(x, dt, b, c):
         t = x.shape[0]
         delta = jax.nn.softplus(dt + dt_bias)
-        xh = x.reshape(t, G, H // G, P)
+        xh = x.reshape(t, g, h // g, p)
+        xd = xh * delta.reshape(t, g, h // g, 1)
+        if rounded is not None:
+            xd = xd.astype(rounded).astype(jnp.float32)
         y = ref.recurrence(
-            xh * delta.reshape(t, G, H // G, 1),
-            jnp.exp(-jnp.exp(a_log) * delta).reshape(t, G, H // G),
-            b.reshape(t, G, N), c.reshape(t, G, N), block=4)
-        return (y + d.reshape(G, H // G, 1) * xh).reshape(t, H * P)
+            xd, jnp.exp(-jnp.exp(a_log) * delta).reshape(t, g, h // g),
+            b.reshape(t, g, n), c.reshape(t, g, n),
+            block=4 if t % 4 == 0 else t)
+        return (y + d.reshape(g, h // g, 1) * xh).reshape(t, h * p)
     return jnp.stack([one(*s) for s in zip(x, dt, b, c)])
+
+
+def _ssd_args(rs, batch, t, dims, dtype=jnp.float32):
+    h, p, n, g = dims
+    shapes = ((batch, t, h * p), (batch, t, h), (batch, t, g * n),
+              (batch, t, g * n), (h,), (h,), (h,))
+    return tuple(_rand(rs, *s).astype(dtype if i < 4 else jnp.float32)
+                 for i, s in enumerate(shapes))
 
 
 @pytest.mark.parametrize("t,chunk", [(37, 8), (37, 16), (32, 8), (5, 128)],
@@ -88,12 +105,77 @@ def test_ssd_matches_the_token_recurrence(t, chunk):
     """Forward and every input's gradient, at a T that is and is not a
     multiple of the chunk, at two chunk sizes, and at a chunk longer than
     the sequence."""
-    rs = np.random.RandomState(0)
-    args = tuple(_rand(rs, *s) for s in (
-        (2, t, H * P), (2, t, H), (2, t, G * N), (2, t, G * N), (H,), (H,),
-        (H,)))
+    args = _ssd_args(np.random.RandomState(0), 2, t, (H, P, N, G))
     _check(lambda *a: lm.ssd(*a, heads=H, head_dim=P, state=N, groups=G,
                              chunk=chunk), _recurrence, args)
+
+
+TILE = (2, 64, 128, 1)      # heads, head_dim, state, groups: the kernels' own
+
+
+def _worst(got, want):
+    """The largest distance over the largest value of ``want``."""
+    got, want = (np.asarray(v, np.float32) for v in (got, want))
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+def check_ssd_at_the_tile(t, dtype, dims=TILE, chunk=128):
+    """``lm.ssd`` at the kernels' tile against the token recurrence with
+    the configuration's roundings (``delta x`` in the data's type, decays
+    and states float32), forward and every input's gradient: to float32's
+    last places in float32, to bfloat16's (``M``, the states and the
+    cotangents are rounded once each) in bfloat16. Also what the chip's
+    lane runs (``tests/tpu``)."""
+    h, p, n, g = dims
+    args = _ssd_args(np.random.RandomState(5), 1, t, dims, dtype)
+    half = dtype != jnp.float32
+    tol = 2e-2 if half else 2e-5
+
+    def op(*a):
+        return lm.ssd(*a, heads=h, head_dim=p, state=n, groups=g, chunk=chunk)
+
+    def want(*a):
+        return _recurrence(*a, dims=dims, rounded=dtype if half else None)
+
+    w = jnp.cos(jnp.arange(t * h * p, dtype=jnp.float32)).reshape(1, t, -1)
+    out, pull = jax.vjp(op, *args)
+    exp, pull_exp = jax.vjp(want, *args)
+    assert out.dtype == dtype
+    assert _worst(out, exp) < tol
+    for got, e in zip(pull(w.astype(dtype)), pull_exp(w)):
+        assert got.dtype == e.dtype
+        assert _worst(got, e) < tol, (got.shape, _worst(got, e))
+
+
+@pytest.mark.parametrize("t", [256, 300])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_ssd_matches_the_token_recurrence_at_the_kernels_tile(t, dtype):
+    """Chunks of 128, heads of 64, state 128, two heads in one group: no
+    padding inside the op, at a T that is and is not whole chunks."""
+    check_ssd_at_the_tile(t, dtype)
+
+
+def test_a_mirrored_segment_does_not_run_the_chunk_kernel_again():
+    """Under the step's checkpoint policy the gradient of a node holds one
+    ``ssd_chunk_fwd`` call and one ``ssd_chunk_bwd`` call: the backward's
+    residuals are the op's inputs, so the recomputed segment has no use of
+    its own for the forward kernel's output. The states' kernel runs
+    again: the entering states are an input of the backward kernel."""
+    from mxnet_tpu import executor
+    args = _ssd_args(np.random.RandomState(6), 1, 32, (H, P, N, G))
+
+    def op(*a):
+        return lm.ssd(*a, heads=H, head_dim=P, state=N, groups=G, chunk=8)
+
+    def loss(*a):
+        return jnp.sum(jax.checkpoint(op, policy=executor._MIRROR_POLICY)(*a))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, tuple(range(7))))(*args))
+    assert text.count("name=ssd_chunk_fwd") == 1, text.count("ssd_chunk_fwd")
+    assert text.count("name=ssd_chunk_bwd") == 1
+    assert text.count("name=ssd_state_fwd") == 2
+    assert text.count("name=ssd_state_bwd") == 1
 
 
 def test_ssd_counts_its_chunks():

@@ -240,14 +240,19 @@ def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip, cell):
     assert grad.memory_analysis().temp_size_in_bytes < room * 2 ** 30
 
 
-def test_ssd_compiles_at_the_cell_shapes(one_chip):
+def test_ssd_compiles_at_the_cell_shapes(one_chip, monkeypatch):
     """``_contrib_SSD`` at ``nemotron3_nano.fit``'s shapes (one sequence of
     8,192 tokens, 64 heads of 64, state 128 in 8 groups, chunks of 128,
     bfloat16), forward and gradient under ``jax.checkpoint`` as the step
-    holds a block: no loop over tokens or chunks (the pass of the states is
+    holds a block: the Pallas kernels compile (one forward, one backward:
+    the recomputed forward is not needed for the kernel's own residuals),
+    no decay or score block of ``(chunk, chunk)`` a head a chunk exists
+    outside them, no loop over tokens or chunks (the pass of the states is
     the L-form over chunks, a product), and the temporaries of one mixer's
-    recurrence stay under 2 GB."""
+    recurrence stay under 0.7 GB."""
     from mxnet_tpu.ops import lm
+    from mxnet_tpu.pallas import ssd as kernels
+    monkeypatch.setattr(kernels, "_use_interpret", lambda: False)
     t, h, p, n, g = 8192, 64, 64, 128, 8
 
     def shape(*s, dtype=jnp.bfloat16):
@@ -266,5 +271,12 @@ def test_ssd_compiles_at_the_cell_shapes(one_chip):
 
     with jax.default_matmul_precision("default"):
         grad = _compile(step, *args)
-    assert " while(" not in grad.as_text()
-    assert grad.memory_analysis().temp_size_in_bytes < 2.0 * 2 ** 30
+    text = grad.as_text()
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    assert sum("ssd_chunk_fwd" in c for c in calls) == 1
+    assert sum("ssd_chunk_bwd" in c for c in calls) == 1
+    assert " while(" not in text
+    assert "f32[1,64,64,128,128]" not in text
+    assert "f32[1,64,8,8,128,128]" not in text
+    assert "f32[64,8,8,128,128]" not in text
+    assert grad.memory_analysis().temp_size_in_bytes < 0.7 * 2 ** 30
