@@ -4,8 +4,9 @@ gate, causal grouped-query attention with an optional window, the
 mixture-of-experts layer (gated, or ungated as ``_contrib_MoEUngated``)
 with its selection bias as an auxiliary state, the Mamba-2 mixer's parts
 (a causal depthwise convolution, the selective state-space recurrence in
-its chunked dual form, the gated group norm), and a token-level
-cross-entropy head that holds a small output.
+its chunked dual form, the gated group norm), Kimi Delta Attention's
+recurrence (a gated delta rule with a decay a channel, chunked), and a
+token-level cross-entropy head that holds a small output.
 
 Layout: activations are ``(batch, T, features)``; heads lie side by side
 in the feature axis (``heads * head_dim``), as ``FullyConnected`` with
@@ -87,9 +88,12 @@ def causal_attention(query, key, value, num_heads=1, num_kv_heads=0,
     ``num_kv_heads`` (default ``num_heads``) K/V heads are shared by
     ``num_heads / num_kv_heads`` query heads each; position i sees j <= i
     and, with ``window``, only i - j < window (itself counted); scores
-    ``q.k / sqrt(head_dim)``, softmax in float32. Forward and backward are
-    the Pallas kernels of ``pallas.flash_attention`` (interpreted off the
-    TPU).
+    ``q.k / sqrt(head_dim)``, softmax in float32. Queries and keys share
+    ``head_dim``; the values' width a head is their own (``value``'s
+    features over ``num_kv_heads``: latent attention's 128 beside keys of
+    192) and the result is (batch, T, heads * value width). Forward and
+    backward are the Pallas kernels of ``pallas.flash_attention``
+    (interpreted off the TPU).
 
     In a mirrored graph (``__mirror_stage__`` segments, or
     ``MXNET_BACKWARD_DO_MIRROR``) every node of this op holds its output
@@ -101,15 +105,14 @@ def causal_attention(query, key, value, num_heads=1, num_kv_heads=0,
     input."""
     from ..pallas.flash_attention import flash_attention
     hq, hkv = int(num_heads), int(num_kv_heads) or int(num_heads)
-    b, t, f = query.shape
-    d = f // hq
+    b, t, _ = query.shape
 
     def heads(x, h):
-        return x.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+        return x.reshape(b, t, h, -1).transpose(0, 2, 1, 3)
 
     o = flash_attention(heads(query, hq), heads(key, hkv), heads(value, hkv),
                         True, None, None, None, int(window) or None)
-    return o.transpose(0, 2, 1, 3).reshape(b, t, f)
+    return o.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
 
 @register("_contrib_MoE", nin=7, nout=2,
@@ -238,20 +241,22 @@ def _publish_load(delta):
 
 @register("_contrib_CausalConv1D", nin=3,
           arg_names=["data", "weight", "bias"],
-          defaults={"kernel": 4, "act_type": "silu"})
-def causal_conv1d(data, weight, bias, kernel=4, act_type="silu"):
+          defaults={"kernel": 4, "act_type": "silu", "no_bias": False})
+def causal_conv1d(data, weight, bias=None, kernel=4, act_type="silu",
+                  no_bias=False):
     """A causal depthwise convolution along T of ``data`` (batch, T,
     channels): ``out[t] = bias + sum_k weight[:, k] * data[t - (kernel -
     1) + k]``, positions before the first counted as nought; ``weight``
-    (channels, kernel), ``bias`` (channels,). ``act_type`` ``"silu"``
-    applies SiLU, ``None`` nothing. Sums in float32, the result in
-    ``data``'s type. Written as ``kernel`` shifted products: one pass over
-    the data for the compiler, where a grouped convolution of one channel
-    a group is ``channels`` convolutions."""
+    (channels, kernel), ``bias`` (channels,; none with ``no_bias``).
+    ``act_type`` ``"silu"`` applies SiLU, ``None`` nothing. Sums in
+    float32, the result in ``data``'s type. Written as ``kernel`` shifted
+    products: one pass over the data for the compiler, where a grouped
+    convolution of one channel a group is ``channels`` convolutions."""
     k, t = int(kernel), data.shape[1]
     x = jnp.pad(data.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
     w = weight.astype(_F32)
-    y = bias.astype(_F32) + sum(x[:, i:i + t] * w[:, i] for i in range(k))
+    y = 0.0 if bias is None or no_bias else bias.astype(_F32)
+    y = y + sum(x[:, i:i + t] * w[:, i] for i in range(k))
     if act_type == "silu":
         y = jax.nn.silu(y)
     elif act_type is not None:
@@ -261,18 +266,31 @@ def causal_conv1d(data, weight, bias, kernel=4, act_type="silu"):
 
 @register("_contrib_GatedRMSNorm", nin=3,
           arg_names=["data", "gate", "gamma"],
-          defaults={"eps": 1e-5, "group_size": 0})
-def gated_rms_norm(data, gate, gamma, eps=1e-5, group_size=0):
+          defaults={"eps": 1e-5, "group_size": 0, "gate_act": "silu",
+                    "norm_first": False})
+def gated_rms_norm(data, gate, gamma, eps=1e-5, group_size=0,
+                   gate_act="silu", norm_first=False):
     """``RMSNorm(data * silu(gate)) * gamma``: the statistics over each run
     of ``group_size`` features (0: all of them), ``gamma`` one scale a
     feature (Mamba-2's gated norm, the gate applied before the norm).
-    Float32 throughout, the result in ``data``'s type."""
+    With ``norm_first`` the norm comes first and the gate after it,
+    ``RMSNorm(data) * gamma * act(gate)``, and ``gamma`` is one scale a
+    feature *of a group*, (group_size,), shared by the groups (Kimi Delta
+    Attention's output norm: a head is a group). ``gate_act`` is ``"silu"``
+    or ``"sigmoid"``. Float32 throughout, the result in ``data``'s type."""
     shape = data.shape
     g = int(group_size) or shape[-1]
-    x = data.astype(_F32) * jax.nn.silu(gate.astype(_F32))
+    act = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}[gate_act]
+    x, z = data.astype(_F32), act(gate.astype(_F32))
+    if not norm_first:
+        x = x * z
     x = x.reshape(shape[:-1] + (shape[-1] // g, g))
     r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return ((x * r).reshape(shape) * gamma.astype(_F32)).astype(data.dtype)
+    if norm_first:
+        y = (x * r * gamma.astype(_F32)).reshape(shape) * z
+    else:
+        y = (x * r).reshape(shape) * gamma.astype(_F32)
+    return y.astype(data.dtype)
 
 
 def _decay_matrix(to, start, strict=False):
@@ -390,6 +408,287 @@ def _ssd_count_steps(out_shape, params, steps):
         "ssm.chunks_run", steps * b * -(-t // int(params.get("chunk", 128))))
 
 
+@register("_contrib_KDA", nin=7,
+          arg_names=["query", "key", "value", "gate", "beta", "A_log",
+                     "dt_bias"],
+          defaults={"heads": 1, "chunk": 64, "sub": 16})
+def kda(query, key, value, gate, beta, A_log, dt_bias, heads=1, chunk=64,
+        sub=16):
+    """Kimi Delta Attention's recurrence (Kimi Linear, arXiv:2510.26692): a
+    gated delta rule whose decay is a channel's, per head h of ``heads``
+    with keys of ``dk`` and values of ``dv`` features::
+
+        q_t <- q_t / |q_t| / sqrt(dk)     k_t <- k_t / |k_t|   (L2, a head)
+        g_t = -exp(A_log) softplus(gate_t + dt_bias)     alpha_t = exp(g_t)
+        b_t = sigmoid(beta_t)
+        S_t = (I - b_t k_t k_t^T) Diag(alpha_t) S_{t-1} + b_t k_t v_t^T
+        o_t = S_t^T q_t                              (S: dk x dv, from 0)
+
+    over ``query``, ``key``, ``gate`` (batch, T, heads * dk), ``value``
+    (batch, T, heads * dv), ``beta`` (batch, T, heads); ``A_log`` (heads,),
+    ``dt_bias`` (heads * dk,); ``|x|`` is ``sqrt(sum(x^2) + 1e-6)``. Computed
+    in the chunked form, never as a scan over T. With chunks of ``chunk``
+    positions and ``G`` the running sum of ``g`` inside a chunk:
+
+    - ``kda/gate``: the norms, ``g``, ``b``, ``G`` (elementwise, float32);
+    - ``kda/intra``: the chunk's own blocks ``Akk[r, i] = sum_c k_r k_i
+      exp(G_r - G_i)`` (``i < r``) and ``Aqk`` (``q_r`` for ``k_r``, ``i <=
+      r``). No ``exp(-G)`` is ever formed (a fast head's would overflow
+      over a chunk): inside a sub-block of ``sub`` positions the
+      exponent is the two positions' own difference, masked before the
+      exponential; between sub-blocks it is split at the later one's first
+      position n into ``exp(G_r - G_n)`` and ``exp(G_n - G_i)``, both <= 1,
+      and the sum over channels is a product. Then ``T = (I + Diag(b)
+      Akk)^-1 Diag(b)`` by doubling (``Akk`` is strictly lower, so ``(I -
+      A)(I + A^2)(I + A^4)...`` ends with ``A^(chunk/2)``), ``W = T (K
+      e^G)``, ``U = T V``;
+    - ``kda/state``: a loop over the chunks carries the state: ``U~ = U - W
+      S``, ``S' = Diag(e^(G_last)) S + (K e^(G_last - G))^T U~``; it hands
+      on each chunk's entering state and ``U~``;
+    - ``kda/out``: ``O = (Q e^G) S + tril(Aqk) U~``.
+
+    ``g``, ``G``, ``b``, the blocks, ``T``, ``U``, ``U~`` and the states are
+    float32; every product's operands are of ``query``'s type (``T``, ``K
+    e^..``, ``Q e^..``, the entering state and ``U~`` rounded to it) with a
+    float32 accumulator, but the doubling, which is float32 at full
+    precision. A T that is no multiple of ``chunk`` is padded with positions
+    that change no state (``g`` 0, ``b`` 0). The chunks a step computes are
+    fixed by the shapes: the counters ``kda.steps`` and ``kda.chunks_run``
+    are counted on the host (``_kda_count_steps``)."""
+    h = int(heads)
+    if query.shape[-1] % h or value.shape[-1] % h \
+            or key.shape != query.shape or gate.shape != query.shape:
+        raise ValueError(
+            "query %s, key %s and gate %s are not alike, or not %d heads "
+            "wide, or value %s is not" % (query.shape, key.shape, gate.shape,
+                                          h, value.shape))
+    if int(chunk) % int(sub):
+        raise ValueError("chunk %d is no multiple of sub %d" % (chunk, sub))
+    return _kda(query, key, value, gate, beta, A_log, dt_bias, h, int(chunk),
+                int(sub))
+
+
+def _l2(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+#: chunks whose blocks ``_kda`` computes at a time
+_KDA_SLAB = 16
+
+
+def _pair_decay(to, start, keep):
+    """``exp(to[.., r, None, :] - start[.., None, i, :])`` where ``keep[r,
+    i]``, 0 elsewhere: the mask goes on the exponent, so what is masked may
+    overflow."""
+    return jnp.exp(jnp.where(keep[..., None], to[..., :, None, :]
+                             - start[..., None, :, :], -jnp.inf))
+
+
+@jax.custom_vjp
+def _kda_own(rs, cs, gs):
+    """A sub-block's own part: ``B[r, i] = sum_c rs_r[c] cs_i[c] exp(gs_r[c]
+    - gs_i[c])`` for ``i <= r``, the exponent the positions' own
+    difference: ``rs``, ``cs``, ``gs`` (..., sub, dk) -> (..., sub, sub).
+    The pull-back makes the exponentials again, once for the rows' sums and
+    once, laid out the other way round, for the columns': each is then one
+    pass with its sum inside, and the (sub, sub, dk) block is never
+    stored."""
+    sub = gs.shape[-2]
+    e = _pair_decay(gs, gs, jnp.tril(jnp.ones((sub, sub), bool)))
+    return jnp.sum(rs[..., :, None, :] * cs[..., None, :, :] * e, axis=-1)
+
+
+def _kda_own_bwd(res, g):
+    rs, cs, gs = res
+    sub = gs.shape[-2]
+    below = jnp.tril(jnp.ones((sub, sub), bool), -1)
+    # the diagonal's exponent is 0 whatever gs is: it is taken apart, so
+    # that what it adds to the gradient of gs is nought exactly
+    on = jnp.sum(g * jnp.eye(sub, dtype=g.dtype), axis=-1, keepdims=True)
+    # sum_{i < r} g[r, i] cs[i] e[r, i]
+    drs = jnp.sum(g[..., None] * cs[..., None, :, :]
+                  * _pair_decay(gs, gs, below), axis=-2)
+    # sum_{r > i} g[r, i] rs[r] e[r, i], on (i, r, c): the exponent is
+    # -(gs_i - gs_r)
+    dcs = jnp.sum(jnp.swapaxes(g, -1, -2)[..., None] * rs[..., None, :, :]
+                  * _pair_decay(-gs, -gs, below.T), axis=-2)
+    return drs + on * cs, dcs + on * rs, rs * drs - cs * dcs
+
+
+_kda_own.defvjp(lambda rs, cs, gs: (_kda_own(rs, cs, gs), (rs, cs, gs)),
+                _kda_own_bwd)
+
+
+def _kda_blocks(row, col, G, sub, dtype):
+    """``B[r, i] = sum_c row_r[c] col_i[c] exp(G_r[c] - G_i[c])`` for ``i <=
+    r`` inside each chunk, 0 above the diagonal: ``row``, ``col``, ``G``
+    (..., chunk, dk) float32, the result (..., chunk, chunk) float32; the
+    products between sub-blocks take operands of ``dtype``."""
+    lead, (q, dk) = G.shape[:-2], G.shape[-2:]
+    m = q // sub
+
+    def split(x):
+        return x.reshape(lead + (m, sub, dk))
+
+    rs, cs, gs = split(row), split(col), split(G)
+    own = _kda_own(rs, cs, gs)
+    if m == 1:
+        return own.reshape(lead + (q, q))
+    # between sub-blocks I > J: split at n, block I's first position
+    gn = gs[..., :, :1, :]                                  # (.., I, 1, dk)
+    left = (rs * jnp.exp(gs - gn)).astype(dtype)            # (.., I, r, dk)
+    earlier = jnp.tril(jnp.ones((m, m), bool), -1)[:, :, None, None]
+    right = (cs[..., None, :, :, :] * jnp.exp(jnp.where(
+        earlier, gn[..., :, None, :, :] - gs[..., None, :, :, :], -jnp.inf))
+        ).astype(dtype)                                     # (.., I, J, i, dk)
+    off = jnp.einsum("...Irc,...IJic->...IrJi", left, right,
+                     preferred_element_type=_F32)
+    full = off + own[..., :, :, None, :] * jnp.eye(m, dtype=_F32)[
+        :, None, :, None]
+    return full.reshape(lead + (q, q))
+
+
+def _mm_f32(x, y):
+    return jnp.matmul(x, y, precision=jax.lax.Precision.HIGHEST)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for strictly lower ``a`` (..., q, q) float32: ``a`` is
+    nilpotent, so the series ``sum (-a)^k`` is the finite product ``(I -
+    a)(I + a^2)(I + a^4)...``, two products a doubling. Its pull-back is
+    ``-inv^T g inv^T`` from the inverse alone: the doublings are not
+    kept."""
+    q = a.shape[-1]
+    y = -a
+    inv = jnp.eye(q, dtype=a.dtype) + y
+    span = 2
+    while span < q:
+        y = _mm_f32(y, y)
+        inv = inv + _mm_f32(inv, y)
+        span *= 2
+    return inv
+
+
+def _unit_lower_inverse_bwd(inv, g):
+    t = jnp.swapaxes(inv, -1, -2)
+    return (-_mm_f32(_mm_f32(t, g), t),)
+
+
+_unit_lower_inverse.defvjp(lambda a: (_unit_lower_inverse(a),) * 2,
+                           _unit_lower_inverse_bwd)
+
+
+def _mul(eq, x, y, dtype):
+    """A product whose operands are rounded to ``dtype`` and whose sum is
+    float32."""
+    return jnp.einsum(eq, x.astype(dtype), y.astype(dtype),
+                      preferred_element_type=_F32)
+
+
+def _kda_gate(query, key, value, gate, beta, A_log, dt_bias, h, q):
+    """``kda/gate``: ``(q, k, v, G, b)`` chunk-major, (chunks, batch, heads,
+    chunk, features): the slabs of ``kda/intra`` and the loop of
+    ``kda/state`` run down the first axis as it lies. ``q`` and ``k`` are
+    normed (and ``q`` scaled), ``G`` is the running sum of ``g`` inside a
+    chunk, ``b`` is ``sigmoid(beta)`` (.., chunk, 1); all float32 but
+    ``v``."""
+    b, t, _ = query.shape
+    dk, dv = query.shape[-1] // h, value.shape[-1] // h
+    pad = -t % q
+    n = (t + pad) // q
+
+    def chunks(x):      # (b, t, h, f) -> (n, b, h, q, f), padded with 0
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else x
+        return x.reshape(b, n, q, h, -1).transpose(1, 0, 3, 2, 4)
+
+    def heads(x):       # (b, t, h * f) -> (n, b, h, q, f) float32
+        return chunks(x.reshape(b, t, h, -1)).astype(_F32)
+
+    # the relayout moves the data's type, the float32 work comes after it
+    g = -jnp.exp(A_log.astype(_F32))[:, None, None] * jax.nn.softplus(
+        heads(gate) + dt_bias.astype(_F32).reshape(h, 1, dk))
+    bt = jax.nn.sigmoid(heads(beta))
+    if pad:     # g = 0 and b = 0: the state passes through
+        live = (jnp.arange(n * q) < t).reshape(n, 1, 1, q, 1)
+        g, bt = jnp.where(live, g, 0.0), jnp.where(live, bt, 0.0)
+    return (_l2(heads(query)) * dk ** -0.5, _l2(heads(key)),
+            chunks(value.reshape(b, t, h, dv)), jnp.cumsum(g, axis=3), bt)
+
+
+def _kda_intra(qn, kn, v, G, bt, sub, dtype):
+    """``kda/intra``: ``(Aqk, W, U)`` of every chunk."""
+    n, q = G.shape[0], G.shape[3]
+    # a few chunks at a time, each made again for the gradient: the
+    # exponentials inside the sub-blocks are sub x chunk x dk a chunk, 2 GB
+    # over 8,192 positions of 32 heads if they ever exist at once
+    slab = next(z for z in range(min(n, _KDA_SLAB), 0, -1) if n % z == 0)
+
+    @jax.checkpoint
+    def blocks(x):
+        q_, k_, g_ = x
+        return (_kda_blocks(k_, k_, g_, sub, dtype),
+                _kda_blocks(q_, k_, g_, sub, dtype))
+
+    akk, aqk = (a.reshape(G.shape[:3] + (q, q)) for a in jax.lax.map(
+        blocks, tuple(x.reshape((n // slab, slab) + x.shape[1:])
+                      for x in (qn, kn, G))))
+    strict = jnp.tril(jnp.ones((q, q), bool), -1)
+    inv = _unit_lower_inverse(jnp.where(strict, akk, 0.0) * bt)
+    tm = inv * jnp.swapaxes(bt, -1, -2)                     # T: (.., q, q)
+    return (aqk, _mul("...ri,...ic->...rc", tm, kn * jnp.exp(G), dtype),
+            _mul("...ri,...iv->...rv", tm, v, dtype))
+
+
+def _kda_state(kn, G, w, u, dtype):
+    """``kda/state``: the loop over the chunks; ``(S, U~)`` of every chunk,
+    ``S`` the state that enters it, both rounded to ``dtype``."""
+    last = G[..., -1:, :]                                # (n, b, h, 1, dk)
+    kp = (kn * jnp.exp(last - G)).astype(dtype)
+
+    def step(s, x):
+        w_, u_, kp_, keep = x
+        sb = s.astype(dtype)
+        ut = (u_ - _mul("bhrc,bhcv->bhrv", w_, sb, dtype)).astype(dtype)
+        return keep * s + _mul("bhrc,bhrv->bhcv", kp_, ut, dtype), (sb, ut)
+
+    zero = jnp.zeros(kn.shape[1:3] + (kn.shape[-1], u.shape[-1]), _F32)
+    return jax.lax.scan(step, zero, (
+        w.astype(dtype), u, kp, jnp.swapaxes(jnp.exp(last), -1, -2)))[1]
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))   # mxlint: disable=jit-site -- a body inside the caller's program (the fused step's card covers it), never a dispatch of its own
+def _kda(query, key, value, gate, beta, A_log, dt_bias, h, q, sub):
+    """``_contrib_KDA``'s body, jitted by itself so that a model's layers
+    lower once."""
+    b, t, _ = query.shape
+    dtype = query.dtype
+    with jax.named_scope("kda/gate"):
+        qn, kn, v, G, bt = _kda_gate(query, key, value, gate, beta, A_log,
+                                     dt_bias, h, q)
+    with jax.named_scope("kda/intra"):
+        aqk, w, u = _kda_intra(qn, kn, v, G, bt, sub, dtype)
+    with jax.named_scope("kda/state"):
+        s_in, ut = _kda_state(kn, G, w, u, dtype)
+    with jax.named_scope("kda/out"):
+        o = _mul("...rc,...cv->...rv", qn * jnp.exp(G), s_in, dtype) \
+            + _mul("...ri,...iv->...rv", aqk, ut, dtype)
+    o = o.astype(dtype).transpose(1, 0, 3, 2, 4).reshape(b, -1, v.shape[2]
+                                                         * v.shape[-1])
+    return o[:, :t]
+
+
+def _kda_count_steps(out_shape, params, steps):
+    """``steps`` training steps of one recurrence into the telemetry
+    counters, as ``_ssd_count_steps`` (the names are written out: the lint
+    holds ``telemetry.COUNTERS`` against the literal calls)."""
+    from .. import telemetry
+    b, t = out_shape[:2]
+    telemetry.counter_inc("kda.steps", steps)
+    telemetry.counter_inc(
+        "kda.chunks_run", steps * b * -(-t // int(params.get("chunk", 64))))
+
+
 @register("_contrib_TokenCrossEntropy", nin=3,
           arg_names=["data", "weight", "label"],
           defaults={"num_classes": 0, "block": 2048})
@@ -439,13 +738,20 @@ def install():
     # result is of the data's type, and inference without shapes says so
     conv.param_dtype_infer = lambda in_types, params: {}
     gated = get_op("_contrib_GatedRMSNorm")
-    gated.param_shape_infer = lambda shapes, params: {2: (shapes[0][-1],)}
+    gated.param_shape_infer = lambda shapes, params: {
+        2: (int(params.get("group_size", 0)) or shapes[0][-1]
+            if params.get("norm_first") else shapes[0][-1],)}
     gated.param_dtype_infer = _f32_inputs(2)
     s = get_op("_contrib_SSD")
     s.param_shape_infer = lambda shapes, params: dict.fromkeys(
         (4, 5, 6), (int(params["heads"]),))
     s.param_dtype_infer = _f32_inputs(4, 5, 6)
     s.step_counters = _ssd_count_steps
+    k = get_op("_contrib_KDA")
+    k.param_shape_infer = lambda shapes, params: {
+        5: (int(params["heads"]),), 6: (shapes[0][-1],)}
+    k.param_dtype_infer = _f32_inputs(5, 6)
+    k.step_counters = _kda_count_steps
     ce = get_op("_contrib_TokenCrossEntropy")
     ce.param_shape_infer = _ce_shapes
     ce.param_dtype_infer = lambda in_types, params: {2: np.int32}

@@ -416,10 +416,12 @@ def _plan(q, k, causal, window, block_q, block_k):
                  bool(causal), int(window or 0), s_kv)
 
 
-def _q_major_specs(band, group, d):
+def _q_major_specs(band, group, d, dv):
     """Block specs of the grid (batch, query head, Q block, K/V step): a Q
-    block's rows, its per-row statistics, and the step's K/V block (the
-    group's K/V head, the index clamped into the band)."""
+    block's rows ``d`` wide (queries) and ``dv`` wide (the output and its
+    cotangent), its per-row statistics, and the step's K/V block ``d``
+    (keys) and ``dv`` (values) wide (the group's K/V head, the index
+    clamped into the band)."""
     def kv_map(b_, h, i, j):
         return (b_, h // group,
                 jnp.minimum(band.k_lo(i) + j, band.k_hi(i)), 0)
@@ -428,30 +430,35 @@ def _q_major_specs(band, group, d):
         return (b_, h, i, 0)
 
     return (pl.BlockSpec((1, 1, band.bq, d), q_map),
+            pl.BlockSpec((1, 1, band.bq, dv), q_map),
             pl.BlockSpec((1, 1, band.bq, 1), q_map),
-            pl.BlockSpec((1, 1, band.bk, d), kv_map))
+            pl.BlockSpec((1, 1, band.bk, d), kv_map),
+            pl.BlockSpec((1, 1, band.bk, dv), kv_map))
 
 
 def _forward(q, k, v, band, scale, interpret):
-    """(out, lse) on padded inputs: ``lse`` is (B, Hq, Sq', 1) float32."""
+    """(out, lse) on padded inputs: ``out`` is (B, Hq, Sq', Dv), ``lse``
+    (B, Hq, Sq', 1) float32."""
     b, hq, s_q, d = q.shape
+    dv = v.shape[-1]
     bq, bk = band.bq, band.bk
-    row, stat, kv = _q_major_specs(band, hq // k.shape[1], d)
+    row, orow, stat, kv, vv = _q_major_specs(band, hq // k.shape[1], d, dv)
     steps = band.n_q * band.k_steps
+    out = jax.ShapeDtypeStruct((b, hq, s_q, dv), q.dtype)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, band=band, scale=scale),
         grid=(b, hq, band.n_q, band.k_steps),
-        in_specs=[row, kv, kv], out_specs=[row, stat],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, hq, s_q, 1), jnp.float32)],
+        in_specs=[row, kv, vv], out_specs=[orow, stat],
+        out_shape=[out, jax.ShapeDtypeStruct((b, hq, s_q, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
+                        pltpu.VMEM((bq, dv), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary"),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * hq * steps * bq * bk * d,
-            bytes_accessed=q.dtype.itemsize * (2 * q.size + k.size + v.size),
+            flops=2 * b * hq * steps * bq * bk * (d + dv),
+            bytes_accessed=q.dtype.itemsize * (q.size + math.prod(out.shape)
+                                               + k.size + v.size),
             transcendentals=b * hq * steps * bq * bk),
         name="flash_attention_fwd", interpret=interpret,
     )(q, k, v)
@@ -459,14 +466,14 @@ def _forward(q, k, v, band, scale, interpret):
 
 def _backward(q, k, v, g, lse, delta, band, scale, interpret):
     b, hq, s_q, d = q.shape
-    hkv = k.shape[1]
+    hkv, d_v = k.shape[1], v.shape[-1]
     group = hq // hkv
     bq, bk = band.bq, band.bk
-    row, stat, kv = _q_major_specs(band, group, d)
+    row, orow, stat, kv, vv = _q_major_specs(band, group, d, d_v)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, band=band, scale=scale),
         grid=(b, hq, band.n_q, band.k_steps),
-        in_specs=[row, kv, kv, row, stat, stat], out_specs=row,
+        in_specs=[row, kv, vv, orow, stat, stat], out_specs=row,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel",
@@ -479,18 +486,23 @@ def _backward(q, k, v, g, lse, delta, band, scale, interpret):
                 jnp.minimum(band.q_lo(j) + t % band.q_steps, band.q_hi(j)),
                 0)
 
+    def kv_map(b_, h, j, t):
+        return (b_, h, j, 0)
+
     qrow = pl.BlockSpec((1, 1, bq, d), q_map)
+    grow = pl.BlockSpec((1, 1, bq, d_v), q_map)
     qstat = pl.BlockSpec((1, 1, bq, 1), q_map)
-    kvrow = pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, t: (b_, h, j, 0))
+    krow = pl.BlockSpec((1, 1, bk, d), kv_map)
+    vrow = pl.BlockSpec((1, 1, bk, d_v), kv_map)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, band=band, scale=scale, group=group),
         grid=(b, hkv, band.n_k, group * band.q_steps),
-        in_specs=[qrow, kvrow, kvrow, qrow, qstat, qstat],
-        out_specs=[kvrow, kvrow],
+        in_specs=[qrow, krow, vrow, grow, qstat, qstat],
+        out_specs=[krow, vrow],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, d_v), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary"),
         name="flash_attention_dkv", interpret=interpret,
@@ -501,9 +513,10 @@ def _backward(q, k, v, g, lse, delta, band, scale, interpret):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     interpret=None, window=None, block_k=None):
-    """Exact attention. ``q``: (B, Hq, Sq, D); ``k``, ``v``: (B, Hkv, Skv,
-    D) with Hq a multiple of Hkv (query head h reads K/V head
-    h // (Hq / Hkv)). ``causal``: position i sees j <= i; ``window`` (with
+    """Exact attention. ``q``: (B, Hq, Sq, D); ``k``: (B, Hkv, Skv, D);
+    ``v``: (B, Hkv, Skv, Dv), ``Dv`` = ``D`` or not; the result is (B, Hq,
+    Sq, Dv). Hq is a multiple of Hkv (query head h reads K/V head
+    h // (Hq / Hkv)); the default ``scale`` is ``1 / sqrt(D)``. ``causal``: position i sees j <= i; ``window`` (with
     ``causal``): and only i - j < window, the position itself counted.
 
     Differentiable; forward and backward are Pallas kernels on the TPU
@@ -514,7 +527,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     (``jax.ad_checkpoint.checkpoint_name``: ``flash_attention_out``,
     ``flash_attention_lse``), so a ``jax.checkpoint`` whose policy saves
     those names (a mirrored segment, ``executor.MIRROR_KEEPS``) holds them
-    between forward and backward, (B, Hq, Sq, D) in ``q``'s type and (B,
+    between forward and backward, (B, Hq, Sq, Dv) in ``q``'s type and (B,
     Hq, Sq) float32, and does not run the kernel again; ``q``, ``k``,
     ``v`` it makes again. Under any other checkpoint, and outside one, a name is
     the identity.

@@ -200,6 +200,8 @@ COUNTERS = (
     # the state-space recurrence (ops/lm.py ``_contrib_SSD``), summed over
     # training steps and mixers: steps, chunks computed
     "ssm.steps", "ssm.chunks_run",
+    # Kimi Delta Attention's recurrence (``_contrib_KDA``), the same pair
+    "kda.steps", "kda.chunks_run",
 )
 
 

@@ -169,18 +169,27 @@ def test_token_cross_entropy():
     (4, 4, 24, 24, False, None, 8, 8), (4, 2, 40, 40, True, None, 8, 16),
     (8, 2, 64, 64, True, 20, 16, 8), (4, 1, 37, 37, True, 9, 8, 8),
     (2, 2, 33, 65, False, None, 32, 32), (2, 1, 48, 48, True, 48, 16, 16),
-    (2, 2, 19, 19, True, 1, 8, None)],
-    ids=lambda c: "hq%d_hkv%d_s%dx%d_c%d_w%s_b%sx%s" % c)
+    (2, 2, 19, 19, True, 1, 8, None),
+    # ..., keys' width, values' width: latent attention's 192 / 128 in
+    # small, S no multiple of the block
+    (4, 4, 37, 37, True, None, 8, 8, 24, 16),
+    (4, 2, 40, 40, True, None, 8, 16, 24, 16),
+    (2, 2, 33, 33, True, None, 32, None, 16, 24),
+    (4, 1, 37, 37, True, 9, 8, 8, 24, 16),
+    (2, 2, 33, 65, False, None, 32, 32, 12, 8)],
+    ids=lambda c: "hq%d_hkv%d_s%dx%d_c%d_w%s_b%sx%s" % c[:8]
+    + ("_d%dx%d" % c[8:] if c[8:] else ""))
 def test_flash_kernel_matches_plain(case):
-    hq, hkv, s_q, s_kv, causal, window, bq, bk = case
+    hq, hkv, s_q, s_kv, causal, window, bq, bk = case[:8]
+    d, dv = case[8:] or (16, 16)
     rs = np.random.RandomState(6)
-    q, k, v = (_rand(rs, 2, hq, s_q, 16), _rand(rs, 2, hkv, s_kv, 16),
-               _rand(rs, 2, hkv, s_kv, 16))
+    q, k, v = (_rand(rs, 2, hq, s_q, d), _rand(rs, 2, hkv, s_kv, d),
+               _rand(rs, 2, hkv, s_kv, dv))
 
     def plain(q, k, v):
         g = hq // hkv
         kk, vv = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, kk) / 4.0
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, kk) / np.sqrt(d)
         i, j = jnp.arange(s_q)[:, None], jnp.arange(s_kv)[None]
         m = jnp.ones((s_q, s_kv), bool)
         if causal:

@@ -90,23 +90,29 @@ def test_flash_attention_gradient_compiles(one_chip):
     assert "tpu_custom_call" in grad.as_text()
 
 
-@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window"])
-def test_flash_attention_compiles_at_8k(one_chip, window):
+@pytest.mark.parametrize("window,kv_heads,d,dv", [
+    (None, 4, 128, 128), (2048, 4, 128, 128), (None, 32, 192, 128)],
+    ids=["full", "window", "latent_192x128"])
+def test_flash_attention_compiles_at_8k(one_chip, window, kv_heads, d, dv):
     """The plain entry tiles over K/V: at S=8192, D=128 with 32 query
     heads on 4 K/V heads (the language-model cell's attention) the causal
     forward and the gradient compile, with and without the window. (Until
     the PR that tiled it, the whole local K/V block and a (block_q, S_kv)
-    score tile sat in VMEM and this shape was refused.)"""
-    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+    score tile sat in VMEM and this shape was refused.) And latent
+    attention's shape: 32 heads with keys of 192, one and a half lane
+    tiles, taken as they are, and values of 128."""
+    q = jax.ShapeDtypeStruct((1, 32, 8192, d), jnp.bfloat16,
                              sharding=one_chip)
-    k = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+    k = jax.ShapeDtypeStruct((1, kv_heads, 8192, d), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, kv_heads, 8192, dv), jnp.bfloat16,
                              sharding=one_chip)
 
     def attn(q, k, v):
         return flash_attention(q, k, v, True, None, None, False, window)
 
-    assert "tpu_custom_call" in _compile(attn, q, k, k).as_text()
-    grad = _compile(jax.grad(_sum32(attn), argnums=(0, 1, 2)), q, k, k)
+    assert "tpu_custom_call" in _compile(attn, q, k, v).as_text()
+    grad = _compile(jax.grad(_sum32(attn), argnums=(0, 1, 2)), q, k, v)
     assert grad.as_text().count("tpu_custom_call") >= 3
 
 
@@ -280,3 +286,39 @@ def test_ssd_compiles_at_the_cell_shapes(one_chip, monkeypatch):
     assert "f32[1,64,8,8,128,128]" not in text
     assert "f32[64,8,8,128,128]" not in text
     assert grad.memory_analysis().temp_size_in_bytes < 0.7 * 2 ** 30
+
+
+def test_kda_compiles_at_the_cell_shapes(one_chip):
+    """``_contrib_KDA`` at ``kimi_linear.fit``'s shapes (one sequence of
+    8,192 tokens, 32 heads of 128 for keys and values, chunks of 64 in
+    sub-blocks of 16, bfloat16), forward and gradient under
+    ``jax.checkpoint`` as the step holds a layer: the sub-blocks'
+    exponentials (2 GiB over the sequence) exist a slab of 16 chunks at a
+    time, the inverse's doublings are not kept for the gradient, and the
+    temporaries of one layer's recurrence stay under 3.3 GiB (3.02 when
+    this was written; 4.77 with the exponentials whole and the doublings
+    kept: the step then did not fit the chip)."""
+    from mxnet_tpu.ops import lm
+    t, h, d = 8192, 32, 128
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    args = (shape(1, t, h * d),) * 4 + (
+        shape(1, t, h), shape(h, dtype=jnp.float32),
+        shape(h * d, dtype=jnp.float32))
+
+    def kda(*a):
+        return lm.kda(*a, heads=h, chunk=64, sub=16)
+
+    def step(*a):
+        out, pull = jax.vjp(jax.checkpoint(kda), *a)
+        return out, pull(jnp.cos(out))
+
+    with jax.default_matmul_precision("default"):
+        grad = _compile(step, *args)
+    text = grad.as_text()
+    assert "f32[1,32,128,4,16,16,128]" not in text
+    assert "f32[128,1,32,4,16,16,128]" not in text
+    assert " while(" in text
+    assert grad.memory_analysis().temp_size_in_bytes < 3.3 * 2 ** 30
