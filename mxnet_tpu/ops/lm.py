@@ -431,17 +431,22 @@ def kda(query, key, value, gate, beta, A_log, dt_bias, heads=1, chunk=64,
     positions and ``G`` the running sum of ``g`` inside a chunk:
 
     - ``kda/gate``: the norms, ``g``, ``b``, ``G`` (elementwise, float32);
-    - ``kda/intra``: the chunk's own blocks ``Akk[r, i] = sum_c k_r k_i
-      exp(G_r - G_i)`` (``i < r``) and ``Aqk`` (``q_r`` for ``k_r``, ``i <=
-      r``). No ``exp(-G)`` is ever formed (a fast head's would overflow
-      over a chunk): inside a sub-block of ``sub`` positions the
-      exponent is the two positions' own difference, masked before the
+    - ``kda/intra`` (``pallas.kda``'s ``kda_intra``: ``kda_intra_fwd`` /
+      ``kda_intra_bwd``, interpreted off the TPU): the chunk's own blocks
+      ``Akk[r, i] = sum_c k_r k_i exp(G_r - G_i)`` (``i < r``) and ``Aqk``
+      (``q_r`` for ``k_r``, ``i <= r``), ``T = (I + Diag(b) Akk)^-1
+      Diag(b)``, ``W = T (K e^G)``, ``U = T V``, one program a chunk and
+      eight heads, and nothing of them but ``Aqk``, ``W`` and ``U`` leaves
+      VMEM. No ``exp(-G)`` is ever formed (a fast head's would overflow
+      over a chunk): inside a sub-block of ``sub`` positions the exponent
+      is the two positions' own difference, masked before the
       exponential; between sub-blocks it is split at the later one's first
-      position n into ``exp(G_r - G_n)`` and ``exp(G_n - G_i)``, both <= 1,
-      and the sum over channels is a product. Then ``T = (I + Diag(b)
-      Akk)^-1 Diag(b)`` by doubling (``Akk`` is strictly lower, so ``(I -
-      A)(I + A^2)(I + A^4)...`` ends with ``A^(chunk/2)``), ``W = T (K
-      e^G)``, ``U = T V``;
+      position n into ``exp(G_r - G_n)`` and ``exp(G_n - G_i)``, both <=
+      1, and the sum over channels is a product. The inverse is the
+      doubling ``(I - A)(I + A^2)(I + A^4)...`` (``Akk`` is strictly lower,
+      so it ends with ``A^(chunk/2)``), float32 at full precision; the
+      backward kernel makes the blocks and the inverse again from the
+      op's inputs;
     - ``kda/state``: a loop over the chunks carries the state: ``U~ = U - W
       S``, ``S' = Diag(e^(G_last)) S + (K e^(G_last - G))^T U~``; it hands
       on each chunk's entering state and ``U~``;
@@ -451,10 +456,13 @@ def kda(query, key, value, gate, beta, A_log, dt_bias, heads=1, chunk=64,
     float32; every product's operands are of ``query``'s type (``T``, ``K
     e^..``, ``Q e^..``, the entering state and ``U~`` rounded to it) with a
     float32 accumulator, but the doubling, which is float32 at full
-    precision. A T that is no multiple of ``chunk`` is padded with positions
-    that change no state (``g`` 0, ``b`` 0). The chunks a step computes are
-    fixed by the shapes: the counters ``kda.steps`` and ``kda.chunks_run``
-    are counted on the host (``_kda_count_steps``)."""
+    precision; ``Aqk`` and ``W`` leave the kernel in ``query``'s type. A T
+    that is no multiple of ``chunk`` is padded with positions that change
+    no state (``g`` 0, ``b`` 0). On the chip ``chunk`` and ``sub`` have
+    to be multiples of 8. The
+    chunks a step computes are fixed by the shapes: the counters
+    ``kda.steps`` and ``kda.chunks_run`` are counted on the host
+    (``_kda_count_steps``)."""
     h = int(heads)
     if query.shape[-1] % h or value.shape[-1] % h \
             or key.shape != query.shape or gate.shape != query.shape:
@@ -472,113 +480,6 @@ def _l2(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-#: chunks whose blocks ``_kda`` computes at a time
-_KDA_SLAB = 16
-
-
-def _pair_decay(to, start, keep):
-    """``exp(to[.., r, None, :] - start[.., None, i, :])`` where ``keep[r,
-    i]``, 0 elsewhere: the mask goes on the exponent, so what is masked may
-    overflow."""
-    return jnp.exp(jnp.where(keep[..., None], to[..., :, None, :]
-                             - start[..., None, :, :], -jnp.inf))
-
-
-@jax.custom_vjp
-def _kda_own(rs, cs, gs):
-    """A sub-block's own part: ``B[r, i] = sum_c rs_r[c] cs_i[c] exp(gs_r[c]
-    - gs_i[c])`` for ``i <= r``, the exponent the positions' own
-    difference: ``rs``, ``cs``, ``gs`` (..., sub, dk) -> (..., sub, sub).
-    The pull-back makes the exponentials again, once for the rows' sums and
-    once, laid out the other way round, for the columns': each is then one
-    pass with its sum inside, and the (sub, sub, dk) block is never
-    stored."""
-    sub = gs.shape[-2]
-    e = _pair_decay(gs, gs, jnp.tril(jnp.ones((sub, sub), bool)))
-    return jnp.sum(rs[..., :, None, :] * cs[..., None, :, :] * e, axis=-1)
-
-
-def _kda_own_bwd(res, g):
-    rs, cs, gs = res
-    sub = gs.shape[-2]
-    below = jnp.tril(jnp.ones((sub, sub), bool), -1)
-    # the diagonal's exponent is 0 whatever gs is: it is taken apart, so
-    # that what it adds to the gradient of gs is nought exactly
-    on = jnp.sum(g * jnp.eye(sub, dtype=g.dtype), axis=-1, keepdims=True)
-    # sum_{i < r} g[r, i] cs[i] e[r, i]
-    drs = jnp.sum(g[..., None] * cs[..., None, :, :]
-                  * _pair_decay(gs, gs, below), axis=-2)
-    # sum_{r > i} g[r, i] rs[r] e[r, i], on (i, r, c): the exponent is
-    # -(gs_i - gs_r)
-    dcs = jnp.sum(jnp.swapaxes(g, -1, -2)[..., None] * rs[..., None, :, :]
-                  * _pair_decay(-gs, -gs, below.T), axis=-2)
-    return drs + on * cs, dcs + on * rs, rs * drs - cs * dcs
-
-
-_kda_own.defvjp(lambda rs, cs, gs: (_kda_own(rs, cs, gs), (rs, cs, gs)),
-                _kda_own_bwd)
-
-
-def _kda_blocks(row, col, G, sub, dtype):
-    """``B[r, i] = sum_c row_r[c] col_i[c] exp(G_r[c] - G_i[c])`` for ``i <=
-    r`` inside each chunk, 0 above the diagonal: ``row``, ``col``, ``G``
-    (..., chunk, dk) float32, the result (..., chunk, chunk) float32; the
-    products between sub-blocks take operands of ``dtype``."""
-    lead, (q, dk) = G.shape[:-2], G.shape[-2:]
-    m = q // sub
-
-    def split(x):
-        return x.reshape(lead + (m, sub, dk))
-
-    rs, cs, gs = split(row), split(col), split(G)
-    own = _kda_own(rs, cs, gs)
-    if m == 1:
-        return own.reshape(lead + (q, q))
-    # between sub-blocks I > J: split at n, block I's first position
-    gn = gs[..., :, :1, :]                                  # (.., I, 1, dk)
-    left = (rs * jnp.exp(gs - gn)).astype(dtype)            # (.., I, r, dk)
-    earlier = jnp.tril(jnp.ones((m, m), bool), -1)[:, :, None, None]
-    right = (cs[..., None, :, :, :] * jnp.exp(jnp.where(
-        earlier, gn[..., :, None, :, :] - gs[..., None, :, :, :], -jnp.inf))
-        ).astype(dtype)                                     # (.., I, J, i, dk)
-    off = jnp.einsum("...Irc,...IJic->...IrJi", left, right,
-                     preferred_element_type=_F32)
-    full = off + own[..., :, :, None, :] * jnp.eye(m, dtype=_F32)[
-        :, None, :, None]
-    return full.reshape(lead + (q, q))
-
-
-def _mm_f32(x, y):
-    return jnp.matmul(x, y, precision=jax.lax.Precision.HIGHEST)
-
-
-@jax.custom_vjp
-def _unit_lower_inverse(a):
-    """``(I + a)^-1`` for strictly lower ``a`` (..., q, q) float32: ``a`` is
-    nilpotent, so the series ``sum (-a)^k`` is the finite product ``(I -
-    a)(I + a^2)(I + a^4)...``, two products a doubling. Its pull-back is
-    ``-inv^T g inv^T`` from the inverse alone: the doublings are not
-    kept."""
-    q = a.shape[-1]
-    y = -a
-    inv = jnp.eye(q, dtype=a.dtype) + y
-    span = 2
-    while span < q:
-        y = _mm_f32(y, y)
-        inv = inv + _mm_f32(inv, y)
-        span *= 2
-    return inv
-
-
-def _unit_lower_inverse_bwd(inv, g):
-    t = jnp.swapaxes(inv, -1, -2)
-    return (-_mm_f32(_mm_f32(t, g), t),)
-
-
-_unit_lower_inverse.defvjp(lambda a: (_unit_lower_inverse(a),) * 2,
-                           _unit_lower_inverse_bwd)
-
-
 def _mul(eq, x, y, dtype):
     """A product whose operands are rounded to ``dtype`` and whose sum is
     float32."""
@@ -588,7 +489,7 @@ def _mul(eq, x, y, dtype):
 
 def _kda_gate(query, key, value, gate, beta, A_log, dt_bias, h, q):
     """``kda/gate``: ``(q, k, v, G, b)`` chunk-major, (chunks, batch, heads,
-    chunk, features): the slabs of ``kda/intra`` and the loop of
+    chunk, features): the kernels' grid of ``kda/intra`` and the loop of
     ``kda/state`` run down the first axis as it lies. ``q`` and ``k`` are
     normed (and ``q`` scaled), ``G`` is the running sum of ``g`` inside a
     chunk, ``b`` is ``sigmoid(beta)`` (.., chunk, 1); all float32 but
@@ -616,30 +517,6 @@ def _kda_gate(query, key, value, gate, beta, A_log, dt_bias, h, q):
             chunks(value.reshape(b, t, h, dv)), jnp.cumsum(g, axis=3), bt)
 
 
-def _kda_intra(qn, kn, v, G, bt, sub, dtype):
-    """``kda/intra``: ``(Aqk, W, U)`` of every chunk."""
-    n, q = G.shape[0], G.shape[3]
-    # a few chunks at a time, each made again for the gradient: the
-    # exponentials inside the sub-blocks are sub x chunk x dk a chunk, 2 GB
-    # over 8,192 positions of 32 heads if they ever exist at once
-    slab = next(z for z in range(min(n, _KDA_SLAB), 0, -1) if n % z == 0)
-
-    @jax.checkpoint
-    def blocks(x):
-        q_, k_, g_ = x
-        return (_kda_blocks(k_, k_, g_, sub, dtype),
-                _kda_blocks(q_, k_, g_, sub, dtype))
-
-    akk, aqk = (a.reshape(G.shape[:3] + (q, q)) for a in jax.lax.map(
-        blocks, tuple(x.reshape((n // slab, slab) + x.shape[1:])
-                      for x in (qn, kn, G))))
-    strict = jnp.tril(jnp.ones((q, q), bool), -1)
-    inv = _unit_lower_inverse(jnp.where(strict, akk, 0.0) * bt)
-    tm = inv * jnp.swapaxes(bt, -1, -2)                     # T: (.., q, q)
-    return (aqk, _mul("...ri,...ic->...rc", tm, kn * jnp.exp(G), dtype),
-            _mul("...ri,...iv->...rv", tm, v, dtype))
-
-
 def _kda_state(kn, G, w, u, dtype):
     """``kda/state``: the loop over the chunks; ``(S, U~)`` of every chunk,
     ``S`` the state that enters it, both rounded to ``dtype``."""
@@ -661,13 +538,14 @@ def _kda_state(kn, G, w, u, dtype):
 def _kda(query, key, value, gate, beta, A_log, dt_bias, h, q, sub):
     """``_contrib_KDA``'s body, jitted by itself so that a model's layers
     lower once."""
+    from ..pallas.kda import kda_intra
     b, t, _ = query.shape
     dtype = query.dtype
     with jax.named_scope("kda/gate"):
         qn, kn, v, G, bt = _kda_gate(query, key, value, gate, beta, A_log,
                                      dt_bias, h, q)
     with jax.named_scope("kda/intra"):
-        aqk, w, u = _kda_intra(qn, kn, v, G, bt, sub, dtype)
+        aqk, w, u = kda_intra(qn, kn, v, G, bt[..., 0], sub)
     with jax.named_scope("kda/state"):
         s_in, ut = _kda_state(kn, G, w, u, dtype)
     with jax.named_scope("kda/out"):

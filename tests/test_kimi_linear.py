@@ -74,10 +74,12 @@ def _check(op_fn, ref_fn, args, tol=2e-5):
 H, DK, DV = 3, 8, 6
 
 
-def _token_scan(q, k, v, gate, beta, a_log, dt_bias, per_head=False):
+def _token_scan(q, k, v, gate, beta, a_log, dt_bias, per_head=False,
+                dims=(H, DK, DV)):
     """The recurrence a token at a time, a sequence and a head at a time:
     the docstring of ``_contrib_KDA`` written out."""
     b, t, _ = q.shape
+    H, DK, DV = dims
 
     def l2(x):
         return x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
@@ -104,14 +106,16 @@ def _token_scan(q, k, v, gate, beta, a_log, dt_bias, per_head=False):
     return jax.vmap(per_sequence)(qn, kn, vv, g, bt).reshape(b, t, H * DV)
 
 
-def _kda_args(rs, batch, t, fast=2.0):
+def _kda_args(rs, batch, t, fast=2.0, dims=(H, DK, DV)):
     """Head 0 slow (A 1), head 1 fast: A 16 and a softplus argument about
     ``fast``, so a chunk's decays reach e^-2000 and ``exp(-G)`` alone would
-    overflow after three tokens."""
-    return (_rand(rs, batch, t, H * DK), _rand(rs, batch, t, H * DK),
-            _rand(rs, batch, t, H * DV), _rand(rs, batch, t, H * DK),
-            _rand(rs, batch, t, H), jnp.log(jnp.asarray([1.0, 16.0, 4.0])),
-            _rand(rs, H * DK) + fast)
+    overflow after three tokens (more heads: A 4 and on round the three)."""
+    h, dk, dv = dims
+    return (_rand(rs, batch, t, h * dk), _rand(rs, batch, t, h * dk),
+            _rand(rs, batch, t, h * dv), _rand(rs, batch, t, h * dk),
+            _rand(rs, batch, t, h),
+            jnp.log(jnp.asarray([1.0, 16.0, 4.0] * h)[:h]),
+            _rand(rs, h * dk) + fast)
 
 
 @pytest.mark.parametrize("t,chunk,sub", [(75, 64, 16), (75, 32, 16),
@@ -161,16 +165,124 @@ def test_kda_refuses_a_chunk_its_sub_blocks_do_not_divide():
         lm.kda(*args, heads=5, chunk=16, sub=8)
 
 
-def test_unit_lower_inverse():
+@pytest.mark.parametrize("t,sub,fast", [
+    (32, 16, 2.0), (27, 8, 2.0), (27, 8, 30.0)], ids=lambda v: str(v))
+def test_kda_intra_kernels_match_the_token_scan(t, sub, fast):
+    """The kernel pair (``pallas/kda.py``, interpreted) inside the op, at
+    chunks of 16: T whole chunks or not, one sub-block a chunk or two,
+    decays a float holds or not (a softplus of 30 under A = 16: e^-480 a
+    token). Forward and every input's gradient against the token scan;
+    the op's ``kda/intra`` is the two kernels and nothing else."""
+    args = _kda_args(np.random.RandomState(t + sub), 1, t, fast=fast)
+
+    def op(*a):
+        return lm.kda(*a, heads=H, chunk=16, sub=sub)
+
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(op(*a)),
+                                       tuple(range(7))))(*args))
+    assert text.count("name=kda_intra_fwd") == 1
+    assert text.count("name=kda_intra_bwd") == 1
+    _check(op, _token_scan, args, tol=5e-5)
+
+
+def test_the_kernels_inverse():
+    """The unit lower block's inverse as the kernels make it (doubling at
+    full precision) and its pull-back ``-inv^T g inv^T``, on the matrices
+    the deleted ``jax.numpy`` inverse was tested on."""
+    from mxnet_tpu.pallas import kda as kernels
     rs = np.random.RandomState(4)
     a = jnp.tril(_rand(rs, 3, 16, 16, scale=0.3), -1)
-    inv = lm._unit_lower_inverse(a)
-    _close(inv @ (jnp.eye(16) + a), jnp.broadcast_to(jnp.eye(16), a.shape),
-           1e-5)
     w = _rand(rs, 3, 16, 16)
-    got = jax.grad(lambda x: jnp.sum(lm._unit_lower_inverse(x) * w))(a)
-    want = jax.grad(lambda x: jnp.sum(jnp.linalg.inv(jnp.eye(16) + x) * w))(a)
-    _close(got, want, 1e-4)
+    for x, g, inv in zip(a, w, kernels._inverses(list(a))):
+        _close(inv @ (jnp.eye(16) + x), jnp.eye(16), 1e-5)
+        want = jax.grad(lambda y: jnp.sum(jnp.linalg.inv(jnp.eye(16) + y)
+                                          * g))(x)
+        _close(kernels._inverse_pullback(inv, g), want, 1e-4)
+
+
+def check_kda_at_the_tile(t, dtype, dims):
+    """``lm.kda`` at the kernels' tile (chunks of 64 in sub-blocks of 16)
+    against the float32 token scan, forward and every input's gradient:
+    to bfloat16's places (the products' operands rounded once each) in
+    bfloat16. What the chip's lane runs (``tests/tpu``)."""
+    h, dk, dv = dims
+    args = _kda_args(np.random.RandomState(7), 1, t, dims=dims)
+    args = tuple(x.astype(dtype) for x in args[:5]) + args[5:]
+    w = jnp.cos(jnp.arange(t * h * dv, dtype=jnp.float32)).reshape(1, t, -1)
+
+    def op(*a):
+        return lm.kda(*a, heads=h, chunk=64, sub=16)
+
+    def want(*a):
+        return _token_scan(*(x.astype(jnp.float32) for x in a), dims=dims)
+
+    out, pull = jax.vjp(op, *args)
+    with jax.default_matmul_precision("highest"):
+        exp, pull_exp = jax.vjp(want, *args)
+        grads = pull_exp(w)
+    assert out.dtype == dtype
+
+    def worst(got, e):
+        got, e = (np.asarray(v, np.float32) for v in (got, e))
+        return float(np.max(np.abs(got - e)) / np.max(np.abs(e)))
+
+    assert worst(out, exp) < 2e-2, worst(out, exp)
+    for got, e in zip(pull(w.astype(dtype)), grads):
+        assert bool(jnp.all(jnp.isfinite(got)))
+        assert worst(got, e) < 3e-2, (got.shape, worst(got, e))
+
+
+def test_a_mirrored_segment_runs_the_intra_kernel_where_needed():
+    """Under the step's checkpoint policy the gradient of a node holds two
+    ``kda_intra_fwd`` calls (the pull-back of ``kda/state`` and ``kda/out``
+    needs ``Aqk``, ``W`` and ``U``, so the recomputed segment makes them
+    again) and one ``kda_intra_bwd``; the only loops are the chunk loop of
+    ``kda/state`` and its pull-back, over every chunk, and the kernels'
+    over their heads: no slab of chunks is mapped and nothing inside the
+    node is checkpointed again."""
+    import re
+    from mxnet_tpu import executor
+    chunks = 32
+    args = _kda_args(np.random.RandomState(6), 1, chunks * 8)
+
+    def op(*a):
+        return lm.kda(*a, heads=H, chunk=8, sub=8)
+
+    def loss(*a):
+        return jnp.sum(jax.checkpoint(op, policy=executor._MIRROR_POLICY)(*a))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, tuple(range(7))))(*args))
+    assert text.count("name=kda_intra_fwd") == 2
+    assert text.count("name=kda_intra_bwd") == 1
+    # the loops: over the chunks, and inside the kernels over their heads
+    lengths = re.findall(r"length=(\d+)", text)
+    assert set(lengths) == {str(chunks), str(H)}, lengths
+    assert text.count("prevent_cse") == 1
+
+
+def test_no_other_cell_reaches_the_kda_kernels():
+    """``_contrib_KDA`` (and so ``pallas/kda.py``) is emitted by the
+    ``kimi_linear`` symbol alone: the three other cells' symbols hold no
+    such node, so their lowered steps are the parent's (checked against
+    the parent's text offline, PERF.md section 6, PR 37)."""
+    afmoe = _load("examples/language-model/symbols/afmoe.py", "afmoe_sym")
+    nemotron = _load("examples/language-model/symbols/nemotron_h.py",
+                     "nemotron_sym")
+    resnet = _load("examples/image-classification/symbols/resnet.py",
+                   "resnet_sym")
+    import test_lm_ops as lm_ops
+    import test_nemotron_h as nem
+
+    def ops(sym):
+        return {n["op"] for n in json.loads(sym.tojson())["nodes"]}
+
+    for sym in (afmoe.get_symbol(dtype="float32", **lm_ops.CONFIG),
+                nemotron.get_symbol(dtype="float32", **nem.CONFIG),
+                resnet.get_symbol(num_classes=10, num_layers=50,
+                                  image_shape="3,32,32")):
+        assert "_contrib_KDA" not in ops(sym)
+    assert "_contrib_KDA" in ops(kimi_linear.get_symbol(dtype="float32",
+                                                         **CONFIG))
 
 
 def test_kda_counts_its_chunks():

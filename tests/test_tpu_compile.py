@@ -288,17 +288,21 @@ def test_ssd_compiles_at_the_cell_shapes(one_chip, monkeypatch):
     assert grad.memory_analysis().temp_size_in_bytes < 0.7 * 2 ** 30
 
 
-def test_kda_compiles_at_the_cell_shapes(one_chip):
+def test_kda_compiles_at_the_cell_shapes(one_chip, monkeypatch):
     """``_contrib_KDA`` at ``kimi_linear.fit``'s shapes (one sequence of
     8,192 tokens, 32 heads of 128 for keys and values, chunks of 64 in
     sub-blocks of 16, bfloat16), forward and gradient under
-    ``jax.checkpoint`` as the step holds a layer: the sub-blocks'
-    exponentials (2 GiB over the sequence) exist a slab of 16 chunks at a
-    time, the inverse's doublings are not kept for the gradient, and the
-    temporaries of one layer's recurrence stay under 3.3 GiB (3.02 when
-    this was written; 4.77 with the exponentials whole and the doublings
-    kept: the step then did not fit the chip)."""
+    ``jax.checkpoint`` as the step holds a layer: ``kda/intra`` is the
+    Pallas kernels of ``pallas/kda.py`` (the forward twice: the pull-back
+    of what follows it needs its outputs; the backward once), no block of
+    the sub-blocks' exponentials exists outside them, the only loop is the
+    one over chunks that carries the state, and the temporaries of one
+    layer's recurrence stay under 2.3 GiB (2.08 when this was written;
+    3.02 with the blocks and the inverse in ``jax.numpy`` a slab of 16
+    chunks at a time, 4.77 with the exponentials whole)."""
     from mxnet_tpu.ops import lm
+    from mxnet_tpu.pallas import kda as kernels
+    monkeypatch.setattr(kernels, "_use_interpret", lambda: False)
     t, h, d = 8192, 32, 128
 
     def shape(*s, dtype=jnp.bfloat16):
@@ -318,7 +322,9 @@ def test_kda_compiles_at_the_cell_shapes(one_chip):
     with jax.default_matmul_precision("default"):
         grad = _compile(step, *args)
     text = grad.as_text()
-    assert "f32[1,32,128,4,16,16,128]" not in text
-    assert "f32[128,1,32,4,16,16,128]" not in text
-    assert " while(" in text
-    assert grad.memory_analysis().temp_size_in_bytes < 3.3 * 2 ** 30
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    assert sum("kda_intra_fwd" in c for c in calls) == 2
+    assert sum("kda_intra_bwd" in c for c in calls) == 1
+    assert "16,16,128]" not in text
+    assert text.count(" while(") == 3       # forward, recomputed, pull-back
+    assert grad.memory_analysis().temp_size_in_bytes < 2.3 * 2 ** 30
