@@ -166,10 +166,12 @@ class TelemetryLogger:
             peak = card.get("peak_bytes")
             self.logger.info(
                 self._rank_tag() +
-                "program card %s: kind=%s trace=%.1fms compile=%.1fms "
-                "flops=%s peak_hbm=%s donated=%d",
+                "program card %s: kind=%s trace=%.1fms lower=%.1fms "
+                "compile=%.1fms card=%.1fms flops=%s peak_hbm=%s "
+                "donated=%d",
                 key, card.get("kind"),
-                card.get("trace_ms") or 0.0, card.get("compile_ms") or 0.0,
+                card.get("trace_ms") or 0.0, card.get("lower_ms") or 0.0,
+                card.get("compile_ms") or 0.0, card.get("card_ms") or 0.0,
                 "%.4g" % flops if flops else None,
                 "%.2fMiB" % (peak / 2.0 ** 20) if peak else None,
                 len(card.get("donated") or ()))

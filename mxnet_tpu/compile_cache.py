@@ -208,8 +208,9 @@ def entry_path(key):
 # Quick-key index: the trace-skip tier
 # ---------------------------------------------------------------------------
 # The content key above is bulletproof (it hashes the actual StableHLO)
-# but computing it requires TRACING the program — a visible slice of a
-# warm start (per-bucket jit_trace is ~15% of a cold serving warmup).
+# but computing it requires TRACING and LOWERING the program — a visible
+# slice of a warm start (per-bucket jit_trace + jit_lower is ~15% of a
+# cold serving warmup).
 # The quick key is computable WITHOUT tracing, from everything that
 # determines what the trace WOULD produce:
 #   * the caller's graph fingerprint (``_GraphProgram`` hashes its

@@ -31,6 +31,9 @@ from .ops.common import rng_scope, mx_dtype
 from . import random as _random
 from . import telemetry
 from . import faults
+from . import jax_cache
+
+jax_cache.listen()
 
 #: the attribute with which a symbol's op node starts a checkpoint segment
 #: (``_GraphProgram.mirror_stages``)
@@ -81,15 +84,15 @@ def record_dispatch(kind):
 # Instrumented program compilation (program cards)
 # ---------------------------------------------------------------------------
 # Every jitted entry point in this module compiles through
-# ``_InstrumentedProgram`` — explicit ``lower().compile()`` with the
-# trace and compile phases timed as telemetry spans and the compiled
-# executable's own cost/memory analysis captured into a PROGRAM CARD in
-# ``telemetry.programs()``. The card is the online counterpart of an
-# offline xprof capture: per-program FLOPs, bytes accessed, HBM
-# footprint, compile wall-time and dispatch count, available at every
-# ``telemetry.snapshot()`` — exactly the per-program features TPU cost
-# models are built on (Kaufman et al. arXiv:2008.01040, TVM
-# arXiv:1802.04799).
+# ``_InstrumentedProgram`` — explicit ``trace().lower().compile()`` with
+# the trace, lower, compile and card phases timed as telemetry spans and
+# the compiled executable's own cost/memory analysis captured into a
+# PROGRAM CARD in ``telemetry.programs()``. The card is the online
+# counterpart of an offline xprof capture: per-program FLOPs, bytes
+# accessed, HBM footprint, compile wall-time and dispatch count,
+# available at every ``telemetry.snapshot()`` — exactly the per-program
+# features TPU cost models are built on (Kaufman et al.
+# arXiv:2008.01040, TVM arXiv:1802.04799).
 
 _PROG_SEQ = itertools.count(1)
 
@@ -274,15 +277,19 @@ def _named(fn, name):
 
 class _InstrumentedProgram:
     """One jitted entry point, compiled through explicit
-    ``lower().compile()`` with full introspection:
+    ``trace().lower().compile()`` with full introspection:
 
     * per-signature AOT executables cached on (treedef, leaf
       shapes/dtypes) — the same key jax's own dispatch cache uses,
       minus sharding (an input moving devices under an unchanged
       shape raises from the strict AOT executable and degrades that
       signature to the plain jit path instead of mis-executing);
-    * trace and compile phases timed as ``jit_trace``/``jit_compile``
-      telemetry spans AND recorded on the card;
+    * the build inside one ``jit_build`` telemetry span, its phases
+      timed as spans AND recorded on the card: tracing (``jit_trace``,
+      ``trace_ms``), lowering (``jit_lower``, ``lower_ms``), the XLA
+      compile or the load from JAX's persistent cache (``jit_compile``,
+      ``compile_ms``) and the cost and memory analyses behind the card
+      (``jit_card``, ``card_ms``);
     * a PROGRAM CARD per compile in ``telemetry.programs()``;
     * a structured once-per-cause RECOMPILE warning through log.py
       when a cache miss follows a prior compile, naming exactly which
@@ -401,105 +408,118 @@ class _InstrumentedProgram:
             card["id"])
 
     def _build(self, sig, args):
-        """Cache miss: explicit lower().compile(), card capture,
-        recompile diagnosis. AOT failures (backend quirks) degrade to
-        the plain jitted callable with a card whose analysis fields
-        stay None — dispatch must never break on introspection.
+        """Cache miss: explicit trace().lower().compile(), card capture,
+        recompile diagnosis, all inside one ``jit_build`` span. AOT
+        failures (backend quirks) degrade to the plain jitted callable
+        with a card whose analysis fields stay None — dispatch must
+        never break on introspection.
 
         With the persisted tier on (``MXNET_COMPILE_CACHE``), the
         program is looked up in the on-disk executable store
         (mxnet_tpu/compile_cache.py) and a hit DESERIALIZES instead of
         invoking XLA (``jit_deserialize`` span, zero ``jit_compile``
         spans — the warm-start contract): first via the trace-skip
-        quick key (graph fingerprint; no ``lower()`` at all), then via
-        the content key over the lowered StableHLO. A miss compiles
+        quick key (graph fingerprint; no trace or lower at all), then
+        via the content key over the lowered StableHLO. A miss compiles
         and persists the fresh executable (plus the quick-key index
         entry) for the next process. Cache load/store failures degrade
-        inside compile_cache — only lower()/compile() errors reach the
-        AOT-fallback path here."""
+        inside compile_cache — only trace/lower/compile errors reach
+        the AOT-fallback path here."""
         from . import compile_cache
-        card_sig = self._signature_cards(args)
-        entry_id = "%s/s%d" % (self.entry, len(self._cache))
-        aot = True
-        compiled = None
-        source = "compiled"
-        cc_on = compile_cache.enabled() \
-            and compile_cache.persistable(self._donate)
-        qkey = None
-        if cc_on:
-            if callable(self._graph_key):
-                self._graph_key = self._graph_key()
-            qkey = compile_cache.quick_key(
-                self.kind, self._graph_key, signature=card_sig,
-                donated=self._donate)
-        trace_ms = compile_ms = deser_ms = 0.0
-        t0 = time.perf_counter()
-        try:
-            if qkey is not None:
-                ikey = compile_cache.index_get(qkey)
-                if ikey is not None:
-                    compiled = compile_cache.load(ikey, kind=self.kind)
-                    if compiled is not None:
-                        source = "disk_cache"   # no trace ran at all
-                        deser_ms = (time.perf_counter() - t0) * 1e3
-            if compiled is None:
-                with telemetry.span("jit_trace"):
-                    lowered = self._jitted.lower(*args)
-                trace_ms = (time.perf_counter() - t0) * 1e3
-                ckey = None
-                if cc_on:
-                    ckey = compile_cache.lowered_key(
-                        self.kind, lowered, signature=card_sig,
-                        donated=self._donate)
-                    if ckey is not None:
-                        t1 = time.perf_counter()
-                        compiled = compile_cache.load(ckey, kind=self.kind)
-                        if compiled is not None:
-                            source = "disk_cache"
-                            deser_ms = (time.perf_counter() - t1) * 1e3
-                            compile_cache.index_put(qkey, ckey)
-                if compiled is None:
-                    t1 = time.perf_counter()
-                    with telemetry.span("jit_compile"):
-                        compiled = lowered.compile()
-                    compile_ms = (time.perf_counter() - t1) * 1e3
-                    if ckey is not None:
-                        compile_cache.store(ckey, compiled,
-                                            kind=self.kind,
-                                            entry=entry_id,
-                                            signature=card_sig)
-                        compile_cache.index_put(qkey, ckey)
-        except Exception as e:
-            aot = False
-            aot_err = "%s: %s" % (type(e).__name__, e)
-        if aot:
-            card = card_from_compiled(
-                self.kind, compiled, entry=entry_id, signature=card_sig,
-                donated=self._donate, extra=self._meta)
-        else:
-            card = card_from_compiled(
-                self.kind, _NoAnalysis(), entry=entry_id,
-                signature=card_sig, donated=self._donate,
-                extra=dict(self._meta, aot_fallback=aot_err))
-        card["trace_ms"] = round(trace_ms, 3)
-        card["compile_ms"] = round(compile_ms, 3)
-        card["source"] = source
-        if source == "disk_cache":
-            # the XLA compile never ran (compile_ms stays 0): the
-            # disk-load cost is its own figure
-            card["deserialize_ms"] = round(deser_ms, 3)
-        if self._card is not None and self.warn_recompile:
-            self._warn_recompile(card)
-        self._card = card
-        telemetry.record_program(card)
-        if self.on_compile is not None:
+        with telemetry.span("jit_build"), jax_cache.instrumented_build():
+            card_sig = self._signature_cards(args)
+            entry_id = "%s/s%d" % (self.entry, len(self._cache))
+            aot = True
+            compiled = None
+            source = "compiled"
+            cc_on = compile_cache.enabled() \
+                and compile_cache.persistable(self._donate)
+            qkey = None
+            if cc_on:
+                if callable(self._graph_key):
+                    self._graph_key = self._graph_key()
+                qkey = compile_cache.quick_key(
+                    self.kind, self._graph_key, signature=card_sig,
+                    donated=self._donate)
+            trace_ms = lower_ms = compile_ms = deser_ms = 0.0
             try:
-                self.on_compile(card)
-            except Exception:
-                pass      # an accounting hook must never break a build
-        rec = [compiled if aot else self._jitted, card, aot]
-        self._cache[sig] = rec
-        return rec
+                if qkey is not None:
+                    t0 = time.perf_counter()
+                    ikey = compile_cache.index_get(qkey)
+                    if ikey is not None:
+                        compiled = compile_cache.load(ikey, kind=self.kind)
+                        if compiled is not None:
+                            source = "disk_cache"   # no trace ran at all
+                            deser_ms = (time.perf_counter() - t0) * 1e3
+                if compiled is None:
+                    t0 = time.perf_counter()
+                    with telemetry.span("jit_trace"):
+                        traced = self._jitted.trace(*args)
+                    t1 = time.perf_counter()
+                    with telemetry.span("jit_lower"):
+                        lowered = traced.lower()
+                    t2 = time.perf_counter()
+                    trace_ms, lower_ms = (t1 - t0) * 1e3, (t2 - t1) * 1e3
+                    ckey = None
+                    if cc_on:
+                        ckey = compile_cache.lowered_key(
+                            self.kind, lowered, signature=card_sig,
+                            donated=self._donate)
+                        if ckey is not None:
+                            t1 = time.perf_counter()
+                            compiled = compile_cache.load(ckey,
+                                                          kind=self.kind)
+                            if compiled is not None:
+                                source = "disk_cache"
+                                deser_ms = (time.perf_counter() - t1) * 1e3
+                                compile_cache.index_put(qkey, ckey)
+                    if compiled is None:
+                        t1 = time.perf_counter()
+                        with telemetry.span("jit_compile"):
+                            compiled = lowered.compile()
+                        compile_ms = (time.perf_counter() - t1) * 1e3
+                        if ckey is not None:
+                            compile_cache.store(ckey, compiled,
+                                                kind=self.kind,
+                                                entry=entry_id,
+                                                signature=card_sig)
+                            compile_cache.index_put(qkey, ckey)
+            except Exception as e:
+                aot = False
+                aot_err = "%s: %s" % (type(e).__name__, e)
+            t0 = time.perf_counter()
+            with telemetry.span("jit_card"):
+                if aot:
+                    card = card_from_compiled(
+                        self.kind, compiled, entry=entry_id,
+                        signature=card_sig, donated=self._donate,
+                        extra=self._meta)
+                else:
+                    card = card_from_compiled(
+                        self.kind, _NoAnalysis(), entry=entry_id,
+                        signature=card_sig, donated=self._donate,
+                        extra=dict(self._meta, aot_fallback=aot_err))
+            card["trace_ms"] = round(trace_ms, 3)
+            card["lower_ms"] = round(lower_ms, 3)
+            card["compile_ms"] = round(compile_ms, 3)
+            card["card_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
+            card["source"] = source
+            if source == "disk_cache":
+                # the XLA compile never ran (compile_ms stays 0): the
+                # disk-load cost is its own figure
+                card["deserialize_ms"] = round(deser_ms, 3)
+            if self._card is not None and self.warn_recompile:
+                self._warn_recompile(card)
+            self._card = card
+            telemetry.record_program(card)
+            if self.on_compile is not None:
+                try:
+                    self.on_compile(card)
+                except Exception:
+                    pass      # an accounting hook must never break a build
+            rec = [compiled if aot else self._jitted, card, aot]
+            self._cache[sig] = rec
+            return rec
 
     def lower(self, *args):
         """AOT passthrough (jax.stages signature): callers that lower

@@ -275,74 +275,83 @@ class BaseModule:
         if divergence_policy not in ("halt", "skip", "rollback"):
             raise MXNetError("divergence_policy must be halt|skip|"
                              "rollback, got %r" % (divergence_policy,))
-        ckpt = checkpoint
-        if isinstance(ckpt, str):
-            ckpt = CheckpointManager(ckpt)
-        rmgr = None
-        if resume is not None and resume is not False:
-            rmgr = ckpt if resume is True else resume
-            if isinstance(rmgr, str):
-                rmgr = CheckpointManager(rmgr)
-            if rmgr is None:
-                raise MXNetError("fit(resume=True) needs checkpoint=")
-        resume_meta = rmgr.latest() if rmgr is not None else None
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label, for_training=True,
-                  force_rebind=force_rebind)
-        if monitor is not None:
-            self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params)
-        skip_batches = 0
-        if resume_meta is not None:
-            rmgr.restore(self, resume_meta)
-            begin_epoch = int(resume_meta["epoch"])
-            skip_batches = int(resume_meta.get("nbatch", 0))
-            self.logger.info(
-                "Resuming from checkpoint %s: epoch=%d nbatch=%d",
-                rmgr.prefix, begin_epoch, skip_batches)
-        if validation_metric is None:
-            validation_metric = eval_metric
-        if not isinstance(eval_metric, _metric.EvalMetric):
-            eval_metric = _metric.create(eval_metric)
-
-        from ..heartbeat import DeadWorkerError
-        if ckpt is not None:
-            ckpt.clear_preempt()
-            ckpt.arm_signals()
+        # the preamble, from here to the first batch of _fit_loop (which
+        # leaves it; leaving again is a no-op, so it is left however fit
+        # ends)
+        setup = telemetry.span("fit_setup").__enter__()
         try:
-            while True:
-                try:
-                    self._fit_loop(train_data, eval_data, eval_metric,
-                                   validation_metric, epoch_end_callback,
-                                   batch_end_callback, eval_end_callback,
-                                   eval_batch_end_callback, monitor,
-                                   sparse_row_id_fn, begin_epoch,
-                                   num_epoch, skip_batches, ckpt,
-                                   divergence_check_every,
-                                   divergence_policy)
-                    break
-                except DeadWorkerError as e:
-                    # ELASTIC RECOVERY: a peer died before a collective
-                    # (the liveness gate aborted the step — nothing is
-                    # hung). Postmortem the death, re-mesh over the
-                    # survivors, restore the last atomic checkpoint and
-                    # continue the SAME fit call from its (epoch,
-                    # nbatch). Work since that checkpoint is lost —
-                    # that is the recovery contract (README
-                    # "Distributed training").
-                    meta = self._elastic_recover(e, ckpt)
-                    begin_epoch = int(meta["epoch"])
-                    skip_batches = int(meta.get("nbatch", 0))
-        finally:
+            ckpt = checkpoint
+            if isinstance(ckpt, str):
+                ckpt = CheckpointManager(ckpt)
+            rmgr = None
+            if resume is not None and resume is not False:
+                rmgr = ckpt if resume is True else resume
+                if isinstance(rmgr, str):
+                    rmgr = CheckpointManager(rmgr)
+                if rmgr is None:
+                    raise MXNetError("fit(resume=True) needs checkpoint=")
+            resume_meta = rmgr.latest() if rmgr is not None else None
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
+            if monitor is not None:
+                self.install_monitor(monitor)
+            self.init_params(initializer=initializer,
+                             arg_params=arg_params, aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params)
+            skip_batches = 0
+            if resume_meta is not None:
+                rmgr.restore(self, resume_meta)
+                begin_epoch = int(resume_meta["epoch"])
+                skip_batches = int(resume_meta.get("nbatch", 0))
+                self.logger.info(
+                    "Resuming from checkpoint %s: epoch=%d nbatch=%d",
+                    rmgr.prefix, begin_epoch, skip_batches)
+            if validation_metric is None:
+                validation_metric = eval_metric
+            if not isinstance(eval_metric, _metric.EvalMetric):
+                eval_metric = _metric.create(eval_metric)
+
+            from ..heartbeat import DeadWorkerError
             if ckpt is not None:
-                ckpt.disarm_signals()
+                ckpt.clear_preempt()
+                ckpt.arm_signals()
+            try:
+                while True:
+                    try:
+                        self._fit_loop(setup, train_data, eval_data,
+                                       eval_metric, validation_metric,
+                                       epoch_end_callback,
+                                       batch_end_callback, eval_end_callback,
+                                       eval_batch_end_callback, monitor,
+                                       sparse_row_id_fn, begin_epoch,
+                                       num_epoch, skip_batches, ckpt,
+                                       divergence_check_every,
+                                       divergence_policy)
+                        break
+                    except DeadWorkerError as e:
+                        # ELASTIC RECOVERY: a peer died before a
+                        # collective (the liveness gate aborted the step —
+                        # nothing is hung). Postmortem the death, re-mesh
+                        # over the survivors, restore the last atomic
+                        # checkpoint and continue the SAME fit call from
+                        # its (epoch, nbatch). Work since that checkpoint
+                        # is lost — that is the recovery contract (README
+                        # "Distributed training").
+                        meta = self._elastic_recover(e, ckpt)
+                        begin_epoch = int(meta["epoch"])
+                        skip_batches = int(meta.get("nbatch", 0))
+            finally:
+                if ckpt is not None:
+                    ckpt.disarm_signals()
+        finally:
+            setup.__exit__(None, None, None)
 
     # mxlint: hot
-    def _fit_loop(self, train_data, eval_data, eval_metric,
+    def _fit_loop(self, setup, train_data, eval_data, eval_metric,
                   validation_metric, epoch_end_callback,
                   batch_end_callback, eval_end_callback,
                   eval_batch_end_callback, monitor, sparse_row_id_fn,
@@ -374,6 +383,7 @@ class BaseModule:
             except StopIteration:
                 end_of_batch = True
                 next_data_batch = None
+            setup.__exit__(None, None, None)    # fit_setup ends here
             while not end_of_batch:
                 data_batch = next_data_batch
                 if monitor is not None:
@@ -461,7 +471,10 @@ class BaseModule:
                 self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
             self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
                              time.time() - tic)
-            self._publish_aux_counters()
+            # the ops' summed counters come back from the device here, so
+            # this waits for the epoch's last steps
+            with telemetry.span("epoch_sync"):
+                self._publish_aux_counters()
 
             # epoch-end host param sync ONLY at a callback boundary: the
             # executor already holds the canonical values, so the

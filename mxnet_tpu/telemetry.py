@@ -30,8 +30,8 @@ is the standing instrument every perf PR reads from:
   them into the device dump;
 * a **program-card registry** — every XLA program the executor
   compiles deposits a card (``record_program``) carrying its abstract
-  input signature, trace/compile wall-time, ``cost_analysis`` FLOPs/
-  bytes and ``memory_analysis`` footprint; ``program_dispatch`` bumps
+  input signature, trace/lower/compile/card wall-time, ``cost_analysis``
+  FLOPs/bytes and ``memory_analysis`` footprint; ``program_dispatch`` bumps
   the card's dispatch count per launch, and ``snapshot()`` derives an
   ONLINE sustained-FLOP/s (and MFU, once ``set_peak_flops`` is told
   the chip's ceiling) from card FLOPs x dispatches over the wall time
@@ -81,7 +81,7 @@ __all__ = [
     "enabled", "enable", "disable", "reset",
     "counter_inc", "counters", "snapshot", "span", "record_span",
     "span_stats", "span_count", "span_durations", "span_seconds",
-    "causal", "current_causal", "record_event", "events",
+    "causal", "current_causal", "record_event", "events", "epoch_s",
     "recent_spans", "serving_queue_depth", "process_identity",
     "on_dispatch", "remove_dispatch", "dispatch_event",
     "record_jit", "record_fallback", "record_fault", "record_transfer",
@@ -117,9 +117,11 @@ FIT_PHASE_SPANS = ("fit_batch", "feed", "step_prep", "step",
                    "io_next", "callbacks", "epoch_sync",
                    "kv_push", "kv_pull")
 
-# Module set-up, once per bind: what a process spends before its first
-# step beside compilation
-SETUP_SPANS = ("bind", "init_params", "init_optimizer")
+# Module set-up: ``fit_setup`` is ``fit`` from its entry to its first
+# batch (``bind``, ``init_params`` and ``init_optimizer``, once per bind,
+# nest in it): what a process spends before its first step beside
+# compilation
+SETUP_SPANS = ("fit_setup", "bind", "init_params", "init_optimizer")
 
 # the serving-path span names (mxnet_tpu/serving.py): request time in
 # queue, program dispatch per coalesced batch, the blocking d2h fetch,
@@ -136,11 +138,14 @@ SERVE_SPANS = ("serve_wait", "serve_batch", "serve_d2h", "serve_request")
 DECODE_SPANS = ("serve_prefill", "serve_decode_step", "serve_detokenize")
 
 # the program-build span names (executor._InstrumentedProgram /
-# compile_cache): tracing, an actual XLA compile, and a disk-cache
-# deserialize. The warm-start lanes gate on the compile-vs-deserialize
-# split — a warm process serving every bucket must record ZERO
-# jit_compile spans and >= one jit_deserialize per program
-COMPILE_SPANS = ("jit_trace", "jit_compile", "jit_deserialize")
+# compile_cache): the whole build, and in it tracing, lowering, an XLA
+# compile (or the load from JAX's persistent cache), the card's cost and
+# memory analyses, and a disk-cache deserialize. The warm-start lanes
+# gate on the compile-vs-deserialize split — a warm process serving
+# every bucket must record ZERO jit_compile spans and >= one
+# jit_deserialize per program
+COMPILE_SPANS = ("jit_build", "jit_trace", "jit_lower", "jit_compile",
+                 "jit_card", "jit_deserialize")
 
 # program-card registry bound: recompile storms must not grow the
 # registry without limit — the oldest card is dropped (its FLOPs x
@@ -202,6 +207,10 @@ COUNTERS = (
     "ssm.steps", "ssm.chunks_run",
     # Kimi Delta Attention's recurrence (``_contrib_KDA``), the same pair
     "kda.steps", "kda.chunks_run",
+    # every XLA program the process builds (``jax_cache``'s listener):
+    # builds, their backend seconds, those the persistent cache did not
+    # serve
+    "xla.programs", "xla.build_s", "xla.cache_misses",
 )
 
 
@@ -543,6 +552,12 @@ def events(n=None):
             for p_ns, kind, data, tid in evs]
 
 
+def epoch_s(perf_s):
+    """Epoch seconds of a ``time.perf_counter()`` reading, on the clock
+    whose ``ts`` ``events()`` and ``recent_spans()`` report."""
+    return _epoch_us(int(perf_s * 1e9)) / 1e6
+
+
 def recent_spans(n=None):
     """The retained span ring as JSON-safe dicts (oldest first):
     ``{"name", "ts" (epoch_s), "dur_ms", "tid", "ctx"}`` — the causal
@@ -831,8 +846,6 @@ def _online_stats():
             (c.get("flops") or 0.0) * c.get("dispatches", 0)
             for c in _programs.values())
         step_s = _span_seconds.get("step", 0.0)
-        compile_s = _span_seconds.get("jit_compile", 0.0)
-        deser_s = _span_seconds.get("jit_deserialize", 0.0)
         wall_s = 0.0 if _dispatch_t0_ns is None \
             else (_dispatch_t1_ns - _dispatch_t0_ns) / 1e9
         timed = max(flops - _dispatch_flops0, 0.0)
@@ -848,11 +861,6 @@ def _online_stats():
         # cumulative enqueue time (the ``step`` spans): how long the
         # host spent handing steps over, not how long they ran
         "step_time_s": round(step_s, 6),
-        # first-launch compiles happen INSIDE the dispatch stream;
-        # reported so readers can judge how much of it was warmup
-        "compile_time_s": round(compile_s, 6),
-        # disk-cache loads (compile_cache) — the warm-start counterpart
-        "deserialize_time_s": round(deser_s, 6),
         "model_flops_per_s": None if rate is None else round(rate, 3),
         "peak_flops": peak,
         # unrounded: a CPU-smoke MFU is ~1e-6 and must not read as 0.0

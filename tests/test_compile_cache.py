@@ -128,11 +128,13 @@ def test_quick_key_tier_skips_tracing(cache_dir):
     cold = _fresh_program(graph_key=gk)
     cold(*_args())
     traces = _span_count("jit_trace")
-    assert traces >= 1
+    lowers = _span_count("jit_lower")
+    assert traces >= 1 and lowers == traces
     warm = _fresh_program(graph_key=gk)
     warm(*_args())
-    # the quick-key index resolved before lower(): no new trace span
+    # the quick-key index resolved before tracing: no new trace or lower
     assert _span_count("jit_trace") == traces
+    assert _span_count("jit_lower") == lowers
     assert _span_count("jit_deserialize") == 1
     assert _cc_counters().get("compile_cache.hit") == 1
 

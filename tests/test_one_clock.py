@@ -4,10 +4,12 @@ and step bookkeeping have spans, and the online estimate reads the
 dispatch stream. All on XLA's CPU backend: counts and names, no times."""
 import contextlib
 import glob
+import inspect
 import json
 import os
 import re
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -16,7 +18,7 @@ import jax
 
 import mxnet_tpu as mx
 from mxnet_tpu import executor as _ex
-from mxnet_tpu import telemetry
+from mxnet_tpu import jax_cache, telemetry
 
 BATCH, DIM, CLASSES = 32, 8, 4
 STEP_SPANS = ("feed", "step_prep", "step", "step_install")
@@ -306,8 +308,173 @@ def test_setup_spans_skip_calls_that_do_nothing():
     mod = mx.mod.Module(_net(), context=mx.cpu())
     _fit(mod)
     _fit(mod)       # bound and initialised: the second fit sets nothing up
-    assert [telemetry.span_count(n) for n in telemetry.SETUP_SPANS] \
-        == [1, 1, 1]
+    assert {n: telemetry.span_count(n) for n in telemetry.SETUP_SPANS} \
+        == {"fit_setup": 2, "bind": 1, "init_params": 1,
+            "init_optimizer": 1}
+
+
+# -- set-up measured from inside the program --------------------------------
+
+BUILD_PHASES = ("jit_trace", "jit_lower", "jit_compile", "jit_card")
+SETUP_INNER = ("bind", "init_params", "init_optimizer")
+
+
+def _ring():
+    """``[(name, start_s, end_s)]`` of the span ring."""
+    return [(s["name"], s["ts"], s["ts"] + s["dur_ms"] / 1e3)
+            for s in telemetry.recent_spans()]
+
+
+def _inside(spans, outer, slack=2e-6):
+    """The names of the spans that lie inside ``outer``'s interval (the
+    ring stamps to the microsecond)."""
+    _, lo, hi = outer
+    return sorted(n for n, a, b in spans if (n, a, b) != outer
+                  and lo - slack <= a and b <= hi + slack)
+
+
+def _fit_setup_nests_the_set_up_spans():
+    _fit(mx.mod.Module(_net(), context=mx.cpu()))
+    spans = _ring()
+    setup, = [s for s in spans if s[0] == "fit_setup"]
+    inner = _inside(spans, setup)
+    assert [n for n in inner if n in SETUP_INNER] == sorted(SETUP_INNER)
+    assert not [n for n in inner if n == "fit_batch"]
+    first = min(s[1] for s in spans if s[0] == "fit_batch")
+    assert setup[2] <= first + 2e-6        # it ends at the first batch
+
+
+def _jit_build_nests_each_phase_once():
+    _fit(mx.mod.Module(_net(), context=mx.cpu()))
+    spans = _ring()
+    builds = [s for s in spans if s[0] == "jit_build"]
+    assert builds and len(builds) == telemetry.span_count("jit_build")
+    for build in builds:
+        assert [n for n in _inside(spans, build) if n.startswith("jit_")] \
+            == sorted(BUILD_PHASES)
+    card, = [c for c in telemetry.programs().values()
+             if c["kind"] == "train_step"]
+    assert all(card[k] >= 0 for k in ("trace_ms", "lower_ms", "compile_ms",
+                                       "card_ms"))
+    # the step's XLA build is logged as the instrumented build's
+    step, = [e["data"] for e in telemetry.events()
+             if e["kind"] == "xla_build" and "train_step" in e["data"]["fn"]]
+    assert step["instrumented"] is True
+
+
+def _second_fit_builds_nothing():
+    mod = mx.mod.Module(_net(), context=mx.cpu())
+    _fit(mod)
+    telemetry.reset()
+    _fit(mod)
+    assert telemetry.span_count("fit_setup") == 1
+    assert not [n for n in telemetry.span_stats() if n.startswith("jit_")]
+
+
+def _epoch_sync_covers_the_counters_fetch(monkeypatch):
+    fetched = []
+    orig = mx.mod.Module._publish_aux_counters
+
+    def publish(self):
+        fetched.append(telemetry.epoch_s(time.perf_counter()))
+        return orig(self)
+
+    monkeypatch.setattr(mx.mod.Module, "_publish_aux_counters", publish)
+    _fit(mx.mod.Module(_net(), context=mx.cpu()))
+    syncs = [s for s in _ring() if s[0] == "epoch_sync"]
+    assert len(fetched) == 1 and len(syncs) == 1
+    assert syncs[0][1] - 2e-6 <= fetched[0] <= syncs[0][2] + 2e-6
+
+
+def _profiler_shows_the_same_nesting(tmp_path):
+    from jax.profiler import ProfileData
+    out = str(tmp_path / "trace")
+    jax.profiler.start_trace(out)
+    try:
+        _fit(mx.mod.Module(_net(), context=mx.cpu()))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    wanted = set(telemetry.SETUP_SPANS) | set(telemetry.COMPILE_SPANS)
+    spans = [(ev.name, ev.start_ns * 1e-9,
+              (ev.start_ns + ev.duration_ns) * 1e-9)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name in wanted]
+    setup, = [s for s in spans if s[0] == "fit_setup"]
+    assert [n for n in _inside(spans, setup, 0) if n in SETUP_INNER] \
+        == sorted(SETUP_INNER)
+    for build in [s for s in spans if s[0] == "jit_build"]:
+        assert _inside(spans, build, 0) == sorted(BUILD_PHASES)
+
+
+def _one_listener_however_many_watches():
+    from jax._src import monitoring     # the registry's readers
+    watches = [jax_cache.CacheWatch() for _ in range(3)]
+    jax_cache.listen()
+    assert monitoring.get_event_listeners().count(jax_cache._on_event) == 1
+    assert monitoring.get_event_duration_listeners().count(
+        jax_cache._on_duration) == 1
+    assert all(w.counts() == {"requests": 0, "hits": 0, "misses": 0}
+               for w in watches)
+
+
+def _cache_watch_counts_with_telemetry_off():
+    telemetry.disable()
+    watch = jax_cache.CacheWatch()
+    jax.monitoring.record_event(
+        "/jax/compilation_cache/compile_requests_use_cache")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert watch.counts() == {"requests": 1, "hits": 0, "misses": 1}
+    assert telemetry.events() == []
+
+
+def _fresh_jit_is_one_build_outside():
+    def triple_plus_one(x):
+        return x * 3.0 + 1.0
+
+    x = np.ones((5,), np.float32)
+    jax.jit(triple_plus_one)(x).block_until_ready()
+    builds = [e["data"] for e in telemetry.events()
+              if e["kind"] == "xla_build"]
+    assert len(builds) == 1, builds
+    assert "triple_plus_one" in builds[0]["fn"]
+    assert builds[0]["instrumented"] is False
+    assert builds[0]["cache_hit"] is False and builds[0]["s"] > 0
+    counts = telemetry.counters()
+    assert counts["xla.programs"] == counts["xla.cache_misses"] == 1
+    assert counts["xla.build_s"] == builds[0]["s"]
+
+
+def _cache_hit_marks_its_build():
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.25, fun_name="f")
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 0.5, fun_name="f")
+    assert [e["data"] for e in telemetry.events()] == [
+        {"fn": "f", "s": 0.25, "cache_hit": True, "instrumented": False}]
+    assert "xla.cache_misses" not in telemetry.counters()
+
+
+@pytest.mark.parametrize("check", [
+    _fit_setup_nests_the_set_up_spans,
+    _jit_build_nests_each_phase_once,
+    _second_fit_builds_nothing,
+    _epoch_sync_covers_the_counters_fetch,
+    _profiler_shows_the_same_nesting,
+    _one_listener_however_many_watches,
+    _cache_watch_counts_with_telemetry_off,
+    _fresh_jit_is_one_build_outside,
+    _cache_hit_marks_its_build,
+], ids=lambda f: f.__name__.strip("_"))
+def test_set_up_is_spanned_and_builds_are_logged(check, monkeypatch,
+                                                 tmp_path):
+    wants = inspect.signature(check).parameters
+    check(**{k: v for k, v in (("monkeypatch", monkeypatch),
+                               ("tmp_path", tmp_path)) if k in wants})
 
 
 def test_fused_batches_transfer_exactly_their_bytes():

@@ -83,6 +83,7 @@ def test_cards_for_all_entry_points():
         assert card["peak_bytes"] and card["peak_bytes"] > 0
         assert card["argument_bytes"] > 0 and card["output_bytes"] > 0
         assert card["compile_ms"] > 0 and card["trace_ms"] >= 0
+        assert card["lower_ms"] >= 0 and card["card_ms"] >= 0
         assert card["dispatches"] >= 1
         # the abstract input signature names the fed arguments
         paths = [e[0] for e in card["signature"]]
@@ -338,15 +339,15 @@ def test_cards_degrade_when_analysis_unavailable(monkeypatch):
 
 
 def test_dispatch_survives_aot_compile_failure():
-    """lower()/compile() blowing up falls back to the plain jitted
-    callable; the card records the fallback, fields stay None."""
+    """trace()/lower()/compile() blowing up falls back to the plain
+    jitted callable; the card records the fallback, fields stay None."""
     prog = _ex._InstrumentedProgram("forward", lambda x: x * 2.0)
 
     class _BrokenLower:
         def __init__(self, real):
             self._real = real
 
-        def lower(self, *args):
+        def trace(self, *args):
             raise RuntimeError("AOT not supported here")
 
         def __call__(self, *args):
@@ -408,8 +409,8 @@ def test_telemetry_logger_programs_mode(caplog):
             frequent=2, programs=True))
     lines = [r.message for r in caplog.records if "program card" in r.message]
     assert lines, "programs=True logged no cards"
-    assert any("train_step" in ln and "compile=" in ln and "flops=" in ln
-               for ln in lines)
+    assert any("train_step" in ln and "lower=" in ln and "compile=" in ln
+               and "card=" in ln and "flops=" in ln for ln in lines)
     # each card logged once
     assert len(lines) == len(set(lines))
 
