@@ -447,10 +447,16 @@ def kda(query, key, value, gate, beta, A_log, dt_bias, heads=1, chunk=64,
       so it ends with ``A^(chunk/2)``), float32 at full precision; the
       backward kernel makes the blocks and the inverse again from the
       op's inputs;
-    - ``kda/state``: a loop over the chunks carries the state: ``U~ = U - W
-      S``, ``S' = Diag(e^(G_last)) S + (K e^(G_last - G))^T U~``; it hands
-      on each chunk's entering state and ``U~``;
-    - ``kda/out``: ``O = (Q e^G) S + tril(Aqk) U~``.
+    - ``kda/state`` (``pallas.kda``'s ``kda_state``: ``kda_state_fwd`` /
+      ``kda_state_bwd``): the state carried from chunk to chunk in VMEM,
+      ``U~ = U - W S``, ``O = (Q e^G) S + tril(Aqk) U~``, ``S' =
+      Diag(e^(G_last)) S + (K e^(G_last - G))^T U~``, one program a
+      sequence and eight heads running down the chunks; ``O`` is written
+      in ``query``'s type straight into the (batch, T, heads * dv) layout.
+      Its backward kernel runs the chunks from the last and carries the
+      state's cotangent; its residuals are its inputs and the entering
+      states in ``query``'s type, which a checkpoint segment's first run
+      does not write.
 
     ``g``, ``G``, ``b``, the blocks, ``T``, ``U``, ``U~`` and the states are
     float32; every product's operands are of ``query``'s type (``T``, ``K
@@ -480,17 +486,10 @@ def _l2(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def _mul(eq, x, y, dtype):
-    """A product whose operands are rounded to ``dtype`` and whose sum is
-    float32."""
-    return jnp.einsum(eq, x.astype(dtype), y.astype(dtype),
-                      preferred_element_type=_F32)
-
-
 def _kda_gate(query, key, value, gate, beta, A_log, dt_bias, h, q):
     """``kda/gate``: ``(q, k, v, G, b)`` chunk-major, (chunks, batch, heads,
-    chunk, features): the kernels' grid of ``kda/intra`` and the loop of
-    ``kda/state`` run down the first axis as it lies. ``q`` and ``k`` are
+    chunk, features): the kernels of ``kda/intra`` and ``kda/state`` take
+    a chunk's block of it as it lies. ``q`` and ``k`` are
     normed (and ``q`` scaled), ``G`` is the running sum of ``g`` inside a
     chunk, ``b`` is ``sigmoid(beta)`` (.., chunk, 1); all float32 but
     ``v``."""
@@ -517,42 +516,19 @@ def _kda_gate(query, key, value, gate, beta, A_log, dt_bias, h, q):
             chunks(value.reshape(b, t, h, dv)), jnp.cumsum(g, axis=3), bt)
 
 
-def _kda_state(kn, G, w, u, dtype):
-    """``kda/state``: the loop over the chunks; ``(S, U~)`` of every chunk,
-    ``S`` the state that enters it, both rounded to ``dtype``."""
-    last = G[..., -1:, :]                                # (n, b, h, 1, dk)
-    kp = (kn * jnp.exp(last - G)).astype(dtype)
-
-    def step(s, x):
-        w_, u_, kp_, keep = x
-        sb = s.astype(dtype)
-        ut = (u_ - _mul("bhrc,bhcv->bhrv", w_, sb, dtype)).astype(dtype)
-        return keep * s + _mul("bhrc,bhrv->bhcv", kp_, ut, dtype), (sb, ut)
-
-    zero = jnp.zeros(kn.shape[1:3] + (kn.shape[-1], u.shape[-1]), _F32)
-    return jax.lax.scan(step, zero, (
-        w.astype(dtype), u, kp, jnp.swapaxes(jnp.exp(last), -1, -2)))[1]
-
-
 @functools.partial(jax.jit, static_argnums=(7, 8, 9))   # mxlint: disable=jit-site -- a body inside the caller's program (the fused step's card covers it), never a dispatch of its own
 def _kda(query, key, value, gate, beta, A_log, dt_bias, h, q, sub):
     """``_contrib_KDA``'s body, jitted by itself so that a model's layers
     lower once."""
-    from ..pallas.kda import kda_intra
-    b, t, _ = query.shape
-    dtype = query.dtype
+    from ..pallas.kda import kda_intra, kda_state
+    t = query.shape[1]
     with jax.named_scope("kda/gate"):
         qn, kn, v, G, bt = _kda_gate(query, key, value, gate, beta, A_log,
                                      dt_bias, h, q)
     with jax.named_scope("kda/intra"):
         aqk, w, u = kda_intra(qn, kn, v, G, bt[..., 0], sub)
     with jax.named_scope("kda/state"):
-        s_in, ut = _kda_state(kn, G, w, u, dtype)
-    with jax.named_scope("kda/out"):
-        o = _mul("...rc,...cv->...rv", qn * jnp.exp(G), s_in, dtype) \
-            + _mul("...ri,...iv->...rv", aqk, ut, dtype)
-    o = o.astype(dtype).transpose(1, 0, 3, 2, 4).reshape(b, -1, v.shape[2]
-                                                         * v.shape[-1])
+        o = kda_state(qn, kn, G, aqk, w, u)
     return o[:, :t]
 
 

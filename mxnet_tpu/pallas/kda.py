@@ -1,8 +1,9 @@
-"""The chunk-local work of the chunked delta rule with a decay a channel
-(Kimi Delta Attention, arXiv:2510.26692) as a Pallas TPU kernel pair:
-``ops/lm.py``'s ``_contrib_KDA`` calls ``kda_intra`` for everything
-``kda/intra`` computes, so that no block of exponentials, no ``(chunk,
-chunk)`` block and no step of the inverse is written to or read from HBM.
+"""The chunked delta rule with a decay a channel (Kimi Delta Attention,
+arXiv:2510.26692) as two Pallas TPU kernel pairs: ``ops/lm.py``'s
+``_contrib_KDA`` calls ``kda_intra`` for everything ``kda/intra`` computes,
+so that no block of exponentials, no ``(chunk, chunk)`` block and no step
+of the inverse is written to or read from HBM, and ``kda_state`` for the
+state carried from chunk to chunk and the output (below).
 
 One program of the grid ``(chunks, batch, heads / heads a program)`` holds
 one chunk of ``q`` positions of a few heads; per head the chunk's normed
@@ -25,7 +26,7 @@ over channels is a product with operands of the data's type. Then
     W = T (K e^G),  U = T V
 
 ``kda_intra_fwd`` hands back ``Aqk`` and ``W`` in the data's type (what
-``kda/state`` and ``kda/out`` round them to) and ``U`` float32;
+``kda_state`` takes them in) and ``U`` float32;
 ``kda_intra_bwd`` makes the blocks, their exponentials and the inverse
 again in VMEM and takes the cotangents of the three to those of ``q``,
 ``k``, ``v``, ``G`` and ``b``. Exponents, blocks, the inverse and every
@@ -34,6 +35,29 @@ accumulator are float32; the products between sub-blocks and those of
 ``jax.numpy`` form these kernels replace rounded them, forward and
 backward); the doubling and its pull-back ``-inv^T g inv^T`` are float32
 at full precision.
+
+``kda_state_fwd`` runs the grid ``(batch, heads / heads a program,
+chunks)``, the chunks in order, and carries each head's state ``S``
+(``dk x dv``, float32, held transposed) in VMEM from one chunk to the
+next, nought at the first:
+
+    U~ = U - W S       O = (Q e^G) S + Aqk U~
+    S <- Diag(e^(G_last)) S + (K e^(G_last - G))^T U~
+
+``Q e^G`` and ``K e^(G_last - G)`` are made in VMEM; ``O`` is written in
+the data's type straight into the op's ``(batch, T, heads x dv)`` layout,
+a ``(chunk, heads a program x dv)`` block a program. Every product takes
+operands of the data's type (``S``, ``U~`` and the decayed ``Q`` and
+``K`` rounded to it, where the ``jax.numpy`` form these kernels replace
+rounded them) and sums in float32. Where the pull-back will follow, it
+also writes each chunk's entering state in the data's type: the
+backward's residuals are these and the inputs. ``kda_state_bwd`` runs the
+chunks from the last, carries the state's cotangent in VMEM and hands
+back those of ``Q``, ``K``, ``G``, ``Aqk``, ``W`` and ``U``; the
+cotangent of a value of the data's type is rounded to it. The decay's
+cotangent reads the rounded entering state where the scan read the
+float32 one. Under a checkpoint whose first run keeps no residual
+(``optimize_remat``) that run writes the output alone.
 
 On the chip a program takes 8 heads (all of them where the heads are no
 multiple of 8), and the chunk and ``sub`` have to be multiples of 8;
@@ -51,7 +75,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _dot_precision, _use_interpret
 
-__all__ = ["kda_intra"]
+__all__ = ["kda_intra", "kda_state"]
 
 _F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
@@ -365,6 +389,145 @@ def _intra_bwd(sub, res, cot):
 _intra.defvjp(_intra_fwd, _intra_bwd)
 
 
+# -- the state from chunk to chunk, and the output ---------------------------
+
+def _state_fwd_kernel(q_ref, k_ref, g_ref, aqk_ref, w_ref, u_ref, o_ref,
+                      *rest, heads):
+    # rest: the entering states' output where the pull-back needs them, and
+    # the state S^T (heads, dv, dk) float32 carried from chunk to chunk
+    s_ref, s_scr = rest if len(rest) == 2 else (None,) + rest
+    dtype = o_ref.dtype
+    dv = u_ref.shape[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    for hh in range(heads):
+        st = s_scr[hh]
+        sb = st.astype(dtype)
+        if s_ref is not None:
+            s_ref[0, 0, hh] = sb
+        g = g_ref[0, 0, hh]
+        last = g[-1:]
+        ut = (u_ref[0, 0, hh] - _dot(w_ref[0, 0, hh], sb, ((1,), (1,)))
+              ).astype(dtype)
+        qe = (q_ref[0, 0, hh] * jnp.exp(g)).astype(dtype)
+        o = _dot(qe, sb, ((1,), (1,))) \
+            + _dot(aqk_ref[0, 0, hh], ut, ((1,), (0,)))
+        o_ref[0, :, hh * dv:(hh + 1) * dv] = o.astype(dtype)
+        kp = (k_ref[0, 0, hh] * jnp.exp(last - g)).astype(dtype)
+        s_scr[hh] = st * jnp.exp(last) + _dot(ut, kp, ((0,), (0,)))
+
+
+def _state_bwd_kernel(q_ref, k_ref, g_ref, aqk_ref, w_ref, u_ref, s_ref,
+                      do_ref, dq_ref, dk_ref, dg_ref, daqk_ref, dw_ref, du_ref,
+                      ds_scr, *, heads):
+    # ds_scr: the cotangent of the state that leaves the chunk, transposed
+    dtype = s_ref.dtype
+    dv = u_ref.shape[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    for hh in range(heads):
+        sb, w, a = s_ref[0, 0, hh], w_ref[0, 0, hh], aqk_ref[0, 0, hh]
+        q, k, g = q_ref[0, 0, hh], k_ref[0, 0, hh], g_ref[0, 0, hh]
+        n = g.shape[0]
+        last = g[-1:]
+        eg, ekp = jnp.exp(g), jnp.exp(last - g)
+        ut = (u_ref[0, 0, hh] - _dot(w, sb, ((1,), (1,)))).astype(dtype)
+        qe, kp = (q * eg).astype(dtype), (k * ekp).astype(dtype)
+        do = do_ref[0, :, hh * dv:(hh + 1) * dv]
+        ds = ds_scr[hh]
+        dsb = ds.astype(dtype)
+        # S' = Diag(e^last) S + kp^T U~, O = qe S + Aqk U~, U~ = U - W S:
+        # the cotangent of a value of the data's type is of that type
+        dkp = _round(_dot(ut, dsb, ((1,), (0,))), dtype)
+        dut = _round(_round(_dot(kp, dsb, ((1,), (1,))), dtype)
+                     + _round(_dot(a, do, ((0,), (0,))), dtype), dtype)
+        dutb = dut.astype(dtype)
+        dqe = _round(_dot(do, sb, ((1,), (0,))), dtype)
+        daqk_ref[0, 0, hh] = _dot(do, ut, ((1,), (1,))).astype(daqk_ref.dtype)
+        dw_ref[0, 0, hh] = (-_dot(dutb, sb, ((1,), (0,)))).astype(
+            dw_ref.dtype)
+        du_ref[0, 0, hh] = dut
+        dsin = _round(_round(_dot(do, qe, ((0,), (0,))), dtype)
+                      - _round(_dot(dutb, w, ((0,), (0,))), dtype), dtype)
+        # the decays: e^last on the state, e^(last - G) on kp, e^G on qe
+        tk = dkp * k * ekp
+        dlast = jnp.exp(last) * jnp.sum(sb.astype(_F32) * ds, 0,
+                                        keepdims=True) \
+            + jnp.sum(tk, 0, keepdims=True)
+        ds_scr[hh] = ds * jnp.exp(last) + dsin
+        dq_ref[0, 0, hh] = dqe * eg
+        dk_ref[0, 0, hh] = dkp * ekp
+        dg_ref[0, 0, hh] = dqe * q * eg - tk + jnp.where(
+            _iota((n, 1), 0) == n - 1, dlast, 0.0)
+
+
+def _state_call(kernel, name, outs, back, *args):
+    n, b, h, q, dk = args[0].shape
+    dv = args[5].shape[-1]
+    hb = HEADS_A_PROGRAM if h % HEADS_A_PROGRAM == 0 else h
+
+    def z(k):                 # the backward runs down the chunks from the last
+        return n - 1 - k if back else k
+
+    def block(x):
+        if x.ndim == 3:       # (batch, chunks x chunk, heads x dv)
+            return pl.BlockSpec((1, q, hb * dv),
+                                lambda i, j, k: (i, z(k), j))
+        return pl.BlockSpec((1, 1, hb) + x.shape[3:],
+                            lambda i, j, k: (z(k), i, j, 0, 0))
+    return pl.pallas_call(
+        functools.partial(kernel, heads=hb),
+        grid=(b, h // hb, n),
+        in_specs=[block(x) for x in args],
+        out_specs=[block(x) for x in outs],
+        out_shape=outs,
+        scratch_shapes=[pltpu.VMEM((hb, dv, dk), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=name, interpret=_use_interpret())(*args)
+
+
+def _state_run(with_states, q, k, g, aqk, w, u):
+    n, b, h, c, dk = q.shape
+    dv = u.shape[-1]
+    outs = (_shape((b, n * c, h * dv), w.dtype),)
+    if with_states:
+        outs += (_shape((n, b, h, dv, dk), w.dtype),)
+    return _state_call(_state_fwd_kernel, "kda_state_fwd", outs, False,
+                       q, k, g, aqk, w, u)
+
+
+@jax.custom_vjp
+def _state(q, k, g, aqk, w, u):
+    # the output alone: what a checkpoint segment's first forward run needs
+    return _state_run(False, q, k, g, aqk, w, u)[0]
+
+
+def _state_fwd(q, k, g, aqk, w, u):
+    o, s = _state_run(True, q, k, g, aqk, w, u)
+    return o, (q, k, g, aqk, w, u, s)
+
+
+def _state_bwd(res, do):
+    q, k, g, aqk, w, u, s = res
+    outs = (_shape(q.shape, _F32), _shape(k.shape, _F32),
+            _shape(g.shape, _F32), _shape(aqk.shape, aqk.dtype),
+            _shape(w.shape, w.dtype), _shape(u.shape, _F32))
+    return tuple(_state_call(_state_bwd_kernel, "kda_state_bwd", outs, True,
+                             q, k, g, aqk, w, u, s, do))
+
+
+# where the residuals go unused (a checkpoint segment's first run) jax calls
+# _state, which writes no entering state
+_state.defvjp(_state_fwd, _state_bwd, optimize_remat=True)
+
+
 def kda_intra(q, k, v, g, b, sub):
     """``(Aqk, W, U)`` of every chunk: ``q``, ``k``, ``g`` (the running
     sum of the log-decays inside each chunk) float32 and ``v`` chunk-major,
@@ -376,3 +539,13 @@ def kda_intra(q, k, v, g, b, sub):
     inputs."""
     return _intra(q.astype(_F32), k.astype(_F32), v, g.astype(_F32),
                   b.astype(_F32), int(sub))
+
+
+def kda_state(q, k, g, aqk, w, u):
+    """The output of every chunk, ``(batch, chunks x chunk, heads x dv)``
+    in ``w``'s type, from ``q``, ``k``, ``g`` as ``kda_intra`` takes them
+    and its ``(Aqk, W, U)``: the state enters the first chunk as nought.
+    Differentiable in all six; the backward's residuals are these inputs
+    and each chunk's entering state in ``w``'s type."""
+    return _state(q.astype(_F32), k.astype(_F32), g.astype(_F32), aqk, w,
+                  u.astype(_F32))

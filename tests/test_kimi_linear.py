@@ -185,6 +185,70 @@ def test_kda_intra_kernels_match_the_token_scan(t, sub, fast):
     _check(op, _token_scan, args, tol=5e-5)
 
 
+def _state_reference(qn, kn, G, aqk, w, u):
+    """The ``jax.numpy`` form that ``kda_state`` replaced: a scan over the
+    chunks carries the state in float32 and hands on each chunk's entering
+    state and ``U~``, rounded; then ``O = (Q e^G) S + Aqk U~``, every
+    product's operands rounded to the data's type."""
+    dtype = w.dtype
+
+    def mul(eq, x, y):
+        return jnp.einsum(eq, x.astype(dtype), y.astype(dtype),
+                          preferred_element_type=jnp.float32)
+
+    last = G[..., -1:, :]
+    kp = (kn * jnp.exp(last - G)).astype(dtype)
+
+    def step(s, x):
+        w_, u_, kp_, keep = x
+        sb = s.astype(dtype)
+        ut = (u_ - mul("bhrc,bhcv->bhrv", w_, sb)).astype(dtype)
+        return keep * s + mul("bhrc,bhrv->bhcv", kp_, ut), (sb, ut)
+
+    n, b, h, c, dv = u.shape
+    zero = jnp.zeros((b, h, kn.shape[-1], dv), jnp.float32)
+    s_in, ut = jax.lax.scan(step, zero, (
+        w, u, kp, jnp.swapaxes(jnp.exp(last), -1, -2)))[1]
+    o = mul("...rc,...cv->...rv", qn * jnp.exp(G), s_in) \
+        + mul("...ri,...iv->...rv", aqk, ut)
+    return o.astype(dtype).transpose(1, 0, 3, 2, 4).reshape(b, n * c, h * dv)
+
+
+def _state_inputs(t, dims, fast, chunk, sub, dtype=jnp.float32):
+    """What ``kda/state`` takes inside the op: ``kda/gate``'s and
+    ``kda_intra``'s outputs for one sequence of ``t`` tokens."""
+    from mxnet_tpu.pallas import kda as kernels
+    h = dims[0]
+    args = _kda_args(np.random.RandomState(t + h), 1, t, fast=fast,
+                     dims=dims)
+    args = tuple(x.astype(dtype) for x in args[:5]) + args[5:]
+    qn, kn, v, G, bt = lm._kda_gate(*args, h, chunk)
+    return (qn, kn, G) + kernels.kda_intra(qn, kn, v, G, bt[..., 0], sub)
+
+
+@pytest.mark.parametrize("t,heads,fast", [
+    (64, 3, -4.0), (57, 3, 30.0), (57, 8, -4.0), (64, 8, 2.0)],
+    ids=lambda v: str(v))
+def test_kda_state_kernels_match_the_chunk_scan(t, heads, fast):
+    """``kda_state`` (``pallas/kda.py``, interpreted) against the
+    ``jax.numpy`` scan over chunks it replaced, at chunks of 16: T four
+    whole chunks or not (padded by ``kda/gate``; a state that enters a
+    chunk and leaves for another needs three), 8 heads a program or a head
+    count no multiple of 8 (all of them), slow decays (a softplus of -4:
+    the state carries over chunks) and fast ones, to decays a float does
+    not hold.
+    The value and the gradients of all six inputs; the lowered pull-back
+    holds each kernel once."""
+    from mxnet_tpu.pallas import kda as kernels
+    ins = _state_inputs(t, (heads, 8, 6), fast, 16, 8)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kernels.kda_state(*a)), tuple(range(6))))(*ins))
+    assert text.count("name=kda_state_fwd") == 1
+    assert text.count("name=kda_state_bwd") == 1
+    assert "length=" not in text
+    _check(kernels.kda_state, _state_reference, ins, tol=5e-5)
+
+
 def test_the_kernels_inverse():
     """The unit lower block's inverse as the kernels make it (doubling at
     full precision) and its pull-back ``-inv^T g inv^T``, on the matrices
@@ -232,14 +296,42 @@ def check_kda_at_the_tile(t, dtype, dims):
         assert worst(got, e) < 3e-2, (got.shape, worst(got, e))
 
 
+def check_kda_state_at_the_tile(t, dims, fast=-4.0, tol=2e-2):
+    """``kda_state`` at the kernels' tile (chunks of 64 in sub-blocks of
+    16, bfloat16) against ``_state_reference`` at the same precision: the
+    value and all six inputs' gradients, each to ``tol`` of its largest
+    magnitude (the two differ by where the compiler keeps float32 between
+    roundings, not by what is rounded). By default the decays are slow
+    enough that the state carries over many chunks. What the chip's lane
+    runs (``tests/tpu``); returns the worst gap of each."""
+    from mxnet_tpu.pallas import kda as kernels
+    ins = jax.jit(lambda: _state_inputs(t, dims, fast, 64, 16,
+                                        jnp.bfloat16))()
+    got, pull = jax.vjp(jax.jit(kernels.kda_state), *ins)
+    want, pull_want = jax.vjp(jax.jit(_state_reference), *ins)
+    w = jnp.cos(jnp.arange(got.size, dtype=jnp.float32)).reshape(
+        got.shape).astype(got.dtype)
+
+    def worst(a, e):
+        a, e = (np.asarray(v, np.float32) for v in (a, e))
+        return float(np.max(np.abs(a - e)) / np.max(np.abs(e)))
+
+    gaps = [worst(got, want)] + [worst(a, e) for a, e in
+                                 zip(pull(w), pull_want(w))]
+    assert got.dtype == jnp.bfloat16
+    assert max(gaps) < tol, gaps
+    return gaps
+
+
 def test_a_mirrored_segment_runs_the_intra_kernel_where_needed():
     """Under the step's checkpoint policy the gradient of a node holds two
-    ``kda_intra_fwd`` calls (the pull-back of ``kda/state`` and ``kda/out``
-    needs ``Aqk``, ``W`` and ``U``, so the recomputed segment makes them
-    again) and one ``kda_intra_bwd``; the only loops are the chunk loop of
-    ``kda/state`` and its pull-back, over every chunk, and the kernels'
-    over their heads: no slab of chunks is mapped and nothing inside the
-    node is checkpointed again."""
+    ``kda_intra_fwd`` and two ``kda_state_fwd`` calls (the pull-back of
+    ``kda/state`` needs ``Aqk``, ``W``, ``U`` and the entering states, so
+    the recomputed segment makes them again; the first run writes no
+    state) and one ``kda_intra_bwd`` and ``kda_state_bwd``; the only loops
+    are the intra kernels' over their heads: no loop runs over the chunks,
+    no slab of chunks is mapped and nothing inside the node is
+    checkpointed again."""
     import re
     from mxnet_tpu import executor
     chunks = 32
@@ -254,9 +346,18 @@ def test_a_mirrored_segment_runs_the_intra_kernel_where_needed():
     text = str(jax.make_jaxpr(jax.grad(loss, tuple(range(7))))(*args))
     assert text.count("name=kda_intra_fwd") == 2
     assert text.count("name=kda_intra_bwd") == 1
-    # the loops: over the chunks, and inside the kernels over their heads
+    assert text.count("name=kda_state_fwd") == 2
+    assert text.count("name=kda_state_bwd") == 1
+    # the first run's kernel writes the output alone, the recomputed one
+    # the entering states (chunks, batch, heads, dv, dk) too
+    out = "[1,%d,%d]" % (chunks * 8, H * DV)
+    written = sorted((line.split("= pallas_call")[0].split()
+                      for line in text.splitlines()
+                      if "= pallas_call" in line and out in line), key=len)
+    assert [len(w) for w in written] == [1, 2], written
+    assert written[1][1].endswith("[%d,1,%d,%d,%d]" % (chunks, H, DV, DK))
     lengths = re.findall(r"length=(\d+)", text)
-    assert set(lengths) == {str(chunks), str(H)}, lengths
+    assert set(lengths) == {str(H)}, lengths
     assert text.count("prevent_cse") == 1
 
 
