@@ -1716,8 +1716,9 @@ class Executor:
         the step; this fetch is the only host sync, so it is called where
         a sync is due anyway (the end of an epoch), never per batch.
         What an op's shapes fix (``OpDef.step_counters``: the state-space
-        recurrence's chunks) is counted here on the host, ``steps`` times:
-        the training steps the caller has run since the last call."""
+        recurrence's chunks; ``OpDef.step_total`` once for all of an op's
+        nodes) is counted here on the host, ``steps`` times: the training
+        steps the caller has run since the last call."""
         fixed = [n for n in self._prog.nodes
                  if steps and n.op is not None and n.op.step_counters]
         if fixed:
@@ -1727,6 +1728,8 @@ class Executor:
             for node in fixed:
                 node.op.step_counters(shapes[node.name + "_output"],
                                       node.attrs, steps)
+            for op in {node.op for node in fixed if node.op.step_total}:
+                op.step_total(steps)
         seen = self.__dict__.setdefault("_aux_published", {})
         for node in self._prog.nodes:
             table = node.op.aux_counters if node.op is not None else None

@@ -4,7 +4,8 @@ gate, causal grouped-query attention with an optional window, the
 mixture-of-experts layer (gated, or ungated as ``_contrib_MoEUngated``)
 with its selection bias as an auxiliary state, the Mamba-2 mixer's parts
 (a causal depthwise convolution, the selective state-space recurrence in
-its chunked dual form, the gated group norm), Kimi Delta Attention's
+its chunked dual form, the gated group norm), LFM2's gated short
+convolution, Kimi Delta Attention's
 recurrence (a gated delta rule with a decay a channel, chunked), and a
 token-level cross-entropy head that holds a small output.
 
@@ -249,19 +250,58 @@ def causal_conv1d(data, weight, bias=None, kernel=4, act_type="silu",
     1) + k]``, positions before the first counted as nought; ``weight``
     (channels, kernel), ``bias`` (channels,; none with ``no_bias``).
     ``act_type`` ``"silu"`` applies SiLU, ``None`` nothing. Sums in
-    float32, the result in ``data``'s type. Written as ``kernel`` shifted
-    products: one pass over the data for the compiler, where a grouped
-    convolution of one channel a group is ``channels`` convolutions."""
-    k, t = int(kernel), data.shape[1]
-    x = jnp.pad(data.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
-    w = weight.astype(_F32)
-    y = 0.0 if bias is None or no_bias else bias.astype(_F32)
-    y = y + sum(x[:, i:i + t] * w[:, i] for i in range(k))
+    float32, the result in ``data``'s type."""
+    y = _causal_taps(data, weight, int(kernel), None if no_bias else bias)
     if act_type == "silu":
         y = jax.nn.silu(y)
     elif act_type is not None:
         raise ValueError("act_type is 'silu' or None, not %r" % (act_type,))
     return y.astype(data.dtype)
+
+
+def _causal_taps(data, weight, k, bias=None):
+    """``bias + sum_j weight[:, j] * data[t - (k - 1) + j]`` over ``data``
+    (batch, T, channels) in float32, positions before the first counted
+    as nought. Written as ``k`` shifted products: one pass over the data
+    for the compiler, where a grouped convolution of one channel a group
+    is ``channels`` convolutions."""
+    t = data.shape[1]
+    x = jnp.pad(data.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
+    w = weight.astype(_F32)
+    y = 0.0 if bias is None else bias.astype(_F32)
+    return y + sum(x[:, i:i + t] * w[:, i] for i in range(k))
+
+
+@register("_contrib_GatedShortConv", nin=2, arg_names=["data", "weight"],
+          defaults={"kernel": 3})
+def gated_short_conv(data, weight, kernel=3):
+    """LFM2's short-convolution mixer between its two projections:
+    ``data`` (batch, T, 3 d) is ``[B | C | x]`` side by side (the input
+    projection's output, in that order) and the result (batch, T, d) is
+    ``C * conv(B * x)``, ``conv`` causal and depthwise over T with
+    ``weight`` (d, kernel), no bias and no activation (as
+    ``_contrib_CausalConv1D``). Float32 throughout, the result in
+    ``data``'s type. The layers a step runs are fixed by the symbol: the
+    counters ``shortconv.runs`` and ``shortconv.steps`` are counted on the
+    host (``_shortconv_count_runs``, ``_shortconv_count_steps``)."""
+    d = data.shape[-1] // 3
+    b, c, x = (data[..., i * d:(i + 1) * d].astype(_F32) for i in range(3))
+    return (c * _causal_taps(b * x, weight, int(kernel))).astype(data.dtype)
+
+
+def _shortconv_count_runs(out_shape, params, steps):
+    """``steps`` training steps of one mixer into the telemetry counters
+    (``Executor.publish_aux_counters``): ``shortconv.runs``, summed over
+    the mixers."""
+    from .. import telemetry
+    telemetry.counter_inc("shortconv.runs", steps)
+
+
+def _shortconv_count_steps(steps):
+    """The training steps themselves, once for all mixers:
+    ``shortconv.steps``."""
+    from .. import telemetry
+    telemetry.counter_inc("shortconv.steps", steps)
 
 
 @register("_contrib_GatedRMSNorm", nin=3,
@@ -591,6 +631,12 @@ def install():
     # the taps may come in float32 (a start plus learned offsets): the
     # result is of the data's type, and inference without shapes says so
     conv.param_dtype_infer = lambda in_types, params: {}
+    short = get_op("_contrib_GatedShortConv")
+    short.param_shape_infer = lambda shapes, params: {
+        1: (shapes[0][-1] // 3, int(params.get("kernel", 3)))}
+    short.param_dtype_infer = conv.param_dtype_infer
+    short.step_counters = _shortconv_count_runs
+    short.step_total = _shortconv_count_steps
     gated = get_op("_contrib_GatedRMSNorm")
     gated.param_shape_infer = lambda shapes, params: {
         2: (int(params.get("group_size", 0)) or shapes[0][-1]

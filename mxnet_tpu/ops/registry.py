@@ -95,6 +95,9 @@ class OpDef:
         # fused steps run since ``Executor.publish_aux_counters`` last
         # called it.
         self.step_counters = None
+        # Optional fn(steps): as ``step_counters``, once for all of the
+        # op's nodes in the program.
+        self.step_total = None
 
     def __repr__(self):
         return "OpDef(%s)" % self.name

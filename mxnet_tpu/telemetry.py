@@ -207,6 +207,9 @@ COUNTERS = (
     "ssm.steps", "ssm.chunks_run",
     # Kimi Delta Attention's recurrence (``_contrib_KDA``), the same pair
     "kda.steps", "kda.chunks_run",
+    # LFM2's gated short convolution (``_contrib_GatedShortConv``): its
+    # layers' runs summed over training steps and mixers, and the steps
+    "shortconv.runs", "shortconv.steps",
     # every XLA program the process builds (``jax_cache``'s listener):
     # builds, their backend seconds, those the persistent cache did not
     # serve

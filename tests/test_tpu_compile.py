@@ -91,8 +91,9 @@ def test_flash_attention_gradient_compiles(one_chip):
 
 
 @pytest.mark.parametrize("window,kv_heads,d,dv", [
-    (None, 4, 128, 128), (2048, 4, 128, 128), (None, 32, 192, 128)],
-    ids=["full", "window", "latent_192x128"])
+    (None, 4, 128, 128), (2048, 4, 128, 128), (None, 32, 192, 128),
+    (None, 8, 64, 64)],
+    ids=["full", "window", "latent_192x128", "gqa_64"])
 def test_flash_attention_compiles_at_8k(one_chip, window, kv_heads, d, dv):
     """The plain entry tiles over K/V: at S=8192, D=128 with 32 query
     heads on 4 K/V heads (the language-model cell's attention) the causal
@@ -100,7 +101,8 @@ def test_flash_attention_compiles_at_8k(one_chip, window, kv_heads, d, dv):
     the PR that tiled it, the whole local K/V block and a (block_q, S_kv)
     score tile sat in VMEM and this shape was refused.) And latent
     attention's shape: 32 heads with keys of 192, one and a half lane
-    tiles, taken as they are, and values of 128."""
+    tiles, taken as they are, and values of 128. And LFM2's: 8 K/V heads
+    of 64, half a lane tile, taken as they are."""
     q = jax.ShapeDtypeStruct((1, 32, 8192, d), jnp.bfloat16,
                              sharding=one_chip)
     k = jax.ShapeDtypeStruct((1, kv_heads, 8192, d), jnp.bfloat16,
@@ -188,25 +190,29 @@ def test_flash_attention_carry_vmem_limit_at_8k(one_chip):
 
 
 @pytest.mark.parametrize("cell", [
-    # top-k, d, f, held, gated, GiB of temporaries
-    (8, 2048, 1024, 16, True, 2.1), (6, 2688, 1856, 8, False, 2.6)],
-    ids=["trinity_mini.fit", "nemotron3_nano.fit"])
+    # top-k, d, f, held, gated, GiB of temporaries, router's experts
+    (8, 2048, 1024, 16, True, 2.1, 128), (6, 2688, 1856, 8, False, 2.6, 128),
+    (4, 2048, 1792, 8, True, 1.3, 32)],
+    ids=["trinity_mini.fit", "nemotron3_nano.fit", "lfm2_moe.fit"])
 def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip, cell):
     """The routed layer at the cells' shapes (8,192 tokens, bfloat16;
     ``trinity_mini.fit``: top-8, d 2,048, f 1,024, 16 of 128 gated experts
     held; ``nemotron3_nano.fit``: top-6, 2,688 x 1,856, 8 of 128, no
-    gate): forward and gradient compile, the sorted order in chunks of
-    the even share (8 of 8,192 rows, 16 of 3,072). The grouped products stay 3
-    forward and 3 + 6 with the gradient (2 and 2 + 4 without a gate), each
+    gate; ``lfm2_moe.fit``: top-4, 2,048 x 1,792 (padded to 2,048), 8 of
+    32): forward and gradient compile, the sorted order in chunks of
+    the even share (8 of 8,192 rows, 16 of 3,072, 8 of 32 of 32,768). The
+    grouped products stay 3 forward and 3 + 6 with the gradient (2 and 2
+    + 4 without a gate), each
     one kernel over the whole order; the row passes are loops whose trip
     count is the step's own, and no branch holds a second body; the
     products' row tile is the 512 rows that ``chunk_rows`` rounds to.
     Under ``jax.checkpoint``, as the step holds a layer, the gated layer's
     temporaries stay at the whole-buffer layer's 2.01 GB (1.93 GiB; 2.44
-    GiB for the ungated layer at its wider, padded shapes)."""
+    GiB for the ungated layer at its wider, padded shapes; 1.24 GiB for
+    LFM2's, whose 32,768 selections are a quarter of the others')."""
     from mxnet_tpu.parallel import moe
-    t, n = 8192, 128
-    k, d, f, count, gated, room = cell
+    t = 8192
+    k, d, f, count, gated, room, n = cell
     assert moe.chunk_rows(t * k, count, n) == t * k * count // n
 
     def shape(*s):
