@@ -43,7 +43,12 @@ MIRROR_STAGE = "__mirror_stage__"
 #: that only it can produce and that costs more to make again than to hold:
 #: a checkpoint segment keeps these between forward and backward and makes
 #: everything else again. Outside a checkpoint a name is the identity.
-MIRROR_KEEPS = ("flash_attention_out", "flash_attention_lse")
+#: The attention kernel's output and log-sum-exp (``pallas.flash_attention``);
+#: the delta rule's ``Aqk``, ``W``, ``U`` (``ops.lm._kda``), which the
+#: pull-back of its state pair reads: 235 MB a layer at 8,192 positions to
+#: hold, a whole ``kda_intra_fwd`` kernel call a layer to make again.
+MIRROR_KEEPS = ("flash_attention_out", "flash_attention_lse",
+                "kda_intra_aqk", "kda_intra_w", "kda_intra_u")
 
 # what every mirrored evaluation hands ``jax.checkpoint``: nothing is kept
 # from inside a segment but the values its ops have named; where no op names
@@ -852,8 +857,9 @@ class _GraphProgram:
 
         Besides its boundary values a segment keeps the values an op
         inside it has named (``MIRROR_KEEPS``: the attention kernel's
-        output and log-sum-exp, which only the kernel can make again);
-        a segment in which no op names a value lowers as under a bare
+        output and log-sum-exp, which only the kernel can make again, and
+        the delta rule's ``Aqk``, ``W``, ``U``, a kernel call to make
+        again); a segment in which no op names a value lowers as under a bare
         ``jax.checkpoint``."""
         import math
 

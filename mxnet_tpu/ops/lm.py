@@ -20,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from .common import as_tuple
 from .registry import register, get_op
@@ -486,7 +487,11 @@ def kda(query, key, value, gate, beta, A_log, dt_bias, heads=1, chunk=64,
       doubling ``(I - A)(I + A^2)(I + A^4)...`` (``Akk`` is strictly lower,
       so it ends with ``A^(chunk/2)``), float32 at full precision; the
       backward kernel makes the blocks and the inverse again from the
-      op's inputs;
+      op's inputs. ``Aqk``, ``W`` and ``U`` carry the names
+      ``kda_intra_aqk``, ``kda_intra_w``, ``kda_intra_u``, which a
+      checkpoint segment keeps (``executor.MIRROR_KEEPS``): the pull-back
+      of ``kda/state`` reads them, so the segment's backward makes
+      ``kda/gate`` and ``kda/state`` again but not ``kda_intra_fwd``;
     - ``kda/state`` (``pallas.kda``'s ``kda_state``: ``kda_state_fwd`` /
       ``kda_state_bwd``): the state carried from chunk to chunk in VMEM,
       ``U~ = U - W S``, ``O = (Q e^G) S + tril(Aqk) U~``, ``S' =
@@ -567,6 +572,11 @@ def _kda(query, key, value, gate, beta, A_log, dt_bias, h, q, sub):
                                      dt_bias, h, q)
     with jax.named_scope("kda/intra"):
         aqk, w, u = kda_intra(qn, kn, v, G, bt[..., 0], sub)
+        # what the pull-back of kda/state reads: a checkpoint segment keeps
+        # them (executor.MIRROR_KEEPS) and runs kda_intra_fwd once
+        aqk = checkpoint_name(aqk, "kda_intra_aqk")
+        w = checkpoint_name(w, "kda_intra_w")
+        u = checkpoint_name(u, "kda_intra_u")
     with jax.named_scope("kda/state"):
         o = kda_state(qn, kn, G, aqk, w, u)
     return o[:, :t]

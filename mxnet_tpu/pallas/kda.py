@@ -367,8 +367,9 @@ def _intra(q, k, v, g, b, sub):
 
 
 def _intra_fwd(q, k, v, g, b, sub):
-    # the residuals are the inputs: a checkpoint segment makes them again
-    # and has no use of its own for what the forward kernel wrote
+    # the residuals are the inputs, which a checkpoint segment makes again;
+    # what the kernel writes the segment keeps by name (ops.lm._kda) for
+    # the state pair's pull-back, so this kernel runs once a step
     n, bb, h, c = b.shape
     lead = (n, bb, h, c)
     outs = (_shape(lead + (c,), v.dtype), _shape(k.shape, v.dtype),

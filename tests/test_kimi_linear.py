@@ -324,14 +324,14 @@ def check_kda_state_at_the_tile(t, dims, fast=-4.0, tol=2e-2):
 
 
 def test_a_mirrored_segment_runs_the_intra_kernel_where_needed():
-    """Under the step's checkpoint policy the gradient of a node holds two
-    ``kda_intra_fwd`` and two ``kda_state_fwd`` calls (the pull-back of
-    ``kda/state`` needs ``Aqk``, ``W``, ``U`` and the entering states, so
-    the recomputed segment makes them again; the first run writes no
-    state) and one ``kda_intra_bwd`` and ``kda_state_bwd``; the only loops
-    are the intra kernels' over their heads: no loop runs over the chunks,
-    no slab of chunks is mapped and nothing inside the node is
-    checkpointed again."""
+    """Under the step's checkpoint policy the gradient of a node holds one
+    ``kda_intra_fwd`` (the segment keeps ``Aqk``, ``W``, ``U`` by name for
+    the pull-back of ``kda/state``) and two ``kda_state_fwd`` calls (that
+    pull-back needs the entering states too, so the recomputed segment
+    makes them; the first run writes no state) and one ``kda_intra_bwd``
+    and ``kda_state_bwd``; the only loops are the intra kernels' over their
+    heads: no loop runs over the chunks, no slab of chunks is mapped and
+    nothing inside the node is checkpointed again."""
     import re
     from mxnet_tpu import executor
     chunks = 32
@@ -344,7 +344,7 @@ def test_a_mirrored_segment_runs_the_intra_kernel_where_needed():
         return jnp.sum(jax.checkpoint(op, policy=executor._MIRROR_POLICY)(*a))
 
     text = str(jax.make_jaxpr(jax.grad(loss, tuple(range(7))))(*args))
-    assert text.count("name=kda_intra_fwd") == 2
+    assert text.count("name=kda_intra_fwd") == 1
     assert text.count("name=kda_intra_bwd") == 1
     assert text.count("name=kda_state_fwd") == 2
     assert text.count("name=kda_state_bwd") == 1
@@ -359,6 +359,36 @@ def test_a_mirrored_segment_runs_the_intra_kernel_where_needed():
     lengths = re.findall(r"length=(\d+)", text)
     assert set(lengths) == {str(H)}, lengths
     assert text.count("prevent_cse") == 1
+
+
+def test_keeping_the_intra_outputs_changes_no_gradient():
+    """A node's seven input gradients under the step's policy, which keeps
+    ``Aqk``, ``W`` and ``U`` across the segment, are bit for bit those of a
+    policy without the three names, which makes them again with a second
+    ``kda_intra_fwd``: the kept values are the ones that run would make."""
+    from mxnet_tpu import executor
+    chunks = 3
+    args = _kda_args(np.random.RandomState(11), 1, chunks * 8 - 3)
+    without = jax.checkpoint_policies.save_only_these_names(
+        *(n for n in executor.MIRROR_KEEPS if not n.startswith("kda_")))
+
+    def grads(policy):
+        def loss(*a):
+            out = jax.checkpoint(
+                lambda *x: lm.kda(*x, heads=H, chunk=8, sub=8),
+                policy=policy)(*a)
+            w = jnp.cos(jnp.arange(out.size, dtype=out.dtype))
+            return jnp.sum(out * w.reshape(out.shape))
+        f = jax.grad(loss, tuple(range(7)))
+        return str(jax.make_jaxpr(f)(*args)), jax.jit(f)(*args)
+
+    text, got = grads(executor._MIRROR_POLICY)
+    text_b, want = grads(without)
+    assert text.count("name=kda_intra_fwd") == 1
+    assert text_b.count("name=kda_intra_fwd") == 2
+    for g, w in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(g)))
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_no_other_cell_reaches_the_kda_kernels():

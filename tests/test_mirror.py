@@ -226,6 +226,66 @@ def test_whole_graph_checkpoint_takes_the_same_policy(window, monkeypatch):
     np.testing.assert_array_equal(grads["data"], grads_b["data"])
 
 
+def _named_in_source():
+    """Every ``checkpoint_name(value, "<name>")`` in the package, as
+    ``(name, file, enclosing function)``."""
+    import ast
+    import mxnet_tpu
+    root = os.path.dirname(mxnet_tpu.__file__)
+    found = []
+
+    def visit(node, path, fn):
+        if isinstance(node, ast.FunctionDef):
+            fn = node.name
+        if isinstance(node, ast.Call) and "checkpoint_name" in (
+                getattr(node.func, "id", None),
+                getattr(node.func, "attr", None)):
+            found.append((node.args[1].value, path, fn))
+        for child in ast.iter_child_nodes(node):
+            visit(child, path, fn)
+
+    for where, _, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(where, f)
+                with open(path) as src:
+                    visit(ast.parse(src.read()),
+                          os.path.relpath(path, root), None)
+    return found
+
+
+def test_each_kept_name_is_made_by_one_op():
+    """Every name a segment keeps is given in exactly one place of the
+    package (one op's body), and no value is named that is not kept."""
+    from mxnet_tpu import executor
+    found = _named_in_source()
+    names = [n for n, _, _ in found]
+    assert sorted(names) == sorted(executor.MIRROR_KEEPS), found
+    assert len(set(executor.MIRROR_KEEPS)) == len(executor.MIRROR_KEEPS)
+    where = {n: (f, fn) for n, f, fn in found}
+    assert where["flash_attention_out"] == ("pallas/flash_attention.py",
+                                            "_fwd")
+    for n in ("kda_intra_aqk", "kda_intra_w", "kda_intra_u"):
+        assert where[n] == ("ops/lm.py", "_kda"), where
+
+
+def test_a_graph_without_the_delta_rule_lowers_as_before(monkeypatch):
+    """Names no op of the graph produces change nothing: two mirrored
+    attention layers lower to the same text under the step's policy and
+    under one that keeps the attention kernel's two names alone."""
+    import jax
+    from mxnet_tpu import executor
+    sym = _attn_layers(0, True)
+    jaxpr, text = _grad_program(sym)[:2]
+    monkeypatch.setattr(
+        executor, "_MIRROR_POLICY",
+        jax.checkpoint_policies.save_only_these_names(
+            "flash_attention_out", "flash_attention_lse"))
+    jaxpr_b, text_b = _grad_program(sym)[:2]
+    assert text == text_b
+    assert _kernels(jaxpr) == _kernels(jaxpr_b) == [2, 2, 2]
+
+
 def test_segment_without_a_named_value_lowers_as_before(monkeypatch):
     """No op of the MLP names a value: the policy keeps nothing and the
     program is the bare checkpoint's, text for text."""

@@ -297,17 +297,22 @@ def test_ssd_compiles_at_the_cell_shapes(one_chip, monkeypatch):
 def test_kda_compiles_at_the_cell_shapes(one_chip, monkeypatch):
     """``_contrib_KDA`` at ``kimi_linear.fit``'s shapes (one sequence of
     8,192 tokens, 32 heads of 128 for keys and values, chunks of 64 in
-    sub-blocks of 16, bfloat16), forward and gradient under
-    ``jax.checkpoint`` as the step holds a layer: ``kda/intra`` and
-    ``kda/state`` are the Pallas kernels of ``pallas/kda.py`` (each forward
-    twice: the pull-back of what follows it needs its outputs; each
-    backward once), no block of the sub-blocks' exponentials exists
-    outside them, no loop is left in the program (the state is carried
-    inside the kernels), and the temporaries of one layer's recurrence
-    stay under 2.3 GiB (1.70 when this was written, 2.08 with the loop
-    over chunks in ``jax.numpy``;
-    3.02 with the blocks and the inverse in ``jax.numpy`` a slab of 16
-    chunks at a time, 4.77 with the exponentials whole)."""
+    sub-blocks of 16, bfloat16), forward and gradient under the checkpoint
+    a mirrored segment gets (``executor._MIRROR_POLICY``), as the step holds
+    a layer: ``kda/intra`` and ``kda/state`` are the Pallas kernels of
+    ``pallas/kda.py`` (``kda_intra_fwd`` once: the segment keeps ``Aqk``,
+    ``W``, ``U`` by name; ``kda_state_fwd`` twice: its pull-back needs the
+    entering states, which the first run does not write; each backward
+    once), no block of the sub-blocks' exponentials exists outside them,
+    no loop is left in the program (the state is carried inside the
+    kernels), and the temporaries of one layer's recurrence stay under 2.3
+    GiB (1.70 with the three kept, as under a bare checkpoint that runs
+    ``kda_intra_fwd`` twice: one layer's backward follows its forward at
+    once, so the kept values live no longer than the second run's would;
+    2.08 with the loop over chunks in
+    ``jax.numpy``; 3.02 with the blocks and the inverse in ``jax.numpy`` a
+    slab of 16 chunks at a time, 4.77 with the exponentials whole)."""
+    from mxnet_tpu import executor
     from mxnet_tpu.ops import lm
     from mxnet_tpu.pallas import kda as kernels
     monkeypatch.setattr(kernels, "_use_interpret", lambda: False)
@@ -324,14 +329,15 @@ def test_kda_compiles_at_the_cell_shapes(one_chip, monkeypatch):
         return lm.kda(*a, heads=h, chunk=64, sub=16)
 
     def step(*a):
-        out, pull = jax.vjp(jax.checkpoint(kda), *a)
+        out, pull = jax.vjp(
+            jax.checkpoint(kda, policy=executor._MIRROR_POLICY), *a)
         return out, pull(jnp.cos(out))
 
     with jax.default_matmul_precision("default"):
         grad = _compile(step, *args)
     text = grad.as_text()
     calls = [line for line in text.splitlines() if " custom-call(" in line]
-    assert sum("kda_intra_fwd" in c for c in calls) == 2
+    assert sum("kda_intra_fwd" in c for c in calls) == 1
     assert sum("kda_intra_bwd" in c for c in calls) == 1
     assert sum("kda_state_fwd" in c for c in calls) == 2
     assert sum("kda_state_bwd" in c for c in calls) == 1
