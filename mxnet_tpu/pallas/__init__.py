@@ -3,7 +3,8 @@
 The compute path of this framework is XLA; these kernels cover the spots
 where XLA's automatic fusion is not enough (blockwise attention with an
 online-softmax accumulator, the chunked state-space recurrence's and
-the delta rule's within-chunk work, quantised communication payloads). Every
+the delta rule's within-chunk work, the routed layer's combine of the held
+rows, quantised communication payloads). Every
 kernel has an ``interpret`` fallback so the suite runs on the virtual CPU
 mesh (tests/conftest.py) and compiles natively on TPU.
 """

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..pallas.moe_combine import moe_combine
+
 __all__ = ["route", "chunk_rows", "chunk_load", "held_experts_ffn",
            "moe_layer", "bias_update", "PARTITION_RULES"]
 
@@ -163,12 +165,13 @@ def _rows(fn, sort, bufs):
         tuple(lax.empty((order.size,) + o.shape[1:], o.dtype) for o in like))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _spread(x, sort, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _spread(x, sort, k, back):
     """``(T, d) -> (chunks x size, d)``: sorted row r is token
     ``order[r] // k``'s, written for the chunks the held rows fill. Its
-    transpose is ``_collect``: both directions are row gathers (a
-    scatter-add of as many rows costs the chip several times a gather)."""
+    transpose is ``back``, ``_collect`` for the rows: both directions are
+    row gathers (a scatter-add of as many rows costs the chip several
+    times a gather)."""
     return _rows(lambda at: (jnp.take(x, at[:, 0] // k, axis=0),), sort,
                  (sort[0].reshape(-1, 1),))[0]
 
@@ -178,17 +181,24 @@ def _collect(y, sort, k):
     """``(chunks x size, d) -> (T, d)``: token t's row is the sum, in
     float32, of the rows its k selections were sorted to, ``inv[t k ..
     t k + k - 1]``; a selection sorted past the held rows (its expert is
-    held elsewhere) adds nought, whatever its row holds."""
+    held elsewhere) adds nought, and its row is not read (``moe_combine``
+    reads the held rows alone)."""
     _, inv, total, _ = sort
-    rows = jnp.where((inv < total)[:, None], jnp.take(y, inv, axis=0), 0)
-    return jnp.sum(rows.reshape((-1, k) + y.shape[1:]).astype(jnp.float32),
-                   axis=1).astype(y.dtype)
+    return moe_combine(y, inv, total, k)
 
 
-_spread.defvjp(lambda x, sort, k: (_spread(x, sort, k), sort),
-               lambda k, sort, g: (_collect(g, sort, k), None))
+def _picked(g, sort, k):
+    """The weights' spread's transpose (``k`` is 1): ``(T k, 1)`` float32,
+    selection s's entry sorted row ``inv[s]``'s, nought where that lies
+    past the held rows. One column is no row for ``moe_combine`` to copy."""
+    _, inv, total, _ = sort
+    return jnp.where((inv < total)[:, None], jnp.take(g, inv, axis=0), 0)
+
+
+_spread.defvjp(lambda x, sort, k, back: (_spread(x, sort, k, back), sort),
+               lambda k, back, sort, g: (back(g, sort, k), None))
 _collect.defvjp(lambda y, sort, k: (_collect(y, sort, k), sort),
-                lambda k, sort, g: (_spread(g, sort, k), None))
+                lambda k, sort, g: (_spread(g, sort, k, _collect), None))
 
 
 def _grouped(lhs, rhs, group_sizes):
@@ -343,8 +353,8 @@ def held_experts_ffn(x, sel, w, counts, w1, w3, w2, first=0, act="silu"):
         order = jnp.pad(order, (0, -(t * k) % size)).reshape(-1, size)
         sort = (order, inv, jnp.sum(rows),
                 chunk_load(rows, t * k, counts.shape[0])[0])
-        xs = _spread(x, sort, k)
-        ws = _spread(w.reshape(-1, 1), sort, 1)
+        xs = _spread(x, sort, k, _collect)
+        ws = _spread(w.reshape(-1, 1), sort, 1, _picked)
     with jax.named_scope("grouped"):
         y = _experts(act, xs, ws, rows, sort, w1, w3, w2)
     with jax.named_scope("combine"):

@@ -192,24 +192,31 @@ def test_flash_attention_carry_vmem_limit_at_8k(one_chip):
 @pytest.mark.parametrize("cell", [
     # top-k, d, f, held, gated, GiB of temporaries, router's experts
     (8, 2048, 1024, 16, True, 2.1, 128), (6, 2688, 1856, 8, False, 2.6, 128),
-    (4, 2048, 1792, 8, True, 1.3, 32)],
-    ids=["trinity_mini.fit", "nemotron3_nano.fit", "lfm2_moe.fit"])
+    (4, 2048, 1792, 8, True, 1.3, 32), (8, 2304, 1024, 8, True, 2.4, 256)],
+    ids=["trinity_mini.fit", "nemotron3_nano.fit", "lfm2_moe.fit",
+         "kimi_linear.fit"])
 def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip, cell):
     """The routed layer at the cells' shapes (8,192 tokens, bfloat16;
     ``trinity_mini.fit``: top-8, d 2,048, f 1,024, 16 of 128 gated experts
     held; ``nemotron3_nano.fit``: top-6, 2,688 x 1,856, 8 of 128, no
     gate; ``lfm2_moe.fit``: top-4, 2,048 x 1,792 (padded to 2,048), 8 of
-    32): forward and gradient compile, the sorted order in chunks of
-    the even share (8 of 8,192 rows, 16 of 3,072, 8 of 32 of 32,768). The
+    32; ``kimi_linear.fit``: top-8, 2,304 x 1,024 (padded to 2,560), 8 of
+    256): forward and gradient compile, the sorted order in chunks of
+    the even share (8 of 8,192 rows, 16 of 3,072, 8 of 32 of 32,768, 32
+    of 2,048). The
     grouped products stay 3 forward and 3 + 6 with the gradient (2 and 2
     + 4 without a gate), each
     one kernel over the whole order; the row passes are loops whose trip
     count is the step's own, and no branch holds a second body; the
     products' row tile is the 512 rows that ``chunk_rows`` rounds to.
+    The combine, forward and in the dispatch's transpose, is the
+    ``moe_combine`` kernel, which reads the held rows alone: no gather
+    of all ``T k`` sorted rows is left in the gradient.
     Under ``jax.checkpoint``, as the step holds a layer, the gated layer's
     temporaries stay at the whole-buffer layer's 2.01 GB (1.93 GiB; 2.44
-    GiB for the ungated layer at its wider, padded shapes; 1.24 GiB for
-    LFM2's, whose 32,768 selections are a quarter of the others')."""
+    GiB for the ungated layer at its wider, padded shapes; 1.27 GiB for
+    LFM2's, whose 32,768 selections are a quarter of the others'; 2.38
+    GiB for Kimi Linear's)."""
     from mxnet_tpu.parallel import moe
     t = 8192
     k, d, f, count, gated, room, n = cell
@@ -249,6 +256,12 @@ def test_chunked_moe_layer_compiles_at_the_cell_shapes(one_chip, cell):
                 if 'op_name="ragged-dot-metadata"' in line
                 and "custom-call(" in line]
     assert metadata and all("s32[%d]" % tiles in m for m in metadata)
+    whole = "[%d,%d]" % (t * k, -(-d // moe._TILE) * moe._TILE)
+    assert not [line for line in text.splitlines()
+                if " gather(" in line
+                and whole in line.split(" = ", 1)[-1].split(" gather(")[0]]
+    assert sum("custom-call(" in line and "moe_combine" in line
+               for line in text.splitlines()) == 2
     assert grad.memory_analysis().temp_size_in_bytes < room * 2 ** 30
 
 
